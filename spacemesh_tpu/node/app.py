@@ -1100,6 +1100,27 @@ class App:
         smeshing.external_worker, proofs come from the out-of-process
         worker via PostSupervisor + RemotePostClient."""
         cfg = self.cfg
+        if cfg.smeshing.external_worker:
+            # a chip belongs to one process. The worker proves, so the
+            # worker owns it — but this process still initializes POST
+            # data (and verifies proofs) in-process, which opens the same
+            # chip first and leaves the worker failing or hanging. Only
+            # the platform JAX lands on decides: a host without an
+            # accelerator keeps external_worker with nothing set. The
+            # init below opens the backend anyway, so asking costs
+            # nothing. ROADMAP D13: move init into the worker.
+            import jax
+
+            platform = jax.default_backend()
+            if platform != "cpu":
+                raise RuntimeError(
+                    "smeshing.external_worker: the POST worker process "
+                    "owns the accelerator, but this node process runs "
+                    f"POST init and verification on JAX ({platform!r}) "
+                    "and holds the same chip. Start the node with "
+                    "JAX_PLATFORMS=cpu (the worker inherits it: "
+                    "everything on the CPU), or turn external_worker off "
+                    "so ONE process owns the chip.")
         post_base = self.data / "post"
         for s in self.signers:
             post_dir = post_base / s.node_id.hex()[:16]

@@ -4,17 +4,24 @@ Which label-kernel variant is fastest is a per-host question (SURVEY.md
 §7; the ASIC-crypto playbook of arxiv 2604.17808 / 2505.14657): the XLA
 gather path with a VMEM/LLC-sized lane chunk wins where the working set
 must be kept hot, the contiguous-row variant wins where the gather's
-read amplification dominates, and the Pallas DMA kernel is only worth
-compiling on a real TPU.  Rather than hardcode that table, first use
-races the candidates on a tiny calibration workload and persists the
-winner per ``(platform, N, batch)`` next to the persistent XLA compile
-cache (utils/accel.py), so every entry point — post/initializer.py,
+read amplification dominates.  Rather than hardcode that table, first
+use races the candidates on a tiny calibration workload and persists the
+winner per ``(platform, N, batch)`` under the checkout's cache root
+(utils/accel.py CACHE_ROOT), so every entry point — post/initializer.py,
 post/prover.py's scan, parallel/mesh.py, bench.py, tools/profiler.py —
 picks up the tuned kernel with zero configuration, and a second process
 on the same host skips the race entirely.
 
+The Pallas kernel (ops/romix_pallas.py) is NOT a race candidate on any
+platform: interpret mode is never a contender on CPU, and Mosaic refuses
+the kernel's 32-word-minor VMEM layout on TPU (ROADMAP S4 has the
+compiler's messages).  ``SPACEMESH_ROMIX=pallas`` stays the explicit
+request — it raises on failure, it never degrades to another impl.  A
+raced candidate that fails to compile or run raises too: the default set
+holds only kernels that compiled and matched on the chip.
+
 The grid has a MESH dimension (docs/ROMIX_KERNEL.md): on hosts exposing
-more than one device — notably the CPU fallback's virtual host devices
+more than one device — notably a CPU run's virtual host devices
 (``--xla_force_host_platform_device_count``, which every test/driver
 entry point already forces to 8) — the race also times the label kernel
 lane-sharded over {2, 4, 8} devices via parallel/mesh.py. The
@@ -41,10 +48,9 @@ Decision precedence (highest first):
 4. a static heuristic default (race disabled or impossible): the plain
    single-device XLA kernel.
 
-Cache file: ``<cache root>/romix_autotune.json`` (cache root is the
-parent of accel.DEFAULT_CACHE_DIR, i.e. ``~/.cache/spacemesh_tpu``;
-``SPACEMESH_ROMIX_CACHE`` overrides the file path, ``SPACEMESH_JAX_CACHE``
-moves the whole cache root).  A corrupt or unreadable file is treated as
+Cache file: ``romix_autotune.json`` under utils/accel.py CACHE_ROOT (the
+git-ignored directory inside the checkout; ``SPACEMESH_ROMIX_CACHE``
+overrides the file path).  A corrupt or unreadable file is treated as
 empty — the race re-runs and rewrites it.  See docs/ROMIX_KERNEL.md.
 """
 
@@ -108,8 +114,6 @@ class Decision:
     chunk: int | None         # lanes per sequential V chunk; None = whole batch
     source: str               # "env" | "cache" | "race" | "default" | "untuned"
     labels_per_sec: float | None = None  # calibration rate, when raced
-    explicit_impl: bool = False  # impl came from SPACEMESH_ROMIX (never
-    #                              silently fall back from it — ops/scrypt.py)
     devices: int = 1          # lane-shard the batch over this many devices
     #                           (parallel/mesh.py; 1 = single-device dispatch)
     mesh_shape: str = "lane"  # which MESH_SHAPES layout the sharded
@@ -123,17 +127,13 @@ class Decision:
 
 
 def cache_path() -> str:
-    """The autotune winners file, colocated with the XLA compile cache."""
+    """The autotune winners file, under the checkout's cache root."""
     explicit = os.environ.get(ENV_CACHE)
     if explicit:
         return os.path.expanduser(explicit)
     from ..utils import accel
 
-    jax_cache = os.environ.get("SPACEMESH_JAX_CACHE")
-    if not jax_cache or jax_cache in _OFF:
-        jax_cache = accel.DEFAULT_CACHE_DIR
-    root = os.path.dirname(os.path.expanduser(jax_cache))
-    return os.path.join(root, "romix_autotune.json")
+    return str(accel.CACHE_ROOT / "romix_autotune.json")
 
 
 def _key(platform: str, n: int, batch: int, dev_cap: int = 1) -> str:
@@ -195,8 +195,8 @@ def _entry_decision(entry: dict, batch: int, source: str) -> Decision | None:
     impl = entry.get("impl")
     chunk = entry.get("chunk")
     devices = entry.get("devices", 1)
-    if impl not in IMPLS:
-        return None
+    if impl not in IMPLS or impl == "pallas":
+        return None  # pallas is never raced, so never a persisted winner
     if chunk is not None and (not isinstance(chunk, int) or chunk < 1):
         return None
     if not isinstance(devices, int) or isinstance(devices, bool) \
@@ -267,12 +267,12 @@ def resolve_auto_mesh(n: int, batch: int):
     and post/prover.py share (hand-rolled twins of this logic have
     already diverged once on knob parsing; see read_mesh_env).
 
-    On the CPU fallback the tuned mesh winner decides (devices > 1 only
-    when the raced row says so and the host still exposes that many).
-    On real multi-device hardware the historical whole-mesh default
-    holds. SPACEMESH_MESH forces either way (off -> always None; the
-    CPU path honors it inside decide(), which collapses a forced count
-    into the returned decision). Callers build the parallel/mesh.py
+    On the CPU the tuned mesh winner decides (devices > 1 only when
+    the raced row says so and the host still exposes that many). On an
+    accelerator the batch shards over every visible device.
+    SPACEMESH_MESH forces either way (off -> always None; the CPU path
+    honors it inside decide(), which collapses a forced count into the
+    returned decision). Callers build the parallel/mesh.py
     Mesh from the returned device list; None means stay single-device.
     """
     import jax
@@ -342,25 +342,15 @@ def candidates(platform: str, n: int, batch: int, mesh_cap: int = 1
                 ) -> list[tuple[str, int | None, int]]:
     """The (impl, chunk, devices) grid raced for one shape."""
     chunks: list[int | None] = [None, *chunk_candidates(n, batch)]
-    if platform == "cpu":
-        # interpret-mode Pallas executes every DMA in Python — never a
-        # contender, so never raced (force it with SPACEMESH_ROMIX=pallas)
-        out = [(impl, c, 1) for impl in ("xla", "xla-rows") for c in chunks]
-    else:
-        out = [("xla", c, 1) for c in chunks]
-        if platform == "tpu":
-            # the Pallas kernel tiles lanes at LANE_TILE internally (its V
-            # scratch is per-tile), so an outer chunk adds nothing
-            out.append(("pallas", None, 1))
+    # no Pallas row on any platform (module docstring)
+    impls = ("xla", "xla-rows") if platform == "cpu" else ("xla",)
+    out = [(impl, c, 1) for impl in impls for c in chunks]
     if mesh_cap > 1:
         # mesh rows: both XLA layouts on CPU (the contiguous-row variant's
         # win condition — gather read amplification — is per-device, so it
         # can flip under sharding too), plain xla elsewhere. No chunk: a
         # sequential lane chunk inside a shard fights GSPMD partitioning
-        # (ops/scrypt.py _tunable), and the Pallas kernel is raced
-        # single-device only (its per-tile V scratch already bounds the
-        # working set).
-        impls = ("xla", "xla-rows") if platform == "cpu" else ("xla",)
+        # (ops/scrypt.py _tunable).
         for d in mesh_candidates(_device_count(), mesh_cap):
             out.extend((impl, None, d) for impl in impls)
     return out
@@ -467,17 +457,13 @@ def _race_rows(platform: str, n: int,
     x_host = jnp.asarray(calibration_block(CAL_BATCH))
     rows = []
     for impl, chunk, devices in combos:
-        # non-pallas candidates never interpret — the SAME static jit key
-        # production uses, so the race's compile is reused, not repaid
-        interpret = impl == "pallas" and platform != "tpu"
         label = f"{impl}" + (f"/chunk={chunk}" if chunk else "") + (
             f"/devices={devices}" if devices > 1 else "")
-        csp = tracing.span("romix.race_candidate",
-                           {"impl": impl, "chunk": chunk,
-                            "devices": devices}
-                           if tracing.is_enabled() else None)
-        csp.__enter__()
-        try:
+        # a candidate that cannot compile or run RAISES out of the race:
+        # the grid holds only kernels known to run on this platform
+        with tracing.span("romix.race_candidate",
+                          {"impl": impl, "chunk": chunk, "devices": devices}
+                          if tracing.is_enabled() else None) as csp:
             if devices > 1:
                 from ..parallel import mesh as pmesh
 
@@ -487,8 +473,10 @@ def _race_rows(platform: str, n: int,
                 x = x_host
 
             def run():
+                # interpret=False: the SAME static jit key production
+                # uses, so the race's compile is reused, not repaid
                 return scrypt.romix_tuned(x, n=n, impl=impl, chunk=chunk,
-                                          interpret=interpret)
+                                          interpret=False)
 
             t0 = time.perf_counter()
             run().block_until_ready()
@@ -506,19 +494,6 @@ def _race_rows(platform: str, n: int,
             rows.append({"impl": impl, "chunk": chunk, "devices": devices,
                          "shape": shape_of(impl),
                          "labels_per_sec": round(rate, 1)})
-        except Exception as e:  # noqa: BLE001 — a candidate that cannot
-            # compile on this host simply loses the race. Persisted as a
-            # 0-rate row so the next process does NOT see it as missing
-            # and re-pay the failing attempt at every startup (delete the
-            # winners file to retry after fixing the host).
-            _log(f"romix autotune: {label} failed "
-                 f"({type(e).__name__}: {e})")
-            csp.set(failed=type(e).__name__)
-            rows.append({"impl": impl, "chunk": chunk, "devices": devices,
-                         "shape": shape_of(impl), "labels_per_sec": 0.0,
-                         "failed": type(e).__name__})
-        finally:
-            csp.__exit__(None, None, None)
     return rows
 
 
@@ -553,7 +528,7 @@ def race(platform: str, n: int, batch: int, dev_cap: int = 1,
               if (r["chunk"] is None or r["chunk"] < batch)
               and r["devices"] <= dev_cap
               and r["devices"] <= batch
-              and not r.get("failed") and r["labels_per_sec"] > 0]
+              and r["labels_per_sec"] > 0]
     if pin_devices is not None:
         usable = [r for r in usable if r["devices"] == pin_devices]
         if not usable:
@@ -683,8 +658,7 @@ def _decide(n: int, batch: int, platform: str, allow_race: bool,
             chunk = None
         devices = mesh_env if mesh_env is not None else (
             cached.devices if cached is not None else 1)
-        return Decision(impl_env, chunk, "env", explicit_impl=True,
-                        devices=devices)
+        return Decision(impl_env, chunk, "env", devices=devices)
     if chunk_set:
         base = cached or default_decision(platform, n, batch)
         chunk = chunk_env if (chunk_env is None or chunk_env < batch) else None

@@ -95,21 +95,6 @@ def pow_hash(challenge: bytes, node_id: bytes, nonce: int) -> bytes:
         challenge + node_id + int(nonce).to_bytes(8, "little")).digest()
 
 
-def _host_scan(challenge: bytes, node_id: bytes, difficulty: bytes,
-               base: int, batch: int) -> int | None:
-    """Pure-host fallback batch (hashlib): the k2pow gate must survive a
-    wedged or failing accelerator — a device dispatch error degrades to
-    this, it does not kill the prove."""
-    prefix = challenge + node_id
-    import hashlib
-
-    for nonce in range(base, base + batch):
-        if hashlib.sha256(
-                prefix + nonce.to_bytes(8, "little")).digest() < difficulty:
-            return nonce
-    return None
-
-
 def search(challenge: bytes, node_id: bytes, difficulty: bytes,
            *, batch: int = 1 << 16, start: int = 0,
            max_batches: int = 1 << 16, inflight: int = 2,
@@ -121,9 +106,8 @@ def search(challenge: bytes, node_id: bytes, difficulty: bytes,
     the host-side hit check of one batch overlaps the next batch's
     device compute.  Batches retire in nonce order, so the result — the
     smallest hit in the first batch containing one — is identical to
-    the historical serial loop's.  A device dispatch failure falls back
-    to a host hashlib scan of that batch (counted in
-    ``runtime_fallbacks_total{kind="k2pow"}``); None when exhausted.
+    the historical serial loop's.  A device failure raises — the search
+    never re-runs a batch on the host unasked.  None when exhausted.
     """
     from ..runtime import engine
 
@@ -139,22 +123,15 @@ def search(challenge: bytes, node_id: bytes, difficulty: bytes,
         # enqueue only: the (B,) hit mask crosses to host at retire
         return base, below_target_jit(pow_hash_batch_jit(st, lo, hi), tgt)
 
-    def fallback(base, exc):
-        del exc  # counted by runtime_fallbacks_total{kind="k2pow"}
-        return base, None  # marker: retire re-scans this batch on host
-
     def retire(ticket):
         # a 0 return is a valid winning nonce: the engine's early-exit
         # test is `is not None`, not truthiness
         base, ok = ticket
-        if ok is None:
-            return _host_scan(challenge, node_id, difficulty, base, batch)
         hits = np.nonzero(np.asarray(ok))[0]
         return int(base + int(hits[0])) if hits.size else None
 
     pipe = engine.Pipeline(kind="k2pow", tenant=tenant,
-                           inflight=inflight, fallback=fallback,
-                           span="pow")
+                           inflight=inflight, span="pow")
     return pipe.run((start + i * batch for i in range(max_batches)),
                     dispatch, retire)
 
@@ -235,10 +212,9 @@ def verify_many(items: list, *, batch: int = 1 << 12,
     device compute); ragged chunks pad to their power-of-two shape
     bucket by replicating lane 0, so occupancy changes reuse compiled
     executables. Batches below ``min_device`` items skip the device
-    round-trip (two hashlib blocks are cheaper than a dispatch), and a
-    device dispatch failure degrades that chunk to the host scan
-    (``runtime_fallbacks_total{kind="k2pow_verify"}``) — never a wrong
-    or missing verdict.
+    round-trip (two hashlib blocks are cheaper than a dispatch) — a
+    choice made from the batch size, never from a failure: a device
+    failure raises.
     """
     n = len(items)
     if n == 0:
@@ -276,21 +252,13 @@ def verify_many(items: list, *, batch: int = 1 << 12,
         return rng, pow_verify_batch_jit(
             jnp.asarray(block1), lo, hi, jnp.asarray(targets))
 
-    def fallback(rng, exc):
-        del exc  # counted by runtime_fallbacks_total{kind="k2pow_verify"}
-        return rng, None  # marker: retire re-verifies this chunk on host
-
     def retire(ticket):
         (lo_i, hi_i), ok = ticket
-        if ok is None:
-            results[lo_i:hi_i] = _verify_host(items[lo_i:hi_i])
-        else:
-            results[lo_i:hi_i] = np.asarray(ok)[:hi_i - lo_i]
+        results[lo_i:hi_i] = np.asarray(ok)[:hi_i - lo_i]
         return None
 
     pipe = engine.Pipeline(kind="k2pow_verify", tenant=tenant,
-                           inflight=inflight, fallback=fallback,
-                           span="pow_verify")
+                           inflight=inflight, span="pow_verify")
     pipe.run(((i, min(i + batch, n)) for i in range(0, n, batch)),
              dispatch, retire)
     return results.tolist()

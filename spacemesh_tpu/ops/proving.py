@@ -121,7 +121,7 @@ def proving_scan_jit(challenge_words, nonce_base, idx_lo, idx_hi, label_words,
 HIT_SEGMENT = 64  # lanes per compaction segment; batch must divide by this
 
 
-def compact_hits(mask, seg_sum=None, *, max_hits: int):
+def compact_hits(mask, *, max_hits: int):
     """Compact a (n_nonces, B) mask into per-nonce hit positions.
 
     Returns ``(batch_counts, local_pos, hit_valid)``: true per-nonce hit
@@ -130,15 +130,11 @@ def compact_hits(mask, seg_sum=None, *, max_hits: int):
     Two-level extraction — segment popcounts, then a gather of only the
     ``max_hits`` segments that actually contain the wanted hits — so the
     cost is one reduction pass over the mask, not a (n_nonces, B) sort.
-
-    ``seg_sum`` may be supplied by a kernel that already reduced the mask
-    (the Pallas epilogue); otherwise it is computed here.
     """
     n_nonces, b = mask.shape
     nseg = b // HIT_SEGMENT
     m3 = mask.reshape(n_nonces, nseg, HIT_SEGMENT)
-    if seg_sum is None:
-        seg_sum = jnp.sum(m3, axis=-1, dtype=jnp.int32)
+    seg_sum = jnp.sum(m3, axis=-1, dtype=jnp.int32)
     seg_csum = jnp.cumsum(seg_sum, axis=1)
     batch_counts = seg_csum[:, -1]
     targets = jnp.arange(1, max_hits + 1, dtype=jnp.int32)

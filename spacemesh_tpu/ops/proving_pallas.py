@@ -8,25 +8,25 @@ in VMEM and unrolls the nonce group over it, so each label crosses
 HBM->VMEM once per group instead of once per nonce (the XLA version
 re-materializes the broadcast state per nonce).
 
-Compaction epilogue (streaming prover): alongside the mask the kernel
-reduces each HIT_SEGMENT-lane span to its hit count while the tile is
-still in VMEM, and masks pad lanes (``lane >= valid``) so a ragged tail
-batch shares the full-batch compiled shape. The surrounding jit
-(``prove_scan_step_pallas``) turns those segment counts into packed
-(nonce, index) hit pairs merged into a donated device carry — the mask
-never crosses PCIe; the only per-batch D2H is the (n_nonces,) count
-vector (ops/proving.py compact_hits/merge_hits).
+Layout: the lane axis is viewed as (B // 128, 128) rows so every state
+word of a tile is one dense (8, 128) u32 vreg — Mosaic has no 1-D vector
+layout, and a (1, T) row would fill one sublane in eight. Inputs:
+  scalars (11,) u32 in SMEM: challenge words 0..7, nonce_base, threshold,
+                 valid
+  idx_lo, idx_hi (B // 128, 128) u32
+  lw    (4, B // 128, 128) u32 little-endian label words
+Output:
+  bits  (B // 128, 128) u32 — bit k set <=> the lane qualifies under
+        nonce_base + k; pad lanes (``lane >= valid``) never qualify, so a
+        ragged tail batch shares the full-batch compiled shape.
 
-Layout (matching ops/scrypt.py): lane-minor u32 tiles. Inputs:
-  base  (12, B)  rows: challenge words 0..7 (broadcast), idx_lo, idx_hi,
-                 zeros, spare
-  lw    (4, B)   little-endian label words
-  nonce_base, threshold, valid: SMEM scalars
-Outputs:
-  mask  (n_nonces, B) int8 qualification
-  seg   (n_nonces, B // HIT_SEGMENT) i32 per-segment hit counts
+One u32 of hit bits per lane is the whole kernel output (the (n_nonces, B)
+mask would be 4-16x the bytes); the surrounding jit unpacks it and
+``prove_scan_step_pallas`` runs the same compaction epilogue as the XLA
+step (ops/proving.py compact_hits/merge_hits), so the mask never crosses
+PCIe and the only per-batch D2H is the (n_nonces,) count vector.
 
-Grid: lane tiles of LANE_TILE. Set ``interpret=True`` to run/verify on CPU
+Grid: lane tiles of LANE_TILE. ``interpret=True`` runs the kernel on CPU
 (the test path); on TPU the same call compiles via Mosaic.
 """
 
@@ -38,18 +38,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import proving
 
-try:  # pltpu only resolves on TPU builds; interpret mode works without it
-    from jax.experimental.pallas import tpu as pltpu
-
-    _SMEM = pltpu.SMEM
-except Exception:  # pragma: no cover - non-TPU jaxlib
-    pltpu = None
-    _SMEM = None
-
-LANE_TILE = 512
+_LANES = 128
+LANE_TILE = 8 * _LANES  # one (8, 128) u32 vreg per state word
+MAX_NONCES = 32         # hit bits per lane in the kernel's u32 output
 
 
 def _quarter(x, a, b, c, d):
@@ -62,26 +57,27 @@ def _quarter(x, a, b, c, d):
     x[a] = x[a] ^ rotl(x[d] + x[c], 18)
 
 
-def _kernel(nonce_ref, thr_ref, valid_ref, base_ref, lw_ref, out_ref,
-            seg_ref, *, n_nonces: int):
-    base = base_ref[...]          # (12, T) u32
-    lw = lw_ref[...]              # (4, T) u32
-    thr = thr_ref[0]
-    nonce0 = nonce_ref[0]
-    valid = valid_ref[0]
-    t = base.shape[1]
-    nseg = t // proving.HIT_SEGMENT
-    # global lane index of each tile lane (2-D iota: TPU-safe)
-    lane = jax.lax.broadcasted_iota(jnp.uint32, (t, 1), 0).reshape(t)
-    alive = (jnp.uint32(pl.program_id(0)) * jnp.uint32(t) + lane) < valid
-    zeros = jnp.zeros((t,), jnp.uint32)
+def _kernel(sc_ref, lo_ref, hi_ref, lw_ref, out_ref, *, n_nonces: int):
+    lo = lo_ref[...]              # (R, 128) u32
+    hi = hi_ref[...]
+    lw = [lw_ref[i] for i in range(4)]
+    nonce0, thr, valid = sc_ref[8], sc_ref[9], sc_ref[10]
+    r, c = lo.shape
+    # global lane index of each tile lane (2-D iota: Mosaic has no 1-D)
+    row = jax.lax.broadcasted_iota(jnp.int32, (r, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (r, c), 1)
+    lane = (pl.program_id(0) * r + row) * c + col
+    alive = lane.astype(jnp.uint32) < valid
+    zeros = jnp.zeros((r, c), jnp.uint32)
+    ch = [zeros + sc_ref[i] for i in range(8)]   # challenge rows
+    bits = zeros
     for k in range(n_nonces):     # static unroll over the nonce group
-        x = [base[i] for i in range(8)]          # challenge rows
+        x = list(ch)
         x.append(zeros + (nonce0 + jnp.uint32(k)))
-        x.append(base[8])                         # idx_lo
-        x.append(base[9])                         # idx_hi
-        x.append(base[10])                        # zeros row
-        x.extend(lw[i] for i in range(4))
+        x.append(lo)
+        x.append(hi)
+        x.append(zeros)
+        x.extend(lw)
         in0 = x[0]
         for _ in range(4):        # Salsa20/8 = 4 double rounds
             _quarter(x, 0, 4, 8, 12)
@@ -92,113 +88,89 @@ def _kernel(nonce_ref, thr_ref, valid_ref, base_ref, lw_ref, out_ref,
             _quarter(x, 5, 6, 7, 4)
             _quarter(x, 10, 11, 8, 9)
             _quarter(x, 15, 12, 13, 14)
-        word0 = x[0] + in0
-        hit = (word0 < thr) & alive
-        out_ref[k, :] = hit.astype(jnp.int8)
-        # compaction epilogue: per-segment popcounts while the tile is in
-        # VMEM, so the host-side hit extraction never touches the mask
-        seg_ref[k, :] = jnp.sum(
-            hit.reshape(nseg, proving.HIT_SEGMENT).astype(jnp.int32),
-            axis=1)
+        hit = ((x[0] + in0) < thr) & alive
+        bits = bits | jnp.where(hit, jnp.uint32(1 << k), jnp.uint32(0))
+    out_ref[...] = bits
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("n_nonces", "interpret", "lane_tile"))
+@functools.partial(jax.jit, static_argnames=("n_nonces", "interpret"))
 def _scan_pallas(challenge_words, nonce_base, idx_lo, idx_hi, label_words,
-                 threshold, valid, *, n_nonces: int, interpret: bool = False,
-                 lane_tile: int = LANE_TILE):
-    """Mask + per-segment hit counts; batch must divide by ``lane_tile``."""
+                 threshold, valid, *, n_nonces: int, interpret: bool = False):
+    """(n_nonces, B) bool mask; batch must divide by ``LANE_TILE``."""
     b = idx_lo.shape[0]
-    if b % lane_tile:
-        raise ValueError(f"batch {b} not a multiple of lane tile {lane_tile}")
-    ch = jnp.broadcast_to(challenge_words.astype(jnp.uint32)[:, None], (8, b))
-    base = jnp.concatenate([
-        ch, idx_lo[None].astype(jnp.uint32), idx_hi[None].astype(jnp.uint32),
-        jnp.zeros((2, b), jnp.uint32),
-    ])
-    grid = (b // lane_tile,)
-    kernel = functools.partial(_kernel, n_nonces=n_nonces)
-    scalar_spec = (pl.BlockSpec(memory_space=_SMEM) if _SMEM is not None
-                   else pl.BlockSpec(memory_space=pl.ANY))
-    seg_tile = lane_tile // proving.HIT_SEGMENT
-    mask, seg = pl.pallas_call(
-        kernel,
-        out_shape=(
-            jax.ShapeDtypeStruct((n_nonces, b), jnp.int8),
-            jax.ShapeDtypeStruct((n_nonces, b // proving.HIT_SEGMENT),
-                                 jnp.int32),
-        ),
-        grid=grid,
+    if b % LANE_TILE:
+        raise ValueError(f"batch {b} not a multiple of lane tile {LANE_TILE}")
+    if not 1 <= n_nonces <= MAX_NONCES:
+        raise ValueError(f"n_nonces {n_nonces} outside [1, {MAX_NONCES}]")
+    rows, tile_rows = b // _LANES, LANE_TILE // _LANES
+    scalars = jnp.concatenate([
+        challenge_words.astype(jnp.uint32),
+        jnp.stack([jnp.asarray(nonce_base, jnp.uint32),
+                   jnp.asarray(threshold, jnp.uint32),
+                   jnp.asarray(valid, jnp.uint32)])])
+    lane_spec = pl.BlockSpec((tile_rows, _LANES), lambda i: (i, 0))
+    bits = pl.pallas_call(
+        functools.partial(_kernel, n_nonces=n_nonces),
+        out_shape=jax.ShapeDtypeStruct((rows, _LANES), jnp.uint32),
+        grid=(b // LANE_TILE,),
         in_specs=[
-            scalar_spec,
-            scalar_spec,
-            scalar_spec,
-            pl.BlockSpec((12, lane_tile), lambda i: (0, i)),
-            pl.BlockSpec((4, lane_tile), lambda i: (0, i)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            lane_spec,
+            lane_spec,
+            pl.BlockSpec((4, tile_rows, _LANES), lambda i: (0, i, 0)),
         ],
-        out_specs=(
-            pl.BlockSpec((n_nonces, lane_tile), lambda i: (0, i)),
-            pl.BlockSpec((n_nonces, seg_tile), lambda i: (0, i)),
-        ),
+        out_specs=lane_spec,
         interpret=interpret,
-    )(jnp.asarray([nonce_base], jnp.uint32),
-      jnp.asarray([threshold], jnp.uint32),
-      jnp.asarray([valid], jnp.uint32), base,
-      label_words.astype(jnp.uint32))
-    return mask, seg
+    )(scalars,
+      idx_lo.astype(jnp.uint32).reshape(rows, _LANES),
+      idx_hi.astype(jnp.uint32).reshape(rows, _LANES),
+      label_words.astype(jnp.uint32).reshape(4, rows, _LANES))
+    shifts = jnp.arange(n_nonces, dtype=jnp.uint32)[:, None]
+    return ((bits.reshape(1, b) >> shifts) & jnp.uint32(1)).astype(bool)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("n_nonces", "interpret", "lane_tile"))
+@functools.partial(jax.jit, static_argnames=("n_nonces", "interpret"))
 def proving_scan_pallas(challenge_words, nonce_base, idx_lo, idx_hi,
                         label_words, threshold, *, n_nonces: int,
-                        interpret: bool = False, lane_tile: int = LANE_TILE):
-    """Drop-in for ops.proving.proving_scan_jit (returns int8 mask).
+                        interpret: bool = False):
+    """Drop-in for ops.proving.proving_scan_jit.
 
-    Batch size must be a multiple of ``lane_tile``.
+    Batch size must be a multiple of ``LANE_TILE``.
     """
     b = idx_lo.shape[0]
-    mask, _ = _scan_pallas(challenge_words, nonce_base, idx_lo, idx_hi,
-                           label_words, threshold, jnp.uint32(b),
-                           n_nonces=n_nonces, interpret=interpret,
-                           lane_tile=lane_tile)
-    return mask
+    return _scan_pallas(challenge_words, nonce_base, idx_lo, idx_hi,
+                        label_words, threshold, jnp.uint32(b),
+                        n_nonces=n_nonces, interpret=interpret)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("n_nonces", "max_hits", "interpret",
-                                    "lane_tile"),
+                   static_argnames=("n_nonces", "max_hits", "interpret"),
                    donate_argnums=(6, 7))
 def prove_scan_step_pallas(challenge_words, nonce_base, idx_lo, idx_hi,
                            label_words, threshold, hit_counts, hit_carry,
                            valid, start_lo, start_hi, *, n_nonces: int,
-                           max_hits: int, interpret: bool = False,
-                           lane_tile: int = LANE_TILE):
+                           max_hits: int, interpret: bool = False):
     """Pallas-backed twin of ops.proving.prove_scan_step_jit.
 
     Same contract: donated (hit_counts, hit_carry) device state, per-batch
     D2H limited to the (n_nonces,) batch count vector.
     """
-    mask, seg = _scan_pallas(challenge_words, nonce_base, idx_lo, idx_hi,
-                             label_words, threshold, valid,
-                             n_nonces=n_nonces, interpret=interpret,
-                             lane_tile=lane_tile)
-    counts, pos, ok = proving.compact_hits(mask.astype(bool), seg_sum=seg,
-                                           max_hits=max_hits)
+    mask = _scan_pallas(challenge_words, nonce_base, idx_lo, idx_hi,
+                        label_words, threshold, valid,
+                        n_nonces=n_nonces, interpret=interpret)
+    counts, pos, ok = proving.compact_hits(mask, max_hits=max_hits)
     return proving.merge_hits(hit_counts, hit_carry, counts, pos, ok,
                               start_lo, start_hi)
 
 
 def proving_scan(challenge: bytes, nonce_base: int, indices, labels: np.ndarray,
-                 threshold: int, n_nonces: int,
-                 interpret: bool | None = None) -> np.ndarray:
+                 threshold: int, n_nonces: int) -> np.ndarray:
     """Host wrapper mirroring ops.proving host entries. Pads the batch to
     the lane tile. Returns (n_nonces, B) bool."""
+    from ..utils import accel
     from .proving import challenge_words
     from .scrypt import labels_to_words, split_indices
 
-    if interpret is None:
-        interpret = jax.devices()[0].platform == "cpu"
     idx = np.atleast_1d(np.asarray(indices, dtype=np.uint64)).ravel()
     b = idx.shape[0]
     pad = (-b) % LANE_TILE
@@ -211,5 +183,5 @@ def proving_scan(challenge: bytes, nonce_base: int, indices, labels: np.ndarray,
         jnp.asarray(challenge_words(challenge)), jnp.uint32(nonce_base),
         jnp.asarray(lo), jnp.asarray(hi),
         jnp.asarray(labels_to_words(labels)), jnp.uint32(threshold),
-        n_nonces=n_nonces, interpret=interpret)
-    return np.asarray(mask)[:, :b].astype(bool)
+        n_nonces=n_nonces, interpret=accel.pallas_interpret())
+    return np.asarray(mask)[:, :b]

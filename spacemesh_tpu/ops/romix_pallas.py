@@ -1,8 +1,6 @@
 """Pallas ROMix variant: contiguous-row (N, T, 32) V + async-copy gathers.
 
-The race candidate recorded in docs/ROUND2_NOTES.md ("Pallas ROMix:
-analysis"): the XLA path (ops/scrypt.py romix_r1) stores V as (N, 32, B)
-and gathers a (32, B) slab per iteration with a per-lane random row —
+The XLA path (ops/scrypt.py romix_r1) stores V as (N, 32, B) and gathers a (32, B) slab per iteration with a per-lane random row —
 one fused XLA gather.  This kernel flips the layout to (N, T, 32) so ONE
 LANE'S ROW IS 128 CONTIGUOUS BYTES, then:
 
@@ -21,12 +19,15 @@ round is elementwise column arithmetic — no per-round ``stack`` /
 block is only materialized as a (T, 32) tile at the DMA boundaries
 (fill-buffer stores, Integerify staging, final output).
 
-Whether this beats XLA's gather is an empirical, per-platform question:
-ops/autotune.py races the two implementations on a tiny calibration
-workload and persists the winner (docs/ROMIX_KERNEL.md).  The flag
-``SPACEMESH_ROMIX=pallas`` forces this path.  Interpret mode verifies
-bit-exactness on CPU (tests/test_romix_pallas.py — the autotune sweep
-in tests/test_romix_autotune.py covers unaligned batches through the
+STATUS: Mosaic refuses this kernel on TPU — the 32-word minor dimension
+is a quarter of a 128-lane vreg row, and the ``[:, 16:17]`` Integerify
+staging slice is not aligned to that tiling (ROADMAP S4 records the
+compiler's messages; a VMEM layout that compiles is that item's work).
+So it is in NO default or raced set (ops/autotune.py): the explicit
+``SPACEMESH_ROMIX=pallas`` is the only way here, and it raises on
+failure.  Interpret mode verifies bit-exactness on CPU
+(tests/test_romix_pallas.py — the autotune sweep in
+tests/test_romix_autotune.py covers unaligned batches through the
 lane-padding wrapper).
 
 Reference workload: activation/post.go:27-61 (labels per unit),
@@ -41,11 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-
-try:  # pltpu resolves on TPU builds; interpret mode works without it
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover - non-TPU jaxlib
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 LANE_TILE = 128
 
@@ -161,20 +158,13 @@ def romix_pallas(x, *, n: int, lane_tile: int = LANE_TILE,
     that).  ``mix_phase=False`` stops after the fill phase — only the
     profiler's stage-split view uses it.
     """
-    if pltpu is None:
-        raise RuntimeError("pltpu unavailable: Pallas TPU support missing "
-                           "from this jaxlib")
     b = x.shape[1]
     if b % lane_tile:
         raise ValueError(f"batch {b} not a multiple of tile {lane_tile}")
     xt = x.T  # (B, 32) lanes major: one lane's row is contiguous
 
-    # scratch declarations use the current callable-memory-space form
-    # (pltpu.ANY(shape, dtype); the pl.ANY(...) call form was removed —
-    # pl.ANY is now the backend-neutral MemorySpace enum member, only
-    # valid as pl.BlockSpec(memory_space=pl.ANY))
     scratch = [
-        pltpu.ANY((n, lane_tile, 32), jnp.uint32),    # V (HBM)
+        pltpu.HBM((n, lane_tile, 32), jnp.uint32),    # V
         pltpu.VMEM((2, lane_tile, 32), jnp.uint32),   # fill double-buffer
         pltpu.VMEM((lane_tile, 32), jnp.uint32),      # gathered rows
         pltpu.SMEM((lane_tile, 1), jnp.uint32),       # per-lane j

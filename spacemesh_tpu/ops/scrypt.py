@@ -57,14 +57,13 @@ from __future__ import annotations
 
 import functools
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..utils import sanitize, tracing
+from ..utils import accel, sanitize, tracing
 from .sha256 import byteswap32, hmac_midstates, sha256_compress
 
 LABEL_BYTES = 16  # reference: 16-byte labels, 2^32 per 64 GiB unit
@@ -312,8 +311,6 @@ def _stage_finish(inner_mid, outer_mid, blk):
 
 # --- tuned dispatch -----------------------------------------------------
 
-_fallback_logged = False
-
 
 def _tunable(*arrays) -> bool:
     """Autotuned chunking/impl selection only applies when the inputs are
@@ -340,29 +337,27 @@ def _plan(n: int, batch: int, *arrays, impl: str | None = None,
 
     ``impl``/``chunk`` are caller overrides (the mesh entry points in
     parallel/mesh.py pass the raced mesh winner's layout through here);
-    they skip the autotune lookup and are only explicit in the
-    SPACEMESH_ROMIX sense when they MATCH an explicit env request — the
-    mesh callers forward decision.impl verbatim, so an operator's
-    SPACEMESH_ROMIX=pallas must keep its never-silently-fall-back
-    contract through the sharded path too."""
+    they skip the autotune lookup.
+
+    There is no fallback between impls: whichever kernel the decision
+    names either runs or raises (a Pallas kernel is only ever selected
+    by an explicit SPACEMESH_ROMIX=pallas — ops/autotune.py keeps it out
+    of every raced default set)."""
     from . import autotune
 
     platform = jax.default_backend()
-    interpret = platform != "tpu"
     if impl is not None:
         if chunk is not None and chunk >= batch:
             chunk = None
-        impl_env, _, _, _ = autotune.read_env()
-        d = autotune.Decision(impl, chunk, "caller",
-                              explicit_impl=impl == impl_env)
+        d = autotune.Decision(impl, chunk, "caller")
     elif not _tunable(*arrays):
         impl_env, chunk_env, chunk_set, _ = autotune.read_env()
         d = autotune.Decision(impl_env or "xla",
-                              chunk_env if chunk_set else None,
-                              "untuned", explicit_impl=impl_env is not None)
+                              chunk_env if chunk_set else None, "untuned")
     else:
         d = autotune.decide(n, batch, platform=platform)
-    return d, (interpret if d.impl == "pallas" else False)
+    # non-pallas impls keep interpret=False in their static jit key
+    return d, d.impl == "pallas" and accel.pallas_interpret()
 
 
 def _bucket_lanes(commitment_words, idx_lo, idx_hi):
@@ -395,43 +390,14 @@ def compiled_shape_count() -> int:
     return _labels_fused._cache_size() + _labels_min_fused._cache_size()
 
 
-def _pallas_failed(d, err: Exception):
-    """A Pallas selection failed to import/compile/run: raise when the
-    operator explicitly demanded it, otherwise log ONCE, count, and
-    return the XLA fallback decision."""
-    global _fallback_logged
-    from . import autotune
-    from ..utils import metrics
-
-    if d.impl != "pallas":
-        raise err
-    if d.explicit_impl:
-        raise RuntimeError(
-            f"{autotune.ENV_IMPL}=pallas was explicitly requested but the "
-            f"Pallas ROMix kernel failed ({type(err).__name__}: {err}); "
-            "refusing to silently degrade to the XLA path") from err
-    metrics.post_romix_fallback.inc(reason=type(err).__name__)
-    if not _fallback_logged:
-        _fallback_logged = True
-        print(f"romix: Pallas kernel failed ({type(err).__name__}: {err}); "
-              "falling back to XLA (counted in post_romix_fallback_total)",
-              file=sys.stderr, flush=True)
-    return autotune.Decision("xla", d.chunk, "fallback")
-
-
 def _stage_romix(blk, *, n: int):
     """ROMix stage dispatch under the autotuned (impl, chunk) decision.
 
     Kept for callers that run the stages separately; the fused pipelines
     below inline the same dispatch into one program."""
     d, interpret = _plan(n, blk.shape[1], blk)
-    try:
-        return romix_tuned(blk, n=n, impl=d.impl, chunk=d.chunk,
-                           interpret=interpret)
-    except Exception as e:  # noqa: BLE001 — pallas-only fallback, re-raised otherwise
-        d = _pallas_failed(d, e)
-        return romix_tuned(blk, n=n, impl=d.impl, chunk=d.chunk,
-                           interpret=False)
+    return romix_tuned(blk, n=n, impl=d.impl, chunk=d.chunk,
+                       interpret=interpret)
 
 
 # --- fused single-program pipelines -------------------------------------
@@ -477,15 +443,9 @@ def scrypt_labels_jit(commitment_words, idx_lo, idx_hi, *, n: int,
                       {"impl": d.impl, "chunk": d.chunk, "n": n,
                        "batch": batch}
                       if tracing.is_enabled() else None):
-        try:
-            words = _labels_fused(commitment_words, idx_lo, idx_hi, n=n,
-                                  impl=d.impl, chunk=d.chunk,
-                                  interpret=interpret)
-        except Exception as e:  # noqa: BLE001 — pallas-only fallback
-            d = _pallas_failed(d, e)
-            words = _labels_fused(commitment_words, idx_lo, idx_hi, n=n,
-                                  impl=d.impl, chunk=d.chunk,
-                                  interpret=False)
+        words = _labels_fused(commitment_words, idx_lo, idx_hi, n=n,
+                              impl=d.impl, chunk=d.chunk,
+                              interpret=interpret)
     return words if valid is None or valid == batch else words[:, :valid]
 
 
@@ -611,25 +571,13 @@ def scrypt_labels_with_min(commitment_words, idx_lo, idx_hi, carry, *,
     sanitize.on_jit_shape("labels_min_fused", batch)
     d, interpret = _plan(n, batch, commitment_words, idx_lo, idx_hi, carry,
                          impl=impl, chunk=chunk)
-    # a pallas attempt can fail AFTER compile (e.g. HBM exhaustion
-    # allocating the per-tile V scratch at dispatch), by which point the
-    # donated carry buffer is consumed — keep an independent (6,)-word
-    # device copy (async, no host sync: the streaming init keeps batches
-    # in flight) so the XLA fallback retry has a live carry to donate
-    backup = jnp.asarray(carry) + jnp.uint32(0) if d.impl == "pallas" else None
     with tracing.span("romix.dispatch",
                       {"impl": d.impl, "chunk": d.chunk, "n": n,
                        "batch": batch, "minscan": True}
                       if tracing.is_enabled() else None):
-        try:
-            words, new_carry, snap = _labels_min_fused(
-                commitment_words, idx_lo, idx_hi, carry, n=n, impl=d.impl,
-                chunk=d.chunk, interpret=interpret)
-        except Exception as e:  # noqa: BLE001 — pallas-only fallback
-            d = _pallas_failed(d, e)
-            words, new_carry, snap = _labels_min_fused(
-                commitment_words, idx_lo, idx_hi, backup, n=n, impl=d.impl,
-                chunk=d.chunk, interpret=False)
+        words, new_carry, snap = _labels_min_fused(
+            commitment_words, idx_lo, idx_hi, carry, n=n, impl=d.impl,
+            chunk=d.chunk, interpret=interpret)
     if valid is not None and valid != batch:
         words = words[:, :valid]
     return words, new_carry, snap

@@ -101,9 +101,7 @@ def cmd_benchmark(a) -> int:
     import numpy as np
 
     from ..ops import scrypt
-    from ..utils import accel
 
-    accel.enable_persistent_cache()
     dev = jax.devices()[0]
     cw = jnp.asarray(scrypt.commitment_to_words(bytes(32)))
     idx = np.arange(a.batch, dtype=np.uint64)
@@ -135,11 +133,8 @@ def cmd_serve(a) -> int:
     """
     import asyncio
 
-    from ..utils import accel
     from .prover import ProofParams
     from .remote import WorkerServer, discover_identities
-
-    accel.enable_persistent_cache()
 
     params = ProofParams(k1=a.k1, k2=a.k2, k3=a.k3,
                          pow_difficulty=bytes.fromhex(a.pow_difficulty))
@@ -256,6 +251,11 @@ def main(argv=None) -> int:
     ps.set_defaults(fn=cmd_serve)
 
     a = p.parse_args(argv)
+    from ..utils import accel
+
+    # every subcommand runs on the platform JAX gives it and says which
+    # (this also turns on the persistent compile cache)
+    accel.announce_platform("spacemesh_tpu.post")
     return a.fn(a)
 
 

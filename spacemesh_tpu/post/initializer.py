@@ -164,12 +164,14 @@ class Initializer:
     def _resolve_plan(self, batch_hint: int):
         """-> (mesh | None, autotune.Decision) for this session.
 
-        Real multi-device backends keep the historical behavior (shard
-        over the whole mesh). On the CPU fallback the autotuned winner
-        decides (ops/autotune.py mesh dimension): the op-dispatch-bound
-        label kernel usually wins sharded over the virtual host devices,
-        and the race has measured by how much on THIS host — zero
-        configuration, SPACEMESH_MESH still forces either way."""
+        An accelerator backend shards over every visible device (four
+        chips of a v5e host: four non-empty shards per batch, the same
+        store bytes as one chip — PERF.md Bring-up). On the CPU the
+        autotuned winner decides (ops/autotune.py mesh dimension): the
+        op-dispatch-bound label kernel usually wins sharded over the
+        virtual host devices, and the race has measured by how much on
+        THIS host — zero configuration, SPACEMESH_MESH still forces
+        either way."""
         from ..ops import autotune
 
         n = self.meta.scrypt_n
@@ -179,8 +181,8 @@ class Initializer:
             mesh = self._mesh_arg if self._mesh_arg.size > 1 else None
             return mesh, autotune.decide(n, batch_hint)
         # ONE definition of the auto routing, shared with post/prover.py
-        # (autotune.resolve_auto_mesh: tuned winner on the CPU fallback,
-        # whole mesh on real hardware, SPACEMESH_MESH forces either way)
+        # (autotune.resolve_auto_mesh: tuned winner on the CPU, every
+        # device on an accelerator, SPACEMESH_MESH forces either way)
         devs, d = autotune.resolve_auto_mesh(n, batch_hint)
         if devs is None:
             return None, d

@@ -49,7 +49,7 @@ import numpy as np
 from ..ops import pow as k2pow
 from ..ops import proving, proving_pallas, scrypt
 from ..runtime import engine
-from ..utils import metrics, tracing
+from ..utils import accel, metrics, tracing
 from .data import LabelStore, PostMetadata
 
 DEFAULT_NONCE_GROUP = 16
@@ -136,8 +136,12 @@ class Prover:
         self.params = params or ProofParams()
         self.nonce_group = nonce_group
         self._platform = jax.devices()[0].platform
-        if use_pallas is None:  # the Mosaic kernel path is TPU-only
-            use_pallas = self._platform == "tpu"
+        if use_pallas is None:
+            # the Pallas scan step (ops/proving_pallas.py) is the default
+            # wherever it runs compiled: under Mosaic it matched
+            # prove_scan_step_jit bit for bit on a v5e (PERF.md Bring-up);
+            # anywhere else it would only interpret
+            use_pallas = not accel.pallas_interpret()
         self.use_pallas = use_pallas
         # pipelined batches share one compiled shape: round the batch up to
         # the compaction segment (and the Pallas lane tile on that path),
@@ -271,7 +275,6 @@ class Prover:
         # padded-and-trimmed inside proving_pallas.proving_scan instead of
         # flipping to the XLA path mid-pass (one compiled shape per path)
         use_pallas = self.use_pallas
-        interpret = self._platform != "tpu"
         group = 0
         while True:
             hits: list[list[int]] = [[] for _ in range(ng)]
@@ -285,8 +288,7 @@ class Prover:
                 nonce0 = group * ng
                 if use_pallas:
                     mask = proving_pallas.proving_scan(
-                        challenge, nonce0, idx, labels, t, n_nonces=ng,
-                        interpret=interpret)
+                        challenge, nonce0, idx, labels, t, n_nonces=ng)
                 else:
                     lo, hi = scrypt.split_indices(idx)
                     lw = scrypt.labels_to_words(labels)
@@ -311,8 +313,18 @@ class Prover:
 
     # -- streaming pipeline -------------------------------------------------
 
+    def scan_step(self):
+        """The scan step a pipelined prove runs, bound ONCE per prove:
+        ``(step, mesh, impl)`` — the callable, the mesh it shards over
+        (None on one device) and which backend it is (``xla-sharded``,
+        ``pallas`` or ``xla``). ProveSession runs exactly this, and
+        chip_smoke.py reports and checks it."""
+        mesh = self._resolve_mesh()
+        impl = "xla-sharded" if mesh is not None else (
+            "pallas" if self.use_pallas else "xla")
+        return self._make_step(mesh), mesh, impl
+
     def _make_step(self, mesh):
-        """Bind the scan-step backend ONCE per prove (no per-batch paths)."""
         ng, cap = self.nonce_group, max(self.params.k2, 1)
         if mesh is not None:
             from ..parallel import mesh as pmesh
@@ -321,7 +333,7 @@ class Prover:
         if self.use_pallas:
             return functools.partial(
                 proving_pallas.prove_scan_step_pallas, n_nonces=ng,
-                max_hits=cap, interpret=self._platform != "tpu")
+                max_hits=cap, interpret=accel.pallas_interpret())
         return functools.partial(proving.prove_scan_step_jit,
                                  n_nonces=ng, max_hits=cap)
 
@@ -546,8 +558,8 @@ class ProveSession:
             thr = jnp.uint32(proving.threshold_u32(
                 p.params.k1, p.meta.total_labels))
             cw = jnp.asarray(proving.challenge_words(self.challenge))
-            mesh = p._resolve_mesh()
-            self._prep = (cw, thr, mesh, p._make_step(mesh))
+            stepfn, mesh, _ = p.scan_step()
+            self._prep = (cw, thr, mesh, stepfn)
         cw, thr, mesh, stepfn = self._prep
         if self._base >= self._max_nonce:
             raise RuntimeError("no winning nonce found (k1/k2 mismatch?)")

@@ -5,6 +5,11 @@ activation/post_supervisor.go:66-299: runCmd spawns the Rust post-service
 with its flags, captures logs, restarts it on exit until stopped). The
 worker here is this package's own CLI (`python -m spacemesh_tpu.post
 serve`), so one binary covers init/prove/verify/serve.
+
+Chip ownership: the worker proves on JAX, so on an accelerator host the
+worker is the ONE process that owns the chip — the process that starts a
+supervisor must not have opened it (node/app.py start_smeshing refuses
+the combination; tools/cluster.py pins its children to the CPU).
 """
 
 from __future__ import annotations
@@ -53,13 +58,10 @@ class PostSupervisor:
         env = dict(os.environ if self.env is None else self.env)
         repo_root = str(Path(__file__).resolve().parent.parent.parent)
         env["PYTHONPATH"] = repo_root + os.pathsep + env.get("PYTHONPATH", "")
-        # every (re)spawned worker shares the machine's persistent XLA
-        # compile cache — a crash-restart must not pay the per-shape
-        # compile again (utils/accel.py enable_persistent_cache)
-        if "SPACEMESH_JAX_CACHE" not in env:
-            cache = os.environ.get("SPACEMESH_JAX_CACHE")
-            if cache is not None:
-                env["SPACEMESH_JAX_CACHE"] = cache
+        # every (re)spawned worker shares the checkout's persistent XLA
+        # compile cache (utils/accel.py: a fixed in-checkout path, or
+        # JAX_COMPILATION_CACHE_DIR, which the env copy above carries) —
+        # a crash-restart does not pay the per-shape compile again
         # keep the worker's port stable across restarts so clients reconnect
         listen = self.listen
         if self.address is not None:

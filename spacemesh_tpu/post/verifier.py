@@ -37,7 +37,7 @@ class VerifyItem:
     total_labels: int
 
 
-def _k3_subset(item: VerifyItem, k3: int, seed: bytes) -> list[int]:
+def k3_subset(item: VerifyItem, k3: int, seed: bytes) -> list[int]:
     """K3-subsample of the proof's indices, keyed by the VERIFIER's seed.
 
     The seed must be unpredictable to the prover (reference
@@ -86,7 +86,7 @@ def verify_many(items: list[VerifyItem], params: ProofParams | None = None,
                                     p.pow_difficulty, pr.pow_nonce)):
             results[i] = False
             continue
-        for j in _k3_subset(it, p.k3, seed):
+        for j in k3_subset(it, p.k3, seed):
             flat_idx.append(j)
             flat_owner.append(i)
     if not flat_idx:

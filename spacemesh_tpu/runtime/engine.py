@@ -15,8 +15,9 @@ two callbacks:
     Enqueue device work for one item and return immediately (the ticket
     is whatever the retire side needs — device arrays, counts, byte
     offsets).  A raised exception is fed to the ``fallback`` hook when
-    one is configured (device-failure fallback, e.g. k2pow's host
-    re-hash) before it is allowed to kill the job.
+    the caller configured one, before it is allowed to kill the job.
+    No POST pipeline configures one: init, prove and k2pow raise on a
+    device failure instead of re-running the batch elsewhere unasked.
 
 ``retire(ticket) -> result | None``
     Block on the oldest in-flight ticket and consume its results.  A

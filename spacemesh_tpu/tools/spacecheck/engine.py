@@ -248,16 +248,15 @@ CACHE_VERSION = 1
 
 
 def default_cache_path() -> str:
-    """Beside the autotune winners file (ops/autotune.py cache_path),
-    derived without importing any jax-touching module — the analyzer
-    must stay runnable before dependency install."""
+    """Beside the autotune winners file, under the checkout's cache
+    root (utils/accel.py imports jax only inside functions — the
+    analyzer must stay runnable before dependency install)."""
     explicit = os.environ.get(CACHE_ENV)
     if explicit:
         return os.path.expanduser(explicit)
-    jax_cache = os.environ.get("SPACEMESH_JAX_CACHE") \
-        or "~/.cache/spacemesh_tpu/jax_cache"
-    root = os.path.dirname(os.path.expanduser(jax_cache))
-    return os.path.join(root, "spacecheck_cache.json")
+    from ...utils import accel
+
+    return str(accel.CACHE_ROOT / "spacecheck_cache.json")
 
 
 def _rules_digest() -> str:

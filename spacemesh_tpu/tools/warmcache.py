@@ -36,7 +36,7 @@ cache for every kind it can dispatch).  ``--no-runtime`` skips that.
 Usage:
   python -m spacemesh_tpu.tools.warmcache [--n 8192]
       [--batches 8192,4096,2048,1024,512] [--prove] [--no-mesh]
-      [--no-probe] [--cached-shapes] [--no-runtime]
+      [--cached-shapes] [--no-runtime]
       [--pack-lanes 4096]
   python -m spacemesh_tpu.tools.profiler --warm      # same, via profiler
 
@@ -197,15 +197,13 @@ def _warm_runtime_kinds(n: int, batch: int, pack_lanes: int) -> dict:
 
 def warm(n: int = 8192, batches: list[int] | None = None, *,
          mesh: bool = True, prove: bool = False,
-         cached_shapes: bool = False, probe: bool = True,
+         cached_shapes: bool = False,
          runtime_kinds: bool = True, pack_lanes: int = 4096) -> dict:
     """Warm the persistent caches; returns a JSON-able report."""
     import os
 
     from ..utils import accel
 
-    if probe and not accel.ensure_usable_platform():
-        _log("accelerator unreachable; warming the CPU fallback")
     if mesh and os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
         # BEFORE any backend use (jax.default_backend below instantiates
         # it): expose the virtual host devices the mesh winners run on
@@ -214,7 +212,7 @@ def warm(n: int = 8192, batches: list[int] | None = None, *,
 
     platform = jax.default_backend()
     cache_dir = accel.enable_persistent_cache()
-    _log(f"persistent compile cache: {cache_dir or 'DISABLED'}")
+    _log(f"persistent compile cache: {cache_dir}")
 
     from ..ops import autotune, scrypt
 
@@ -265,8 +263,6 @@ def main(argv=None) -> int:
     ap.add_argument("--cached-shapes", action="store_true",
                     help="also warm every shape with a persisted "
                     "autotune winner on this host")
-    ap.add_argument("--no-probe", action="store_true",
-                    help="skip the accelerator liveness probe (tests)")
     ap.add_argument("--no-runtime", action="store_true",
                     help="skip the registered runtime workload kinds "
                     "(fused/packed init, prove scan, verify, k2pow)")
@@ -276,7 +272,7 @@ def main(argv=None) -> int:
     a = ap.parse_args(argv)
     doc = warm(a.n, [int(b) for b in a.batches.split(",") if b],
                mesh=not a.no_mesh, prove=a.prove,
-               cached_shapes=a.cached_shapes, probe=not a.no_probe,
+               cached_shapes=a.cached_shapes,
                runtime_kinds=not a.no_runtime, pack_lanes=a.pack_lanes)
     print(json.dumps(doc, indent=2))
     return 0

@@ -329,14 +329,7 @@ post_mesh_shard_imbalance = REGISTRY.gauge(
     "post_mesh_shard_imbalance",
     "(max-min)/max per-shard fetch seconds of the last sharded batch")
 
-# ROMix label kernel (ops/scrypt.py dispatch + ops/autotune.py). The
-# fallback counter makes a Pallas selection that silently degraded to the
-# XLA path visible (an explicit SPACEMESH_ROMIX=pallas request raises
-# instead of counting here).
-post_romix_fallback = REGISTRY.counter(
-    "post_romix_fallback_total",
-    "Pallas ROMix selections that fell back to the XLA path "
-    "(label=reason)")
+# ROMix label kernel (ops/scrypt.py dispatch + ops/autotune.py)
 post_romix_autotune_races = REGISTRY.counter(
     "post_romix_autotune_races_total",
     "ROMix kernel autotune races run (persisted-winner cache misses)")

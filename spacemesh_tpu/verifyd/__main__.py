@@ -103,6 +103,9 @@ def main(argv=None) -> int:
                          "must verify under its clients' network "
                          "prefix (default: empty prefix)")
     args = ap.parse_args(argv)
+    from ..utils import accel
+
+    accel.announce_platform("spacemesh_tpu.verifyd")
     try:
         return asyncio.run(serve(args))
     except KeyboardInterrupt:

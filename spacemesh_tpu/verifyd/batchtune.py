@@ -81,18 +81,14 @@ def race_enabled() -> bool:
 
 
 def cache_path() -> str:
-    """The measured-rates file, colocated with the XLA compile cache
-    (the same placement rule as ops/autotune.cache_path)."""
+    """The measured-rates file, under the checkout's cache root (the
+    same placement rule as ops/autotune.cache_path)."""
     explicit = os.environ.get(ENV_CACHE)
     if explicit:
         return os.path.expanduser(explicit)
     from ..utils import accel
 
-    jax_cache = os.environ.get("SPACEMESH_JAX_CACHE")
-    if not jax_cache or jax_cache in _OFF:
-        jax_cache = accel.DEFAULT_CACHE_DIR
-    root = os.path.dirname(os.path.expanduser(jax_cache))
-    return os.path.join(root, "verifyd_batchtune.json")
+    return str(accel.CACHE_ROOT / "verifyd_batchtune.json")
 
 
 def _load_cache(path: str | None = None) -> dict:

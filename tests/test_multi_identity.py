@@ -98,3 +98,20 @@ def test_consensus_progressed_with_split_weight(ran):
     assert applied >= LPE + 1
     assert any(blockstore.ids_in_layer(ran.state, lyr)
                for lyr in range(LPE, applied + 1))
+
+
+def test_external_worker_refused_on_an_accelerator(tmp_path, monkeypatch):
+    """One process per chip: where JAX lands on an accelerator, the node
+    (which inits POST in-process) refuses to also babysit a worker that
+    needs the same chip. The platform decides, not an env var — the run
+    above is the CPU side of the same check."""
+    import jax
+
+    app = App(_config(tmp_path))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        with pytest.raises(RuntimeError, match="ONE process owns the chip"):
+            asyncio.run(app.start_smeshing())
+        assert app.post_supervisor is None
+    finally:
+        app.close()

@@ -76,7 +76,7 @@ def test_native_is_actually_fast(lib):
 def test_rebuild_on_stale_lib(tmp_path):
     """build.py recompiles when the source is newer than the .so."""
     src = native._DIR / "blake3.cpp"
-    lib_path = native._DIR / "libsmtpu_blake3.so"
+    lib_path = native._OUT / "libsmtpu_blake3.so"
     if not lib_path.exists():
         pytest.skip("no prior build")
     os.utime(src)  # source now newer

@@ -236,7 +236,7 @@ def test_profiler_pipeline_hook(capsys):
 
     from spacemesh_tpu.tools import profiler
 
-    doc = profiler.pipeline_benchmark(2, 512, 256, probe=False)
+    doc = profiler.pipeline_benchmark(2, 512, 256)
     json.dumps(doc)  # must be JSON-serializable
     assert doc["labels_per_sec"] > 0
     assert set(doc["stages"]) >= {"dispatch_s", "fetch_s",

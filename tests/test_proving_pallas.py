@@ -15,8 +15,7 @@ def test_pallas_scan_matches_reference():
     idx = np.arange(total, dtype=np.uint64)
     labels = scrypt.scrypt_labels(COMMIT, idx, n=2)
     t = proving.threshold_u32(200, total)
-    got = proving_pallas.proving_scan(CH, 5, idx, labels, t, n_nonces=4,
-                                      interpret=True)
+    got = proving_pallas.proving_scan(CH, 5, idx, labels, t, n_nonces=4)
     assert got.shape == (4, total)
     assert got.any(), "expected some qualifying labels at this threshold"
     for k in range(4):
@@ -30,8 +29,7 @@ def test_pallas_scan_padding():
     idx = np.arange(total, dtype=np.uint64)
     labels = scrypt.scrypt_labels(COMMIT, idx, n=2)
     t = proving.threshold_u32(100, total)
-    got = proving_pallas.proving_scan(CH, 0, idx, labels, t, n_nonces=2,
-                                      interpret=True)
+    got = proving_pallas.proving_scan(CH, 0, idx, labels, t, n_nonces=2)
     assert got.shape == (2, total)
     vals = proving.proving_hashes(CH, 0, idx, labels)
     assert np.array_equal(got[0], vals < t)
@@ -88,7 +86,7 @@ def test_step_equivalence_window_crossing_group_boundary():
     # nonce window straddling a group boundary (base 24 with 16 nonces
     # covers groups 1 and 2): both kernels must key every nonce correctly
     (xla, pallas), (want_counts, want_hits) = _step_both(
-        count=512, batch=512, nonce_base=24, n_nonces=16)
+        count=1024, batch=1024, nonce_base=24, n_nonces=16)
     for counts, hits in (xla, pallas):
         assert np.array_equal(counts, want_counts)
         assert hits == want_hits

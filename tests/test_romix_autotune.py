@@ -6,10 +6,10 @@ The decision surface under test (docs/ROMIX_KERNEL.md):
   env (SPACEMESH_ROMIX / SPACEMESH_ROMIX_CHUNK)  >  persisted winner
   >  race (persisted)  >  static default
 
-plus the Pallas failure contract: an explicit SPACEMESH_ROMIX=pallas
-request RAISES when the kernel cannot run, while an autotuned/cached
-pallas selection falls back to XLA once, logged and counted in
-post_romix_fallback_total.
+plus the Pallas contract: the kernel is in NO raced or persisted set
+(Mosaic refuses it on TPU — ROADMAP S4), an explicit SPACEMESH_ROMIX=pallas
+request is the only way to it and RAISES the kernel's own error when it
+cannot run, and nothing ever degrades from one impl to another.
 """
 
 import hashlib
@@ -95,7 +95,7 @@ def test_env_impl_beats_cached_winner(tuner, monkeypatch):
     assert autotune.decide(N, 64, platform="cpu").impl == "xla-rows"
     monkeypatch.setenv(autotune.ENV_IMPL, "xla")
     d = autotune.decide(N, 64, platform="cpu")
-    assert (d.impl, d.source, d.explicit_impl) == ("xla", "env", True)
+    assert (d.impl, d.source) == ("xla", "env")
     # env impl == cached impl inherits the cached chunk
     monkeypatch.setenv(autotune.ENV_IMPL, "xla-rows")
     assert autotune.decide(N, 64, platform="cpu").chunk == 2
@@ -189,26 +189,32 @@ def test_explicit_pallas_request_raises_on_failure(tuner, monkeypatch):
     _break_pallas(monkeypatch)
     monkeypatch.setenv(autotune.ENV_IMPL, "pallas")
     commitment = hashlib.sha256(b"pallas-must-raise").digest()
-    with pytest.raises(RuntimeError, match="explicitly requested"):
+    with pytest.raises(RuntimeError, match="mosaic exploded"):
         # unique (n, batch) shape so the jit cache cannot satisfy the
         # call without re-entering the (broken) pallas dispatch
         scrypt.scrypt_labels(commitment, np.arange(5, dtype=np.uint64), n=4)
 
 
-def test_cached_pallas_winner_falls_back_and_counts(tuner, monkeypatch):
-    from spacemesh_tpu.utils import metrics
-
+def test_pallas_is_never_selected_unasked(tuner, monkeypatch):
+    """The new contract in place of fall-back-and-count: no platform's
+    raced grid holds a pallas row, and a winners file that names pallas
+    (hand-edited, or written by an older build) is not served — so a
+    broken Pallas kernel cannot be reached, and cannot be 'recovered
+    from', without SPACEMESH_ROMIX=pallas."""
+    for platform in ("cpu", "tpu", "gpu"):
+        grid = autotune.candidates(platform, 8192, 8192, mesh_cap=8)
+        assert grid and all(impl != "pallas" for impl, _, _ in grid), \
+            platform
     _break_pallas(monkeypatch)
     # decisions are keyed by the BUCKETED batch — the executable shape a
     # 6-lane call actually runs at (ops/scrypt.py shape_bucket)
     _seed(tuner, autotune._key("cpu", 4, scrypt.shape_bucket(6)),
           "pallas", None)
-    before = sum(metrics.post_romix_fallback._values.values())
-    commitment = hashlib.sha256(b"pallas-falls-back").digest()
+    d = autotune.decide(4, scrypt.shape_bucket(6), platform="cpu")
+    assert d.impl != "pallas" and d.source == "race"
+    commitment = hashlib.sha256(b"pallas-not-served").digest()
     got = scrypt.scrypt_labels(commitment, np.arange(6, dtype=np.uint64),
                                n=4)
     want = hashlib.scrypt(commitment, salt=(2).to_bytes(8, "little"),
                           n=4, r=1, p=1, dklen=16)
-    assert bytes(got[2]) == want, "XLA fallback result wrong"
-    after = sum(metrics.post_romix_fallback._values.values())
-    assert after == before + 1, "fallback not counted"
+    assert bytes(got[2]) == want

@@ -1,9 +1,10 @@
-"""Pallas ROMix race candidate: bit-exact vs the XLA path + hashlib.
+"""Pallas ROMix kernel: bit-exact vs the XLA path + hashlib.
 
 Interpret mode on CPU (the kernel's DMA orchestration runs in the
-Pallas interpreter); on TPU the same call compiles via Mosaic — the
-SPACEMESH_ROMIX=pallas flag races the two implementations on identical
-inputs (docs/ROUND2_NOTES.md "Pallas ROMix" analysis).
+Pallas interpreter). On TPU Mosaic refuses the kernel's VMEM layout
+(ROADMAP S4), so SPACEMESH_ROMIX=pallas — the only way onto this path —
+raises there; these tests keep the algorithm checked until a layout
+that compiles replaces it.
 """
 
 import hashlib

@@ -375,16 +375,20 @@ def test_k2pow_on_engine_matches_serial_twin():
     assert k2pow.search(ch, nid, bytes(32), batch=64, max_batches=2) is None
 
 
-def test_k2pow_host_fallback_identical(monkeypatch):
+def test_k2pow_device_failure_raises(monkeypatch):
+    """No host re-hash unasked: a device failure in the k2pow search
+    surfaces as the device's own error, and nothing counts a fallback."""
     from spacemesh_tpu.ops import pow as k2pow
 
     ch = hashlib.sha256(b"rt-pow-fb-c").digest()
     nid = hashlib.sha256(b"rt-pow-fb-n").digest()
     diff = bytes([0, 16]) + bytes([255]) * 30
-    want = k2pow.search(ch, nid, diff, batch=2048)
 
     def boom(*a, **k):
         raise RuntimeError("device down")
 
     monkeypatch.setattr(k2pow, "pow_hash_batch_jit", boom)
-    assert k2pow.search(ch, nid, diff, batch=2048) == want
+    before = sum(metrics.runtime_fallbacks.sample().values())
+    with pytest.raises(RuntimeError, match="device down"):
+        k2pow.search(ch, nid, diff, batch=2048)
+    assert sum(metrics.runtime_fallbacks.sample().values()) == before

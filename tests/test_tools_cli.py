@@ -258,14 +258,14 @@ def test_profiler_lists_providers_and_recommends(capsys):
 
     from spacemesh_tpu.tools import profiler
 
-    assert profiler.main(["--providers", "--no-probe"]) == 0
+    assert profiler.main(["--providers"]) == 0
     doc = _json.loads(capsys.readouterr().out)
     ids = [p["id"] for p in doc["providers"]]
     assert "cpu:openssl" in ids
     assert any(i.startswith("jax:") for i in ids)
 
     assert profiler.main(["--n", "2", "--batches", "32", "--reps", "1",
-                          "--cpu-labels", "4", "--no-probe"]) == 0
+                          "--cpu-labels", "4"]) == 0
     doc = _json.loads(capsys.readouterr().out)
     assert doc["scrypt_n"] == 2
     rec = doc["recommendation"]
@@ -285,8 +285,7 @@ def test_profiler_verify_benchmark(capsys):
 
     from spacemesh_tpu.tools import profiler
 
-    assert profiler.main(["--verify", "--verify-batches", "10,20",
-                          "--no-probe"]) == 0
+    assert profiler.main(["--verify", "--verify-batches", "10,20"]) == 0
     doc = _json.loads(capsys.readouterr().out)
     rates = doc["verify"]
     assert [r["batch"] for r in rates] == [10, 20]
@@ -303,7 +302,7 @@ def test_profiler_pipeline_stage_timings(capsys):
 
     assert profiler.main(["--pipeline", "--n", "2",
                           "--pipeline-labels", "512",
-                          "--pipeline-batch", "256", "--no-probe"]) == 0
+                          "--pipeline-batch", "256"]) == 0
     doc = _json.loads(capsys.readouterr().out)
     assert doc["labels_per_sec"] > 0
     assert set(doc["stages"]) >= {"dispatch_s", "fetch_s",

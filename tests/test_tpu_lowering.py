@@ -1,0 +1,138 @@
+"""What the chip's compiler accepts, checked without a chip.
+
+The installed libtpu can AOT-compile for a ``v5e:2x2`` topology with no
+device attached, so the programs the ``tpu`` default path runs are
+lowered and compiled here, under ``JAX_PLATFORMS=cpu``, exactly as the
+chip will get them (``interpret=False``, Mosaic for Pallas). It proves
+they COMPILE — that they also match their references on the chip is
+chip_smoke.py's job.
+
+Everything happens in a SUBPROCESS: a Mosaic layout failure is a SIGABRT
+(``Check failed``), not an exception, and must not take pytest down.
+The test skips only when the topology itself cannot be built.
+"""
+
+import json
+import os
+import subprocess
+import sys
+from pathlib import Path
+
+import pytest
+
+REPO = Path(__file__).resolve().parent.parent
+NO_TOPOLOGY = 77
+
+_CHILD = r"""
+import json, sys
+import jax, jax.numpy as jnp, numpy as np
+from jax.experimental import topologies
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception as e:
+    print(f"no v5e topology: {type(e).__name__}: {e}", file=sys.stderr)
+    sys.exit(77)
+
+from spacemesh_tpu.ops import pow as k2pow
+from spacemesh_tpu.ops import proving, proving_pallas, scrypt
+from spacemesh_tpu.post import prover
+
+dev0 = SingleDeviceSharding(topo.devices[0])
+u32 = jnp.uint32
+N = 8192
+out = {"device_kind": topo.devices[0].device_kind,
+       "devices": len(topo.devices)}
+
+
+def sds(shape, dtype=u32, sharding=dev0):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def build(name, fn, *args, **kw):
+    c = fn.lower(*args, **kw).compile()
+    m = c.memory_analysis()
+    out[name] = {"temp": m.temp_size_in_bytes,
+                 "all_gather": "all-gather" in c.as_text()}
+
+
+# init: the fused label + VRF min-scan program at mainnet N (a small
+# batch keeps the compile to seconds; the shape is otherwise the chip's)
+b = 128
+build("labels_min_fused", scrypt._labels_min_fused, sds((8,)), sds((b,)),
+      sds((b,)), sds((scrypt.VRF_CARRY_WORDS,)), n=N, impl="xla",
+      chunk=None, interpret=False)
+
+# prove: both scan steps the Prover can bind on tpu (Pallas is the
+# single-device default, XLA the sharded one and the reference), at the
+# CLI's batch width, nonce group and K2
+b, ng, cap = 1 << 14, prover.DEFAULT_NONCE_GROUP, 37
+step_args = (sds((8,)), sds(()), sds((b,)), sds((b,)), sds((4, b)),
+             sds(()), sds((ng,), jnp.int32), sds((2, ng, cap)), sds(()),
+             sds(()), sds(()))
+build("prove_step_xla", proving.prove_scan_step_jit, *step_args,
+      n_nonces=ng, max_hits=cap)
+build("prove_step_pallas", proving_pallas.prove_scan_step_pallas,
+      *step_args, n_nonces=ng, max_hits=cap, interpret=False)
+build("prove_mask_pallas", proving_pallas.proving_scan_pallas,
+      *step_args[:6], n_nonces=ng, interpret=False)
+
+# k2pow: search (one 2^16-nonce batch) and batched witness verification
+b = 1 << 16
+build("pow_hash", k2pow.pow_hash_batch_jit, sds((8,)), sds((b,)), sds((b,)))
+build("pow_below_target", k2pow.below_target_jit, sds((8, b)), sds((8,)))
+b = 256
+build("pow_verify", k2pow.pow_verify_batch_jit, sds((16, b)), sds((b,)),
+      sds((b,)), sds((8, b)))
+
+# four chips: the label batch lane-sharded over the 2x2 host
+b = 2048
+label_kw = dict(n=N, impl="xla", chunk=None, interpret=False)
+build("labels_single", scrypt._labels_fused, sds((8,)), sds((b,)),
+      sds((b,)), **label_kw)
+mesh = Mesh(np.array(topo.devices), ("data",))
+lanes = NamedSharding(mesh, P("data"))
+build("labels_sharded", scrypt._labels_fused,
+      sds((8,), sharding=NamedSharding(mesh, P())),
+      sds((b,), sharding=lanes), sds((b,), sharding=lanes), **label_kw)
+
+print(json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=str(REPO) + os.pathsep
+               + os.environ.get("PYTHONPATH", ""))
+    r = subprocess.run([sys.executable, "-c", _CHILD], env=env, cwd=REPO,
+                       capture_output=True, text=True, timeout=600)
+    if r.returncode == NO_TOPOLOGY:
+        pytest.skip("libtpu cannot build a v5e:2x2 topology here: "
+                    + r.stderr.strip().splitlines()[-1])
+    assert r.returncode == 0, (
+        f"AOT compile for v5e died (rc={r.returncode}; a negative rc is "
+        f"a signal — Mosaic aborts with SIGABRT):\n{r.stderr[-3000:]}")
+    return json.loads(r.stdout.strip().splitlines()[-1])
+
+
+def test_tpu_default_programs_compile_for_v5e(lowered):
+    assert lowered["devices"] == 4
+    # the Pallas scan step is the tpu default (and stays reachable via
+    # use_pallas=True), so Mosaic must keep compiling it
+    for name in ("labels_min_fused", "prove_step_xla", "prove_step_pallas",
+                 "prove_mask_pallas", "pow_hash", "pow_below_target",
+                 "pow_verify"):
+        assert name in lowered, name
+
+
+def test_labels_shard_over_four_chips_without_collectives(lowered):
+    single, sharded = lowered["labels_single"], lowered["labels_sharded"]
+    assert not sharded["all_gather"], \
+        "the lane-sharded label program gathers across chips"
+    # V dominates temp and shards with the lanes: a quarter per device
+    ratio = sharded["temp"] / single["temp"]
+    assert 0.2 < ratio < 0.3, ratio
