@@ -19,11 +19,13 @@ lower = harder) so operator configs translate directly.
 from __future__ import annotations
 
 import functools
+import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils import tracing
 from .sha256 import IV, sha256_compress
 
 # Message layout: challenge(32) || node_id(32) || le64(nonce) = 72 bytes
@@ -36,6 +38,7 @@ def _words_be(data: bytes) -> np.ndarray:
 
 
 @jax.jit
+@jax.named_scope("pow_sha256")   # op metadata: the phase's name in a trace
 def pow_hash_batch_jit(prefix_state, nonce_lo, nonce_hi):
     """SHA-256 over the second block for a (B,) batch of nonces.
 
@@ -164,6 +167,7 @@ def below_targets_jit(digest_words, target_words):
 
 
 @jax.jit
+@jax.named_scope("pow_sha256")
 def pow_verify_batch_jit(block1, nonce_lo, nonce_hi, target_words):
     """Verify a (B,) batch of (challenge, node_id, nonce, difficulty)
     witnesses in one two-block SHA-256 pass.
@@ -249,12 +253,20 @@ def verify_many(items: list, *, batch: int = 1 << 12,
         nonces = np.array([x[3] for x in rows], dtype=np.uint64)
         lo = jnp.asarray((nonces & 0xFFFFFFFF).astype(np.uint32))
         hi = jnp.asarray((nonces >> 32).astype(np.uint32))
-        return rng, pow_verify_batch_jit(
-            jnp.asarray(block1), lo, hi, jnp.asarray(targets))
+        b1, tg = jnp.asarray(block1), jnp.asarray(targets)
+        # the ticket carries the enqueue instant to retire, which closes
+        # the device.flight span
+        t0 = time.perf_counter_ns()
+        return rng, pow_verify_batch_jit(b1, lo, hi, tg), t0
 
     def retire(ticket):
-        (lo_i, hi_i), ok = ticket
-        results[lo_i:hi_i] = np.asarray(ok)[:hi_i - lo_i]
+        (lo_i, hi_i), ok, t0 = ticket
+        host = np.asarray(ok)
+        tracing.interval("device.flight", t0,
+                         {"program": "pow_verify", "lanes": host.shape[0],
+                          "d2h_bytes": host.nbytes}
+                         if tracing.is_enabled() else None)
+        results[lo_i:hi_i] = host[:hi_i - lo_i]
         return None
 
     pipe = engine.Pipeline(kind="k2pow_verify", tenant=tenant,

@@ -66,20 +66,22 @@ def proving_hash_jit(challenge_words, nonce, idx_lo, idx_hi, label_words):
     Returns (B,) u32 hash values.
     """
     b = idx_lo.shape[0]
-    ch = challenge_words.astype(jnp.uint32)
-    if ch.ndim == 1:
-        ch = ch[:, None]
-    ch = jnp.broadcast_to(ch, (8, b))
-    nv = jnp.broadcast_to(jnp.asarray(nonce, jnp.uint32).reshape(-1), (b,))
-    state = jnp.concatenate([
-        ch,
-        nv[None],
-        idx_lo[None],
-        idx_hi[None],
-        jnp.zeros((1, b), jnp.uint32),
-        label_words,
-    ])
-    return salsa20_8(state)[0]
+    with jax.named_scope("proving_hash"):   # op metadata: the phase's name
+        ch = challenge_words.astype(jnp.uint32)
+        if ch.ndim == 1:
+            ch = ch[:, None]
+        ch = jnp.broadcast_to(ch, (8, b))
+        nv = jnp.broadcast_to(
+            jnp.asarray(nonce, jnp.uint32).reshape(-1), (b,))
+        state = jnp.concatenate([
+            ch,
+            nv[None],
+            idx_lo[None],
+            idx_hi[None],
+            jnp.zeros((1, b), jnp.uint32),
+            label_words,
+        ])
+        return salsa20_8(state)[0]
 
 
 def _scan_mask(challenge_words, nonce_base, idx_lo, idx_hi, label_words,

@@ -57,6 +57,7 @@ from __future__ import annotations
 
 import functools
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -159,7 +160,8 @@ def romix_r1(x, n: int, *, mix_phase: bool = True):
         v = lax.dynamic_update_slice_in_dim(v, xx[None], i, axis=0)
         return v, blockmix_r1(xx)
 
-    v, x = lax.fori_loop(0, n, fill, (v0, x))
+    with jax.named_scope("romix_fill"):
+        v, x = lax.fori_loop(0, n, fill, (v0, x))
     if not mix_phase:
         return x
 
@@ -170,7 +172,8 @@ def romix_r1(x, n: int, *, mix_phase: bool = True):
         )[0]
         return blockmix_r1(xx ^ vj)
 
-    return lax.fori_loop(0, n, mix, x)
+    with jax.named_scope("romix_mix"):
+        return lax.fori_loop(0, n, mix, x)
 
 
 def romix_r1_rows(x, n: int, *, mix_phase: bool = True):
@@ -190,7 +193,8 @@ def romix_r1_rows(x, n: int, *, mix_phase: bool = True):
         v = lax.dynamic_update_slice_in_dim(v, xx.T, i * b, axis=0)
         return v, blockmix_r1(xx)
 
-    v, x = lax.fori_loop(0, n, fill, (v0, x))
+    with jax.named_scope("romix_fill"):
+        v, x = lax.fori_loop(0, n, fill, (v0, x))
     if not mix_phase:
         return x
     lanes = jnp.arange(b, dtype=jnp.uint32)
@@ -201,7 +205,8 @@ def romix_r1_rows(x, n: int, *, mix_phase: bool = True):
         vj = jnp.take(v, rows, axis=0)  # (B, 32): contiguous per lane
         return blockmix_r1(xx ^ vj.T)
 
-    return lax.fori_loop(0, n, mix, x)
+    with jax.named_scope("romix_mix"):
+        return lax.fori_loop(0, n, mix, x)
 
 
 def _romix_chunked(fn, x, n: int, chunk: int | None, **kw):
@@ -272,6 +277,12 @@ def _pbkdf2_first(inner_mid, outer_mid, idx_lo, idx_hi):
     return byteswap32(jnp.concatenate(out))  # repack BE digests as LE words
 
 
+# The device phases carry names (jax.named_scope: op metadata only, the
+# HLO and its fusion are the same) so a device trace can be read by
+# phase: pbkdf2_expand, romix_fill, romix_mix, pbkdf2_finish, minscan.
+
+
+@jax.named_scope("pbkdf2_finish")
 def _pbkdf2_second(inner_mid, outer_mid, b_le):
     """PBKDF2(pw, salt=B'||be32(1), c=1) -> 32-byte digests, (8, B) u32 BE."""
     b = b_le.shape[1]
@@ -285,6 +296,7 @@ def _pbkdf2_second(inner_mid, outer_mid, b_le):
     return _hmac_finish(outer_mid, st)
 
 
+@jax.named_scope("pbkdf2_expand")
 def _expand(commitment_words, idx_lo, idx_hi):
     # commitment_words: (8,) shared across the batch, or (8, B) per-lane
     # (the batched verifier recomputes labels of many smeshers at once)
@@ -370,16 +382,21 @@ def _bucket_lanes(commitment_words, idx_lo, idx_hi):
     if bb == b:
         return commitment_words, idx_lo, idx_hi, b
     pad = bb - b
-    idx_lo = jnp.concatenate(
-        [jnp.asarray(idx_lo), jnp.broadcast_to(jnp.asarray(idx_lo)[-1:],
-                                               (pad,))])
-    idx_hi = jnp.concatenate(
-        [jnp.asarray(idx_hi), jnp.broadcast_to(jnp.asarray(idx_hi)[-1:],
-                                               (pad,))])
-    cw = jnp.asarray(commitment_words)
-    if cw.ndim == 2:  # per-lane commitments: repeat the last column too
-        cw = jnp.concatenate(
-            [cw, jnp.broadcast_to(cw[:, -1:], (cw.shape[0], pad))], axis=1)
+    # eager device ops, each its own small program: the span says what
+    # they cost the host while the device runs something else
+    with tracing.span("romix.pad", {"valid": b, "batch": bb}
+                      if tracing.is_enabled() else None):
+        idx_lo = jnp.concatenate(
+            [jnp.asarray(idx_lo),
+             jnp.broadcast_to(jnp.asarray(idx_lo)[-1:], (pad,))])
+        idx_hi = jnp.concatenate(
+            [jnp.asarray(idx_hi),
+             jnp.broadcast_to(jnp.asarray(idx_hi)[-1:], (pad,))])
+        cw = jnp.asarray(commitment_words)
+        if cw.ndim == 2:  # per-lane commitments: repeat the last column too
+            cw = jnp.concatenate(
+                [cw, jnp.broadcast_to(cw[:, -1:], (cw.shape[0], pad))],
+                axis=1)
     return cw, idx_lo, idx_hi, b
 
 
@@ -416,6 +433,37 @@ def _labels_fused(commitment_words, idx_lo, idx_hi, *, n: int, impl: str,
     return _pbkdf2_second(inner_mid, outer_mid, blk)[:4]
 
 
+def _labels_enqueue(commitment_words, idx_lo, idx_hi, *, n: int,
+                    impl: str | None = None, chunk: int | None = None):
+    """:func:`scrypt_labels_jit` that also says when the label program
+    was enqueued and at what width: ``(words, t0_ns, batch)``. The
+    caller that fetches ``words`` closes the ``device.flight`` span
+    from ``t0_ns`` (:func:`_run`)."""
+    valid = None
+    if _tunable(commitment_words, idx_lo, idx_hi):
+        commitment_words, idx_lo, idx_hi, valid = _bucket_lanes(
+            commitment_words, idx_lo, idx_hi)
+    batch = int(idx_lo.shape[0])
+    sanitize.on_jit_shape("labels_fused", batch)
+    d, interpret = _plan(n, batch, commitment_words, idx_lo, idx_hi,
+                         impl=impl, chunk=chunk)
+    t0 = time.perf_counter_ns()
+    # the span covers the ENQUEUE (trace+compile on a cache miss, else
+    # async dispatch) — device time shows up in the XLA trace, which the
+    # SPACEMESH_TRACE_JAX bridge lines these spans up against
+    with tracing.span("romix.dispatch",
+                      {"impl": d.impl, "chunk": d.chunk, "n": n,
+                       "batch": batch,
+                       "valid": batch if valid is None else valid}
+                      if tracing.is_enabled() else None):
+        words = _labels_fused(commitment_words, idx_lo, idx_hi, n=n,
+                              impl=d.impl, chunk=d.chunk,
+                              interpret=interpret)
+    if valid is not None and valid != batch:
+        words = words[:, :valid]
+    return words, t0, batch
+
+
 def scrypt_labels_jit(commitment_words, idx_lo, idx_hi, *, n: int,
                       impl: str | None = None, chunk: int | None = None):
     """Batch of labels. ``idx_lo/idx_hi``: (B,) u32 halves of label indices.
@@ -428,25 +476,8 @@ def scrypt_labels_jit(commitment_words, idx_lo, idx_hi, *, n: int,
     the bucket's executable instead of compiling their own
     (:func:`shape_bucket`; sharded/traced inputs skip the pad — mesh
     callers pre-bucket on host)."""
-    valid = None
-    if _tunable(commitment_words, idx_lo, idx_hi):
-        commitment_words, idx_lo, idx_hi, valid = _bucket_lanes(
-            commitment_words, idx_lo, idx_hi)
-    batch = int(idx_lo.shape[0])
-    sanitize.on_jit_shape("labels_fused", batch)
-    d, interpret = _plan(n, batch, commitment_words, idx_lo, idx_hi,
-                         impl=impl, chunk=chunk)
-    # the span covers the ENQUEUE (trace+compile on a cache miss, else
-    # async dispatch) — device time shows up in the XLA trace, which the
-    # SPACEMESH_TRACE_JAX bridge lines these spans up against
-    with tracing.span("romix.dispatch",
-                      {"impl": d.impl, "chunk": d.chunk, "n": n,
-                       "batch": batch}
-                      if tracing.is_enabled() else None):
-        words = _labels_fused(commitment_words, idx_lo, idx_hi, n=n,
-                              impl=d.impl, chunk=d.chunk,
-                              interpret=interpret)
-    return words if valid is None or valid == batch else words[:, :valid]
+    return _labels_enqueue(commitment_words, idx_lo, idx_hi, n=n,
+                           impl=impl, chunk=chunk)[0]
 
 
 # --- on-device VRF-nonce scan ----------------------------------------------
@@ -495,6 +526,7 @@ def vrf_carry_decode(carry) -> tuple[int, tuple[int, int]] | None:
     return int(c[4]) << 32 | int(c[5]), (hi, lo)
 
 
+@jax.named_scope("minscan")
 def _minscan(words, idx_lo, idx_hi, carry):
     # LE-u128 key limbs, most significant first (labels are LE bytes; the
     # (4, B) words are BE within each 4-byte group, so byteswap gives the
@@ -573,7 +605,8 @@ def scrypt_labels_with_min(commitment_words, idx_lo, idx_hi, carry, *,
                          impl=impl, chunk=chunk)
     with tracing.span("romix.dispatch",
                       {"impl": d.impl, "chunk": d.chunk, "n": n,
-                       "batch": batch, "minscan": True}
+                       "batch": batch, "minscan": True,
+                       "valid": batch if valid is None else valid}
                       if tracing.is_enabled() else None):
         words, new_carry, snap = _labels_min_fused(
             commitment_words, idx_lo, idx_hi, carry, n=n, impl=d.impl,
@@ -617,8 +650,18 @@ def _run(cw: np.ndarray, indices: np.ndarray, n: int) -> np.ndarray:
     if indices.size == 0:
         return np.zeros((0, LABEL_BYTES), dtype=np.uint8)
     lo, hi = split_indices(indices)
-    words = scrypt_labels_jit(jnp.asarray(cw), jnp.asarray(lo), jnp.asarray(hi), n=n)
-    out = np.frombuffer(labels_to_bytes(words), dtype=np.uint8)
+    with tracing.span("romix.upload",
+                      {"bytes": cw.nbytes + lo.nbytes + hi.nbytes}
+                      if tracing.is_enabled() else None):
+        jcw, jlo, jhi = jnp.asarray(cw), jnp.asarray(lo), jnp.asarray(hi)
+    words, t0, batch = _labels_enqueue(jcw, jlo, jhi, n=n)
+    host = np.asarray(words, dtype=np.uint32)   # blocks: the words land
+    # the stretch in which the host had the label program outstanding
+    tracing.interval("device.flight", t0,
+                     {"program": "labels_fused", "lanes": batch,
+                      "d2h_bytes": host.nbytes}
+                     if tracing.is_enabled() else None)
+    out = np.frombuffer(labels_to_bytes(host), dtype=np.uint8)
     return out.reshape(-1, LABEL_BYTES)
 
 

@@ -426,7 +426,9 @@ class Prover:
                 kind="prove", tenant=tenant, inflight=self.inflight,
                 span="prove",
                 attrs=lambda it: {"window": nonce_base, "start": it[0],
-                                  "count": it[1]})
+                                  "count": it[1]},
+                retire_attrs=lambda tk: {"window": nonce_base,
+                                         "end": tk[0]})
             rw0 = stats.read_wait_s
             res = pipe.run(ranges, dispatch, retire)
             exited = res is not None
@@ -465,14 +467,12 @@ class Prover:
         p = self.params
         ng = self.nonce_group
         tr = time.perf_counter()
-        with tracing.span("prove.retire",
-                          {"window": nonce_base, "end": scanned_end}
-                          if tracing.is_enabled() else None):
-            for g, bc in enumerate(bcs):
-                vec = np.asarray(bc)
-                host_counts[g * ng:(g + 1) * ng] += vec
-                stats.d2h_bytes += vec.nbytes
-                metrics.post_prove_d2h_bytes.inc(vec.nbytes)
+        # the engine's prove.retire span (runtime/engine.py) is open here
+        for g, bc in enumerate(bcs):
+            vec = np.asarray(bc)
+            host_counts[g * ng:(g + 1) * ng] += vec
+            stats.d2h_bytes += vec.nbytes
+            metrics.post_prove_d2h_bytes.inc(vec.nbytes)
         stats.retire_s += time.perf_counter() - tr
         qualified = host_counts >= p.k2
         if not qualified.any():

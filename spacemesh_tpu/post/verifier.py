@@ -16,12 +16,14 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
+import time
 
 import numpy as np
 
 from ..ops import autotune
 from ..ops import pow as k2pow
 from ..ops import proving, scrypt
+from ..utils import tracing
 from .prover import Proof, ProofParams
 
 
@@ -66,29 +68,60 @@ def verify_many(items: list[VerifyItem], params: ProofParams | None = None,
     is drawn per call so provers cannot predict which indices get checked.
     Pass an explicit seed only for reproducible verification (tests,
     deterministic replay).
+
+    Traced as one ``post.verify`` span whose attributes count the call
+    where the work happens (docs/OBSERVABILITY.md): distinct ``proofs``
+    (a proof object repeated in ``items`` is the farm's power-of-two
+    padding, not a proof), those ``host_rejected``, the ``lanes_valid``
+    K3 indices they send to the device, the ``lanes`` dispatched after
+    both paddings, blocking device->host ``syncs``, ``h2d_bytes`` and
+    ``d2h_bytes``.
     """
     import os
 
     p = params or ProofParams()
     if seed is None:
         seed = os.urandom(32)
+    # the span holds this dict: filled in as the call goes
+    tr = ({"proofs": 0, "host_rejected": 0, "lanes_valid": 0, "lanes": 0,
+           "syncs": 0, "h2d_bytes": 0, "d2h_bytes": 0}
+          if tracing.is_enabled() else None)
+    with tracing.span("post.verify", tr):
+        return _verify_many(items, p, seed, tr)
+
+
+def _verify_many(items: list[VerifyItem], p: ProofParams, seed: bytes,
+                 tr: dict | None) -> list[bool]:
     results = [True] * len(items)
 
     # 1) structural + pow checks (host, cheap)
     flat_idx: list[int] = []
     flat_owner: list[int] = []
-    for i, it in enumerate(items):
-        pr = it.proof
-        if (len(pr.indices) < p.k2
-                or len(set(pr.indices)) != len(pr.indices)
-                or any(not (0 <= j < it.total_labels) for j in pr.indices)
-                or not k2pow.verify(it.challenge, it.node_id,
-                                    p.pow_difficulty, pr.pow_nonce)):
-            results[i] = False
-            continue
-        for j in k3_subset(it, p.k3, seed):
-            flat_idx.append(j)
-            flat_owner.append(i)
+    seen: set[int] = set()
+    with tracing.span("post.verify.checks",
+                      {"proofs": len(items)} if tr is not None else None):
+        for i, it in enumerate(items):
+            pr = it.proof
+            first = tr is not None and id(it) not in seen
+            if first:
+                seen.add(id(it))
+                tr["proofs"] += 1
+            if (len(pr.indices) < p.k2
+                    or len(set(pr.indices)) != len(pr.indices)
+                    or any(not (0 <= j < it.total_labels)
+                           for j in pr.indices)
+                    or not k2pow.verify(it.challenge, it.node_id,
+                                        p.pow_difficulty, pr.pow_nonce)):
+                results[i] = False
+                if first:
+                    tr["host_rejected"] += 1
+                continue
+            subset = k3_subset(it, p.k3, seed)
+            if first:
+                tr["lanes_valid"] += len(subset)
+            for j in subset:
+                flat_idx.append(j)
+                flat_owner.append(i)
     if not flat_idx:
         return results
 
@@ -96,80 +129,122 @@ def verify_many(items: list[VerifyItem], params: ProofParams | None = None,
     # scrypt_n must be uniform per compiled program; group by n (usually 1).
     import jax.numpy as jnp
 
-    owners = np.array(flat_owner)
-    idx = np.array(flat_idx, dtype=np.uint64)
-    commits = np.stack([
-        np.frombuffer(items[o].commitment, dtype=np.uint8) for o in flat_owner])
-    chals = np.stack([
-        np.frombuffer(items[o].challenge, dtype="<u4").astype(np.uint32)
-        for o in flat_owner]).T  # (8, B)
-    nonces = np.array([items[o].proof.nonce for o in flat_owner], dtype=np.uint32)
-    values = np.empty(len(idx), dtype=np.uint32)
+    with tracing.span("post.verify.pack"):
+        owners = np.array(flat_owner)
+        idx = np.array(flat_idx, dtype=np.uint64)
+        commits = np.stack([
+            np.frombuffer(items[o].commitment, dtype=np.uint8)
+            for o in flat_owner])
+        chals = np.stack([
+            np.frombuffer(items[o].challenge, dtype="<u4").astype(np.uint32)
+            for o in flat_owner]).T  # (8, B)
+        nonces = np.array([items[o].proof.nonce for o in flat_owner],
+                          dtype=np.uint32)
+        values = np.empty(len(idx), dtype=np.uint32)
     for n in sorted({items[o].scrypt_n for o in flat_owner}):
-        sel = np.array([items[o].scrypt_n == n for o in flat_owner])
-        # pad the flat batch to its power-of-two shape bucket (repeat
-        # lane 0, trim after): an unbucketed pass would compile one
-        # executable per DISTINCT spot-check count — farm batches at
-        # varying occupancy turned every new flat count into a fresh
-        # XLA compile
-        b = int(sel.sum())
-        bb = scrypt.shape_bucket(b)
-        pad = bb - b
+        with tracing.span("post.verify.pack"):
+            sel = np.array([items[o].scrypt_n == n for o in flat_owner])
+            # pad the flat batch to its power-of-two shape bucket (repeat
+            # lane 0, trim after): an unbucketed pass would compile one
+            # executable per DISTINCT spot-check count — farm batches at
+            # varying occupancy turned every new flat count into a fresh
+            # XLA compile
+            b = int(sel.sum())
+            bb = scrypt.shape_bucket(b)
+            pad = bb - b
 
-        def _pad(a, axis=0):
-            reps = np.take(a, [0], axis=axis)
-            return np.concatenate(
-                [a, np.repeat(reps, pad, axis=axis)], axis=axis)
+            def _pad(a, axis=0):
+                reps = np.take(a, [0], axis=axis)
+                return np.concatenate(
+                    [a, np.repeat(reps, pad, axis=axis)], axis=axis)
 
-        lo, hi = scrypt.split_indices(idx[sel])
-        # the shared tuned mesh routing (SPACEMESH_MESH forces; CPU
-        # consults the raced winner) — the verify farm's batch recompute
-        # is a label batch like any other, so it shards like one
-        devs, d = autotune.resolve_auto_mesh(n, bb)
-        if devs is not None and len(devs) > 1 and bb % len(devs) == 0:
+            lo, hi = scrypt.split_indices(idx[sel])
+            # the shared tuned mesh routing (SPACEMESH_MESH forces; CPU
+            # consults the raced winner) — the verify farm's batch
+            # recompute is a label batch like any other, so it shards
+            # like one
+            devs, d = autotune.resolve_auto_mesh(n, bb)
+            sharded = (devs is not None and len(devs) > 1
+                       and bb % len(devs) == 0)
+            if sharded:
+                # mesh callers pre-bucket on host (ops/scrypt.py
+                # _tunable): pad BEFORE the label recompute so one
+                # sharded executable serves every occupancy at this
+                # bucket
+                cw8 = commits[sel].view(">u4").astype(np.uint32).T  # (8, b)
+                chal_b, nonce_b = chals[:, sel], nonces[sel]
+                if pad:
+                    cw8, chal_b = _pad(cw8, axis=1), _pad(chal_b, axis=1)
+                    nonce_b, lo, hi = _pad(nonce_b), _pad(lo), _pad(hi)
+        if tr is not None:
+            tr["lanes"] += bb
+        if sharded:
             from ..parallel import mesh as pmesh
 
-            # mesh callers pre-bucket on host (ops/scrypt.py _tunable):
-            # pad BEFORE the label recompute so one sharded executable
-            # serves every occupancy at this bucket
-            cw8 = commits[sel].view(">u4").astype(np.uint32).T  # (8, b)
-            chal_b, nonce_b = chals[:, sel], nonces[sel]
-            if pad:
-                cw8, chal_b = _pad(cw8, axis=1), _pad(chal_b, axis=1)
-                nonce_b, lo, hi = _pad(nonce_b), _pad(lo), _pad(hi)
             mesh = pmesh.data_mesh(devs)
             # sharded label words feed the sharded proving hash directly
             # — no host bytes round-trip between the two programs. The
             # label pipeline emits BE word groups; the proving hash eats
             # LE (what labels_to_bytes->labels_to_words round-trips on
             # the single-device path), so swap on device.
+            t0 = time.perf_counter_ns()
             lw_dev = pmesh.words_to_le(pmesh.scrypt_labels_sharded(
                 mesh, cw8, lo, hi, n=n, impl=d.impl))
             lay = pmesh.topology.get().layouts_for(mesh)
             vals = np.asarray(proving.proving_hash_jit(
                 lay.put_lane(chal_b), lay.put_batch(nonce_b),
-                lay.put_batch(lo), lay.put_batch(hi), lw_dev))[:b]
+                lay.put_batch(lo), lay.put_batch(hi), lw_dev))
+            if tr is not None:
+                # one flight: the label words never come to the host
+                tracing.interval("device.flight", t0,
+                                 {"program": "labels_proving_sharded",
+                                  "lanes": bb, "d2h_bytes": vals.nbytes})
+                tr["syncs"] += 1
+                tr["h2d_bytes"] += (cw8.nbytes + chal_b.nbytes
+                                    + nonce_b.nbytes + lo.nbytes
+                                    + hi.nbytes)
+                tr["d2h_bytes"] += vals.nbytes
+            vals = vals[:b]
         else:
+            # its device.flight is recorded in ops/scrypt._run
             labels = scrypt.scrypt_labels_multi(commits[sel], idx[sel], n=n)
-            lw = scrypt.labels_to_words(labels)
-            if pad:
-                chal_b = _pad(chals[:, sel], axis=1)
-                nonce_b = _pad(nonces[sel])
-                lo, hi = _pad(lo), _pad(hi)
-                lw = _pad(lw, axis=1)
-            else:
-                chal_b, nonce_b = chals[:, sel], nonces[sel]
-            vals = np.asarray(proving.proving_hash_jit(
-                jnp.asarray(chal_b), jnp.asarray(nonce_b),
-                jnp.asarray(lo), jnp.asarray(hi), jnp.asarray(lw)))[:b]
+            # the round trip the sharded twin does not make: label
+            # bytes on the host -> LE words -> padded -> back up
+            with tracing.span("post.verify.relayout") as rsp:
+                lw = scrypt.labels_to_words(labels)
+                if pad:
+                    chal_b = _pad(chals[:, sel], axis=1)
+                    nonce_b = _pad(nonces[sel])
+                    lo, hi = _pad(lo), _pad(hi)
+                    lw = _pad(lw, axis=1)
+                else:
+                    chal_b, nonce_b = chals[:, sel], nonces[sel]
+                args = (jnp.asarray(chal_b), jnp.asarray(nonce_b),
+                        jnp.asarray(lo), jnp.asarray(hi), jnp.asarray(lw))
+                if tr is not None:
+                    rsp.set(bytes=labels.nbytes + lw.nbytes)
+            t0 = time.perf_counter_ns()
+            vals = np.asarray(proving.proving_hash_jit(*args))
+            if tr is not None:
+                tracing.interval("device.flight", t0,
+                                 {"program": "proving_hash", "lanes": bb,
+                                  "d2h_bytes": vals.nbytes})
+                tr["syncs"] += 2
+                # up: commitments and indices for the labels (b lanes),
+                # then the proving hash's five inputs (bb lanes)
+                tr["h2d_bytes"] += (40 * b + chal_b.nbytes + nonce_b.nbytes
+                                    + lo.nbytes + hi.nbytes + lw.nbytes)
+                tr["d2h_bytes"] += labels.nbytes + vals.nbytes
+            vals = vals[:b]
         values[sel] = vals
 
     # 3) threshold check per item
-    thr = np.array([proving.threshold_u32(p.k1, items[o].total_labels)
-                    for o in flat_owner], dtype=np.uint64)
-    bad_owners = set(owners[values >= thr].tolist())
-    for o in bad_owners:
-        results[o] = False
+    with tracing.span("post.verify.threshold"):
+        thr = np.array([proving.threshold_u32(p.k1, items[o].total_labels)
+                        for o in flat_owner], dtype=np.uint64)
+        bad_owners = set(owners[values >= thr].tolist())
+        for o in bad_owners:
+            results[o] = False
     return results
 
 

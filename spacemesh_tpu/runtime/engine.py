@@ -116,8 +116,10 @@ class Pipeline:
                   (callers that still own their own, e.g. during
                   migration tests).  Dispatch spans are named
                   ``f"{span}.dispatch"`` so existing timeline tooling
-                  (trace-smoke CI, profiler --timeline) keeps matching.
+                  (trace-smoke CI, profiler --timeline) keeps matching;
+                  the blocking side is ``f"{span}.retire"``.
     ``attrs``     ``item -> dict`` extra dispatch-span attributes.
+    ``retire_attrs`` ``ticket -> dict`` extra retire-span attributes.
     ``on_inflight`` depth hook (pipeline-specific gauges).
     """
 
@@ -127,6 +129,7 @@ class Pipeline:
                  breaker=None,
                  span: str | None = None,
                  attrs: Optional[Callable[[Any], dict]] = None,
+                 retire_attrs: Optional[Callable[[Any], dict]] = None,
                  on_inflight: Optional[Callable[[int], None]] = None):
         self.kind = kind
         self.tenant = tenant
@@ -136,6 +139,7 @@ class Pipeline:
         self._breaker = breaker
         self._span = span
         self._attrs = attrs
+        self._retire_attrs = retire_attrs
         self._on_inflight = on_inflight
         self.stats = PipelineStats()
         self._pending: deque = deque()
@@ -156,15 +160,20 @@ class Pipeline:
         if self._on_inflight is not None:
             self._on_inflight(n)
 
+    def _stage_span(self, stage: str, extra, arg):
+        """The ``<span>.<stage>`` span of one item or ticket."""
+        if self._span is None:
+            return tracing._NOP
+        attrs = None
+        if tracing.is_enabled():
+            attrs = {"kind": self.kind, "tenant": self.tenant}
+            if extra is not None:
+                attrs.update(extra(arg))
+        return tracing.span(f"{self._span}.{stage}", attrs)
+
     def _dispatch_one(self, dispatch, item):
         t0 = time.perf_counter()
-        attrs = None
-        if self._span is not None and tracing.is_enabled():
-            attrs = {"kind": self.kind, "tenant": self.tenant}
-            if self._attrs is not None:
-                attrs.update(self._attrs(item))
-        sp = (tracing.span(f"{self._span}.dispatch", attrs)
-              if self._span is not None else tracing._NOP)
+        sp = self._stage_span("dispatch", self._attrs, item)
         br = self._breaker
         with sp:
             if br is not None and not br.allow():
@@ -202,7 +211,8 @@ class Pipeline:
     def _retire_one(self, retire, ticket):
         t0 = time.perf_counter()
         try:
-            return retire(ticket)
+            with self._stage_span("retire", self._retire_attrs, ticket):
+                return retire(ticket)
         finally:
             self.stats.retire_s += time.perf_counter() - t0
             metrics.runtime_retired.inc(kind=self.kind, tenant=self.tenant)

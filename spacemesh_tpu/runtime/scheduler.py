@@ -129,10 +129,16 @@ class _Job:
     """A worker-pool job: runs as one or more quanta."""
 
     __slots__ = ("handle", "tenant", "kind", "fn", "deadline", "cancelled",
-                 "gang", "abort")
+                 "gang", "abort", "queued_at", "req")
 
     def __init__(self, handle: JobHandle, tenant: _Tenant, kind: str, fn,
-                 deadline: float | None, gang: bool = False, abort=None):
+                 deadline: float | None, gang: bool = False, abort=None,
+                 req=None):
+        # when it (re)entered its tenant's queue, and the submitter's
+        # request identifier: the runtime.quantum span's queue_wait_ms
+        # and req
+        self.queued_at = time.perf_counter()
+        self.req = req
         self.handle = handle
         self.tenant = tenant
         self.kind = kind
@@ -425,15 +431,17 @@ class TenantScheduler:
         return t, handle
 
     def submit_call(self, tid: str, fn, *, kind: str = "call",
-                    deadline_s: float | None = None) -> JobHandle:
+                    deadline_s: float | None = None,
+                    req=None) -> JobHandle:
         """Generic single-quantum job: ``fn()`` runs on a worker; its
-        return value resolves the handle."""
+        return value resolves the handle.  ``req`` is the submitter's
+        request identifier, recorded on the ``runtime.quantum`` span."""
         with self._lock:
             t, handle = self._admit(tid, kind)
             job = _Job(handle, t, kind,
                        lambda: ("done", fn()),
                        None if deadline_s is None
-                       else self._now() + deadline_s)
+                       else self._now() + deadline_s, req=req)
             self._jobs[handle.id] = job
             t.jobs.append(job)
             self._work.notify()
@@ -660,11 +668,16 @@ class TenantScheduler:
         # second state channel)
         if job.gang:
             self._gang.acquire()
+        attrs = None
+        if tracing.is_enabled():
+            attrs = {"tenant": job.tenant.id, "kind": job.kind,
+                     "job": job.handle.id,
+                     "queue_wait_ms": round(
+                         (t0 - job.queued_at) * 1e3, 3)}
+            if job.req is not None:
+                attrs["req"] = job.req
         try:
-            with tracing.span("runtime.quantum",
-                              {"tenant": job.tenant.id, "kind": job.kind,
-                               "job": job.handle.id}
-                              if tracing.is_enabled() else None):
+            with tracing.span("runtime.quantum", attrs):
                 try:
                     outcome, result = job.fn()
                 except Exception as exc:  # noqa: BLE001 — job fails, pool survives
@@ -686,6 +699,7 @@ class TenantScheduler:
                     # multi-quantum job continues ahead of the tenant's
                     # own later jobs (per-job FIFO), fair share decides
                     # across tenants
+                    job.queued_at = time.perf_counter()
                     job.tenant.jobs.appendleft(job)
                 self._work.notify()
             if error is not None:

@@ -280,11 +280,7 @@ REGISTRY = Registry()
 # curated "public" metrics (reference metrics/public/public.go)
 layer_gauge = REGISTRY.gauge("node_current_layer", "wall-clock layer")
 verified_gauge = REGISTRY.gauge("tortoise_verified_layer", "verified frontier")
-post_init_seconds = REGISTRY.histogram("post_init_seconds",
-                                       "POST init session duration")
 proofs_generated = REGISTRY.counter("post_proofs_generated", "proofs made")
-proofs_verified = REGISTRY.counter("post_proofs_verified",
-                                   "proofs verified (label=result)")
 peers_gauge = REGISTRY.gauge("p2p_connected_peers", "connected peers")
 sync_state_gauge = REGISTRY.gauge(
     "sync_state", "0 notSynced, 1 gossipSync, 2 synced")

@@ -41,6 +41,12 @@ Controls:
   ``jax.profiler.TraceAnnotation`` so host spans line up with XLA
   device traces inside a ``jax.profiler.trace()`` capture on TPU.
 
+``interval(name, t0_ns)`` records a completed span from a start the
+caller took earlier (a device program's flight: enqueued here, fetched
+there). From the first ``start()`` in a process that has imported JAX,
+every trace / lower / backend compile / cache retrieval JAX reports
+lands as an ``xla.compile`` span under the span that caused it.
+
 Span linkage in the export: every event's ``args`` carries its ``id``
 and its ``parent`` id; cross-cutting links that are not parent/child
 (a verify-farm batch and its member requests) are recorded as explicit
@@ -66,6 +72,7 @@ import contextvars
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 
@@ -239,6 +246,7 @@ class Tracer:
         self._tid_names = {}
         self._started_at = time.time()
         self.enabled = True
+        _listen_for_compiles()
 
     def stop(self) -> int:
         """Stop recording; the ring stays exportable. Returns the number
@@ -279,6 +287,16 @@ class Tracer:
             return
         self._record(name, cat, time.perf_counter_ns() // 1000, 0,
                      next(self._ids), _current.get(), attrs, "i")
+
+    def interval(self, name: str, t0_ns: int, attrs=None,
+                 cat: str = "host") -> None:
+        """A completed span from a start the caller took earlier
+        (``time.perf_counter_ns()``), ending now."""
+        if not self.enabled:
+            return
+        t1 = time.perf_counter_ns()
+        self._record(name, cat, t0_ns // 1000, max(t1 - t0_ns, 0) // 1000,
+                     next(self._ids), _current.get(), attrs, "X")
 
     def span(self, name: str, attrs=None, parent=None, cat: str = "host"):
         if not self.enabled:
@@ -329,6 +347,49 @@ class Tracer:
 TRACER = Tracer()
 
 
+# --- which span compiled (jax.monitoring duration events) ---------------
+
+_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval",
+}
+# tracing one program reports a trace event for every jnp function it
+# calls, thousands of a few microseconds each, under the one that matters
+_COMPILE_FLOOR_S = 1e-3
+_compile_listening = False
+
+
+def _on_compile(event: str, secs: float, **kw) -> None:
+    # JAX calls this on the compiling thread the moment the timed part
+    # ends, so the current span is the one that caused it
+    if not TRACER.enabled or secs < _COMPILE_FLOOR_S:
+        return
+    short = _COMPILE_EVENTS.get(event)
+    if short is None:
+        return
+    attrs = {"event": short}
+    if kw.get("fun_name"):
+        attrs["fun"] = str(kw["fun_name"])
+    TRACER.interval("xla.compile",
+                    time.perf_counter_ns() - int(secs * 1e9), attrs)
+
+
+def _listen_for_compiles() -> None:
+    """Register :func:`_on_compile` once with ``jax.monitoring``. Only
+    in a process that has imported JAX already: one that has not (a sim
+    shard) has nothing to compile and should not pay the import; a later
+    ``start()`` tries again."""
+    global _compile_listening
+    if _compile_listening or "jax" not in sys.modules:
+        return
+    import jax.monitoring
+
+    jax.monitoring.register_event_duration_secs_listener(_on_compile)
+    _compile_listening = True
+
+
 # --- module-level convenience API (what instrumented code calls) --------
 
 
@@ -352,6 +413,18 @@ def span(name: str, attrs=None, parent=None, cat: str = "host"):
 def instant(name: str, attrs=None, cat: str = "host") -> None:
     if TRACER.enabled:
         TRACER.instant(name, attrs, cat)
+
+
+def interval(name: str, t0_ns: int, attrs=None, cat: str = "host") -> None:
+    """Record a completed span that began at ``t0_ns`` (a
+    ``time.perf_counter_ns()`` the caller read earlier) and ends now;
+    parent = the current span. For work that starts in one place and is
+    collected in another (a device program enqueued, its result fetched
+    later, sometimes on a later loop turn): a context manager held open
+    across that stretch would re-parent every span opened in between.
+    It does not touch the contextvar and enters no ``TraceAnnotation``."""
+    if TRACER.enabled:
+        TRACER.interval(name, t0_ns, attrs, cat)
 
 
 def start(capacity: int | None = None, jax_bridge: bool | None = None) -> None:

@@ -396,8 +396,11 @@ class VerificationFarm:
 
     # --- submission ---------------------------------------------------
 
-    async def submit(self, req, lane: Lane = Lane.GOSSIP) -> bool:
-        """Queue one verification and await its verdict."""
+    async def submit(self, req, lane: Lane = Lane.GOSSIP, *,
+                     trace_req=None) -> bool:
+        """Queue one verification and await its verdict. ``trace_req``
+        is the caller's request identifier, recorded as ``req`` on the
+        ``farm.request`` span (verifyd hands its request's)."""
         if self._closed:
             raise FarmClosed("farm closed")
         self._bind()
@@ -424,11 +427,13 @@ class VerificationFarm:
             async with tracing.span(
                     "farm.request",
                     {"kind": req.kind, "lane": lane.name.lower(),
-                     "dedup": True, "twin": ent.span.id}
+                     "dedup": True, "twin": ent.span.id,
+                     "req": trace_req}
                     if tracing.is_enabled() else None):
                 return await self._await(ent.future)
         sp = tracing.span("farm.request",
-                          {"kind": req.kind, "lane": lane.name.lower()}
+                          {"kind": req.kind, "lane": lane.name.lower(),
+                           "req": trace_req}
                           if tracing.is_enabled() else None)
         with sp:
             # backpressure: a full lane blocks ITS OWN submitters only
@@ -474,7 +479,7 @@ class VerificationFarm:
                 # one loop turn so same-tick submitters (gather bursts)
                 # land in this batch
                 await asyncio.sleep(0)
-                await self._coalesce(kind, st)
+                reason = await self._coalesce(kind, st)
                 if self._closed:
                     break
                 # take() is NOT capped at the tuned target: the target
@@ -488,7 +493,13 @@ class VerificationFarm:
                 if not batch:
                     continue
                 self._on_taken(batch)
-                task = self._loop.create_task(self._dispatch(kind, batch))
+                # why this batch, this size, now (farm.batch attributes)
+                why = ({"reason": reason, "inflight": len(st.inflight),
+                        "target": self._batch_limit(kind),
+                        "left": st.lanes.count()}
+                       if tracing.is_enabled() else None)
+                task = self._loop.create_task(
+                    self._dispatch(kind, batch, why))
                 st.inflight.add(task)
                 task.add_done_callback(st.inflight.discard)
         except asyncio.CancelledError:
@@ -517,8 +528,11 @@ class VerificationFarm:
         return bool(self._tuner.dispatch_now(kind, n,
                                              max(now - oldest, 0.0)))
 
-    async def _coalesce(self, kind: str, st: _KindState) -> None:
-        """Hold the batch open until it is worth dispatching.
+    async def _coalesce(self, kind: str, st: _KindState) -> str | None:
+        """Hold the batch open until it is worth dispatching; returns
+        the clause that let it go (``full`` | ``idle`` | ``deadline`` |
+        ``tuner``, or ``block`` when only a pending BLOCK request got it
+        past the in-flight cap), None when there is nothing to take.
 
         Dispatch NOW when: the batch is full (the per-kind tuned target
         when a batch tuner is attached); the backend is idle (a lone
@@ -532,31 +546,34 @@ class VerificationFarm:
         while not self._closed:
             n = st.lanes.count()
             if n == 0:
-                return
+                return None
             # the in-flight cap gates EVERY dispatch (a full batch too:
             # spawning the whole backlog at once would flood the worker
             # pool and anything submitted later — block-critical work
             # included — would queue behind sleeping threads). Only a
             # pending BLOCK request bypasses the cap.
-            can_go = (len(st.inflight) < self.max_inflight
-                      or bool(st.lanes.lanes[Lane.BLOCK]))
+            under_cap = len(st.inflight) < self.max_inflight
+            can_go = under_cap or bool(st.lanes.lanes[Lane.BLOCK])
             now = self._loop.time()
             if self._tuner is None:
                 # static policy: full batch, idle fast-path, deadline
-                go = (n >= self.max_batch
-                      or not st.inflight
-                      or st.lanes.earliest_deadline() <= now)
+                go = ("full" if n >= self.max_batch
+                      else "idle" if not st.inflight
+                      else "deadline"
+                      if st.lanes.earliest_deadline() <= now else None)
             else:
                 # tuned policy: the idle fast-path routes through the
                 # speculative model too — under service load an idle
                 # backend must not slice a filling batch into
                 # fragments, and with no model yet (or arrivals gone
                 # quiet) dispatch_now returns the fast-path answer
-                go = (n >= self._batch_limit(kind)
-                      or st.lanes.earliest_deadline() <= now
-                      or self._tuner_go(kind, st, n, now))
+                go = ("full" if n >= self._batch_limit(kind)
+                      else "deadline"
+                      if st.lanes.earliest_deadline() <= now
+                      else "tuner" if self._tuner_go(kind, st, n, now)
+                      else None)
             if can_go and go:
-                return
+                return go if under_cap else "block"
             st.arrived.clear()
             arr = self._loop.create_task(st.arrived.wait())
             waits = {arr} | set(st.inflight)
@@ -589,14 +606,16 @@ class VerificationFarm:
                 wait, kind=p.req.kind)
             p.span.set(queue_wait_ms=round(wait * 1e3, 3))
 
-    async def _dispatch(self, kind: str, batch: list[_Pending]) -> None:
+    async def _dispatch(self, kind: str, batch: list[_Pending],
+                        why: dict | None = None) -> None:
         # the batch span is the hub of the capture: its args carry the
         # member request-span ids, and each member span records the
         # batch id back — so in a Perfetto export a request's wall time
         # decomposes into lane wait vs its batch's backend dispatch
         bsp = tracing.span("farm.batch",
                            {"kind": kind, "n": len(batch),
-                            "members": [p.span.id for p in batch]}
+                            "members": [p.span.id for p in batch],
+                            **(why or {})}
                            if tracing.is_enabled() else None)
         for p in batch:
             p.span.set(batch=bsp.id)

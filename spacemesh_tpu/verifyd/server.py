@@ -35,6 +35,7 @@ import json
 
 from aiohttp import web
 
+from ..utils import tracing
 from ..utils.metrics import REGISTRY
 from . import protocol
 from .service import Shed, VerifydClosed, VerifydService
@@ -251,18 +252,37 @@ class VerifydServer:
                                   "unregistered": bool(gone)})
 
     async def verify(self, req) -> web.Response:
-        body = await self._body(req)
-        try:
-            return web.json_response(await self._do_verify(body))
-        except protocol.ProtocolError as e:
-            raise web.HTTPBadRequest(text=str(e))
-        except Shed as e:
-            return _shed_response(e)
-        except VerifydClosed as e:
-            return web.json_response(
-                Shed(protocol.SHED_SHUTTING_DOWN, str(e),
-                     replica_hint=self.service.replica_hint).to_doc(),
-                status=503)
+        # the root of one request's span tree: body read to response
+        # object; service.verify's spans take this span's id as `req`
+        with tracing.span("verifyd.http") as sp:
+            status, items, resp = "aborted", 0, None
+            try:
+                body = await self._body(req)
+                if isinstance(body, dict) \
+                        and isinstance(body.get("items"), list):
+                    items = len(body["items"])
+                try:
+                    resp = web.json_response(await self._do_verify(body))
+                    status = "ok"
+                except protocol.ProtocolError as e:
+                    raise web.HTTPBadRequest(text=str(e))
+                except Shed as e:
+                    status, resp = e.reason, _shed_response(e)
+                except VerifydClosed as e:
+                    status = protocol.SHED_SHUTTING_DOWN
+                    resp = web.json_response(
+                        Shed(status, str(e),
+                             replica_hint=self.service.replica_hint
+                             ).to_doc(), status=503)
+                return resp
+            except web.HTTPBadRequest:
+                status = "bad_request"
+                raise
+            finally:
+                if tracing.is_enabled():
+                    sp.set(req=sp.id, bytes_in=req.content_length or 0,
+                           bytes_out=len(resp.body) if resp is not None
+                           else 0, items=items, status=status)
 
     async def stats(self, req) -> web.Response:
         del req

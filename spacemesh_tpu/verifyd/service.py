@@ -31,6 +31,8 @@ each admitted request opens a ``verifyd.request`` span; the drain
 coroutine re-parents into it across the scheduler's worker-thread hop
 (``verifyd.drain``), so a client request decomposes through
 ``farm.request`` into its ``farm.batch`` in one Perfetto timeline.
+Every span of one request (``verifyd.request``, ``runtime.quantum``,
+``verifyd.drain``, each ``farm.request``) carries the same ``req``.
 
 Shutdown (``aclose``) stops admission (``shutting_down`` sheds), drains
 admitted work, then closes the scheduler and farm — zero stranded
@@ -470,9 +472,17 @@ class VerifydService:
                  if tracing.is_enabled() else None)
         if attrs is not None and trace_parent:
             attrs["link"] = trace_parent
+        # the request's identifier on every span of its tree: the id of
+        # the span this call runs under (verifyd.http behind the HTTP
+        # server), else this request span's own
+        req_id = tracing.current_id()
         sp = tracing.span("verifyd.request", attrs)
         with sp:
             parent = sp.id if tracing.is_enabled() else None
+            if req_id is None:
+                req_id = parent
+            if attrs is not None:
+                attrs["req"] = req_id
             loop = asyncio.get_running_loop()
             self._loop = loop
 
@@ -481,12 +491,13 @@ class VerifydService:
                 # into the farm (on the loop) and wait for verdicts —
                 # the wall cost charges the client's fair-share vtime
                 return asyncio.run_coroutine_threadsafe(
-                    self._drain_into_farm(reqs, lane, parent),
+                    self._drain_into_farm(reqs, lane, parent, req_id),
                     loop).result()  # spacecheck: ok=SC002 sync method runs on a scheduler worker thread, not the loop
 
             try:
                 handle = self.scheduler.submit_call(
-                    cid, quantum, kind="verifyd", deadline_s=deadline_s)
+                    cid, quantum, kind="verifyd", deadline_s=deadline_s,
+                    req=req_id)
             except QuotaExceeded as exc:
                 self._shed(c, cid, protocol.SHED_QUOTA, str(exc),
                            retry_after_s=self.estimated_wait_s())
@@ -565,18 +576,20 @@ class VerifydService:
             return verdicts
 
     async def _drain_into_farm(self, reqs: list, lane: Lane,
-                               parent) -> list[bool]:
+                               parent, req_id=None) -> list[bool]:
         # run_coroutine_threadsafe copies the WORKER thread's context,
         # so the request span must be re-established explicitly — the
         # farm.request spans below then parent into it, and their
         # farm.batch linkage closes the client->batch causal chain
         async with tracing.span("verifyd.drain",
                                 {"n": len(reqs),
-                                 "lane": lane.name.lower()}
+                                 "lane": lane.name.lower(),
+                                 "req": req_id}
                                 if tracing.is_enabled() else None,
                                 parent=parent):
             return list(await asyncio.gather(
-                *(self.farm.submit(r, lane) for r in reqs)))
+                *(self.farm.submit(r, lane, trace_req=req_id)
+                  for r in reqs)))
 
     # -- introspection --------------------------------------------------
 

@@ -54,6 +54,88 @@ def test_disabled_span_call_is_cheap():
     assert dt < 1.0, f"disabled span path too slow: {dt:.3f}s / 200k"
 
 
+# --- interval() and the compile listener --------------------------------
+
+
+def _names(doc):
+    return [e["name"] for e in doc["traceEvents"] if e["ph"] != "M"]
+
+
+@pytest.mark.parametrize("on", [True, False], ids=["on", "off"])
+def test_interval_records_parent_and_attrs(on):
+    """A completed span from an earlier start: parent is the current
+    span, the contextvar is untouched; off, nothing is recorded."""
+    tracing.start(capacity=64)
+    if not on:
+        tracing.stop()
+    t0 = time.perf_counter_ns()
+    with tracing.span("outer") as outer:
+        with tracing.span("between"):
+            pass
+        tracing.interval("device.flight", t0, {"program": "p", "lanes": 4})
+        assert tracing.current_id() == outer.id
+    doc = tracing.export()
+    tracing.validate(doc)
+    if not on:
+        assert _names(doc) == []
+        return
+    evs = {e["name"]: e for e in doc["traceEvents"] if e["ph"] != "M"}
+    flight = evs["device.flight"]
+    assert flight["ph"] == "X"
+    assert flight["args"]["parent"] == outer.id
+    assert flight["args"]["program"] == "p" and flight["args"]["lanes"] == 4
+    # it began before the span that is its parent, and was not re-parented
+    # under (nor did it re-parent) the span opened in between
+    assert flight["ts"] <= evs["outer"]["ts"]
+    assert evs["between"]["args"]["parent"] == outer.id
+    assert flight["ts"] + flight["dur"] >= \
+        evs["between"]["ts"] + evs["between"]["dur"]
+
+
+@pytest.mark.parametrize("on", [True, False], ids=["on", "off"])
+def test_fresh_jit_shape_leaves_an_xla_compile_child(on):
+    """Which span recompiled: JAX's own duration events land as
+    ``xla.compile`` spans under the span that caused them."""
+    import jax
+    import jax.numpy as jnp
+
+    tracing.start(capacity=256)
+    if not on:
+        tracing.stop()
+    fn = jax.jit(lambda x: x * 3 + 1)    # a new function: never cached
+    with tracing.span("caller") as caller:
+        fn(jnp.zeros((7, 3), jnp.uint32)).block_until_ready()
+    doc = tracing.export()
+    tracing.validate(doc)
+    compiles = [e for e in doc["traceEvents"] if e["name"] == "xla.compile"]
+    if not on:
+        assert compiles == [] and _names(doc) == []
+        return
+    assert compiles, _names(doc)
+    assert all(e["args"]["parent"] == caller.id for e in compiles)
+    events = {e["args"]["event"] for e in compiles}
+    assert events & {"backend_compile", "cache_retrieval"} and events <= {
+        "trace", "lower", "backend_compile", "cache_retrieval"}
+    # the thousands of sub-millisecond inner traces are not recorded
+    assert all(e["dur"] >= 1000 for e in compiles)
+    # the same shape again compiles nothing
+    tracing.start(capacity=256)
+    with tracing.span("caller"):
+        fn(jnp.ones((7, 3), jnp.uint32)).block_until_ready()
+    assert "xla.compile" not in _names(tracing.export())
+
+
+def test_disabled_interval_is_cheap():
+    """Off, ``interval`` is one branch: the bound the disabled span
+    path is held to holds for it too."""
+    interval = tracing.interval
+    t0 = time.perf_counter()
+    for _ in range(200_000):
+        interval("hot", 0)
+    dt = time.perf_counter() - t0
+    assert dt < 1.0, f"disabled interval path too slow: {dt:.3f}s / 200k"
+
+
 # --- recording + export -----------------------------------------------
 
 
