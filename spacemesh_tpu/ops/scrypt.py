@@ -372,18 +372,31 @@ def _plan(n: int, batch: int, *arrays, impl: str | None = None,
     return d, d.impl == "pallas" and accel.pallas_interpret()
 
 
+def pad_lanes(a: np.ndarray, pad: int) -> np.ndarray:
+    """The bucket pad on the HOST: repeat the last lane ``pad`` times
+    along the lane (last) axis. What every caller that still holds numpy
+    arrays uses (:func:`_run`, post/verifier.py), so that the device sees
+    one bucket-sized upload and no eager op."""
+    if not pad:
+        return a
+    return np.concatenate([a, np.repeat(a[..., -1:], pad, axis=-1)],
+                          axis=-1)
+
+
 def _bucket_lanes(commitment_words, idx_lo, idx_hi):
     """Pad the lane axis up to its shape bucket (repeat the last index).
     Returns (cw, lo, hi, valid) with ``valid`` = the caller's lane count
     (trim the output to it), or the inputs unchanged when the batch is
-    already bucket-sized."""
+    already bucket-sized. Only ragged DEVICE-resident inputs get here:
+    host callers pad in numpy first (:func:`pad_lanes`)."""
     b = int(idx_lo.shape[0])
     bb = shape_bucket(b)
     if bb == b:
         return commitment_words, idx_lo, idx_hi, b
     pad = bb - b
-    # eager device ops, each its own small program: the span says what
-    # they cost the host while the device runs something else
+    # eager device ops, each its own small program (compiled per distinct
+    # occupancy): the span says what they cost the host while the device
+    # runs something else
     with tracing.span("romix.pad", {"valid": b, "batch": bb}
                       if tracing.is_enabled() else None):
         idx_lo = jnp.concatenate(
@@ -639,6 +652,18 @@ def labels_to_words(labels: np.ndarray) -> np.ndarray:
     return np.ascontiguousarray(labels).view("<u4").reshape(-1, 4).T.astype(np.uint32)
 
 
+@jax.jit
+def words_to_le(words):
+    """(4, B) BE label words -> LE proving-hash words, on device.
+
+    The device-side twin of the host ``labels_to_bytes`` ->
+    ``labels_to_words`` round trip: the verifier feeds the label
+    program's words straight into the proving hash (post/verifier.py),
+    so the endianness flip the host conversion performs for free happens
+    here."""
+    return byteswap32(words)
+
+
 def _check_n(n: int) -> None:
     # RFC 7914: for r=1, N must be a power of two and < 2^(128*r/8) = 2^16
     if n < 2 or n >= 2**16 or (n & (n - 1)) != 0:
@@ -650,6 +675,14 @@ def _run(cw: np.ndarray, indices: np.ndarray, n: int) -> np.ndarray:
     if indices.size == 0:
         return np.zeros((0, LABEL_BYTES), dtype=np.uint8)
     lo, hi = split_indices(indices)
+    # pad to the bucket here, in numpy: the device gets bucket-sized
+    # arrays, so _bucket_lanes has nothing to do and nothing is trimmed
+    # on the device
+    b = lo.shape[0]
+    pad = shape_bucket(b) - b
+    lo, hi = pad_lanes(lo, pad), pad_lanes(hi, pad)
+    if cw.ndim == 2:
+        cw = pad_lanes(cw, pad)
     with tracing.span("romix.upload",
                       {"bytes": cw.nbytes + lo.nbytes + hi.nbytes}
                       if tracing.is_enabled() else None):
@@ -661,7 +694,7 @@ def _run(cw: np.ndarray, indices: np.ndarray, n: int) -> np.ndarray:
                      {"program": "labels_fused", "lanes": batch,
                       "d2h_bytes": host.nbytes}
                      if tracing.is_enabled() else None)
-    out = np.frombuffer(labels_to_bytes(host), dtype=np.uint8)
+    out = np.frombuffer(labels_to_bytes(host[:, :b]), dtype=np.uint8)
     return out.reshape(-1, LABEL_BYTES)
 
 

@@ -117,17 +117,6 @@ def scrypt_labels_sharded(mesh: Mesh, commitment_words, idx_lo, idx_hi,
     return scrypt.scrypt_labels_jit(cw, idx_lo, idx_hi, n=n, impl=impl)
 
 
-@jax.jit
-def words_to_le(words):
-    """(4, B) BE label words -> LE proving-hash words, on device.
-
-    The device-side twin of the host ``labels_to_bytes`` ->
-    ``labels_to_words`` round trip: sharded verify feeds label words
-    straight into the proving hash without a host bytes detour, so the
-    endianness flip the host path performs for free must happen here."""
-    return byteswap32(words)
-
-
 def prove_step_sharded(mesh: Mesh, challenge_words, nonce_base, idx_lo,
                        idx_hi, label_words, threshold, hit_counts, hit_carry,
                        valid, start_lo, start_hi, *, n_nonces: int,

@@ -18,6 +18,7 @@ import dataclasses
 import hashlib
 import time
 
+import jax
 import numpy as np
 
 from ..ops import autotune
@@ -127,8 +128,6 @@ def _verify_many(items: list[VerifyItem], p: ProofParams, seed: bytes,
 
     # 2) one batched label recompute + proving-hash pass over ALL proofs.
     # scrypt_n must be uniform per compiled program; group by n (usually 1).
-    import jax.numpy as jnp
-
     with tracing.span("post.verify.pack"):
         owners = np.array(flat_owner)
         idx = np.array(flat_idx, dtype=np.uint64)
@@ -144,99 +143,52 @@ def _verify_many(items: list[VerifyItem], p: ProofParams, seed: bytes,
     for n in sorted({items[o].scrypt_n for o in flat_owner}):
         with tracing.span("post.verify.pack"):
             sel = np.array([items[o].scrypt_n == n for o in flat_owner])
-            # pad the flat batch to its power-of-two shape bucket (repeat
-            # lane 0, trim after): an unbucketed pass would compile one
-            # executable per DISTINCT spot-check count — farm batches at
-            # varying occupancy turned every new flat count into a fresh
-            # XLA compile
+            # pad the flat batch to its power-of-two shape bucket HERE, in
+            # numpy (pad lanes repeat the last one, trimmed after the
+            # fetch): the device gets one bucket-sized batch, so one
+            # executable of each program serves every occupancy of the
+            # bucket and no eager device op pads or trims
             b = int(sel.sum())
             bb = scrypt.shape_bucket(b)
-            pad = bb - b
-
-            def _pad(a, axis=0):
-                reps = np.take(a, [0], axis=axis)
-                return np.concatenate(
-                    [a, np.repeat(reps, pad, axis=axis)], axis=axis)
-
             lo, hi = scrypt.split_indices(idx[sel])
+            cw8 = commits[sel].view(">u4").astype(np.uint32).T  # (8, b)
+            cw8, chal_b, nonce_b, lo, hi = (
+                scrypt.pad_lanes(a, bb - b)
+                for a in (cw8, chals[:, sel], nonces[sel], lo, hi))
             # the shared tuned mesh routing (SPACEMESH_MESH forces; CPU
             # consults the raced winner) — the verify farm's batch
             # recompute is a label batch like any other, so it shards
-            # like one
+            # like one. Placement is the only thing a mesh changes.
             devs, d = autotune.resolve_auto_mesh(n, bb)
-            sharded = (devs is not None and len(devs) > 1
-                       and bb % len(devs) == 0)
-            if sharded:
-                # mesh callers pre-bucket on host (ops/scrypt.py
-                # _tunable): pad BEFORE the label recompute so one
-                # sharded executable serves every occupancy at this
-                # bucket
-                cw8 = commits[sel].view(">u4").astype(np.uint32).T  # (8, b)
-                chal_b, nonce_b = chals[:, sel], nonces[sel]
-                if pad:
-                    cw8, chal_b = _pad(cw8, axis=1), _pad(chal_b, axis=1)
-                    nonce_b, lo, hi = _pad(nonce_b), _pad(lo), _pad(hi)
-        if tr is not None:
-            tr["lanes"] += bb
-        if sharded:
-            from ..parallel import mesh as pmesh
+            where, impl = None, None    # one device: the tuned (impl, chunk)
+            if devs is not None and len(devs) > 1 and bb % len(devs) == 0:
+                from ..parallel import topology
 
-            mesh = pmesh.data_mesh(devs)
-            # sharded label words feed the sharded proving hash directly
-            # — no host bytes round-trip between the two programs. The
-            # label pipeline emits BE word groups; the proving hash eats
-            # LE (what labels_to_bytes->labels_to_words round-trips on
-            # the single-device path), so swap on device.
-            t0 = time.perf_counter_ns()
-            lw_dev = pmesh.words_to_le(pmesh.scrypt_labels_sharded(
-                mesh, cw8, lo, hi, n=n, impl=d.impl))
-            lay = pmesh.topology.get().layouts_for(mesh)
-            vals = np.asarray(proving.proving_hash_jit(
-                lay.put_lane(chal_b), lay.put_batch(nonce_b),
-                lay.put_batch(lo), lay.put_batch(hi), lw_dev))
-            if tr is not None:
-                # one flight: the label words never come to the host
-                tracing.interval("device.flight", t0,
-                                 {"program": "labels_proving_sharded",
-                                  "lanes": bb, "d2h_bytes": vals.nbytes})
-                tr["syncs"] += 1
-                tr["h2d_bytes"] += (cw8.nbytes + chal_b.nbytes
-                                    + nonce_b.nbytes + lo.nbytes
-                                    + hi.nbytes)
-                tr["d2h_bytes"] += vals.nbytes
-            vals = vals[:b]
-        else:
-            # its device.flight is recorded in ops/scrypt._run
-            labels = scrypt.scrypt_labels_multi(commits[sel], idx[sel], n=n)
-            # the round trip the sharded twin does not make: label
-            # bytes on the host -> LE words -> padded -> back up
-            with tracing.span("post.verify.relayout") as rsp:
-                lw = scrypt.labels_to_words(labels)
-                if pad:
-                    chal_b = _pad(chals[:, sel], axis=1)
-                    nonce_b = _pad(nonces[sel])
-                    lo, hi = _pad(lo), _pad(hi)
-                    lw = _pad(lw, axis=1)
-                else:
-                    chal_b, nonce_b = chals[:, sel], nonces[sel]
-                args = (jnp.asarray(chal_b), jnp.asarray(nonce_b),
-                        jnp.asarray(lo), jnp.asarray(hi), jnp.asarray(lw))
-                if tr is not None:
-                    rsp.set(bytes=labels.nbytes + lw.nbytes)
-            t0 = time.perf_counter_ns()
-            vals = np.asarray(proving.proving_hash_jit(*args))
-            if tr is not None:
-                tracing.interval("device.flight", t0,
-                                 {"program": "proving_hash", "lanes": bb,
-                                  "d2h_bytes": vals.nbytes})
-                tr["syncs"] += 2
-                # up: commitments and indices for the labels (b lanes),
-                # then the proving hash's five inputs (bb lanes)
-                tr["h2d_bytes"] += (40 * b + chal_b.nbytes + nonce_b.nbytes
-                                    + lo.nbytes + hi.nbytes + lw.nbytes)
-                tr["d2h_bytes"] += labels.nbytes + vals.nbytes
-            vals = vals[:b]
-        values[sel] = vals
+                lay = topology.get().layouts_for_devices(devs)
+                where = [lay.lane, lay.lane, lay.batch, lay.batch, lay.batch]
+                impl = d.impl           # the raced mesh winner's layout
+            host = [cw8, chal_b, nonce_b, lo, hi]
+            h2d = sum(a.nbytes for a in host)
+        with tracing.span("romix.upload",
+                          {"bytes": h2d} if tr is not None else None):
+            cw8, chal_b, nonce_b, lo, hi = jax.device_put(host, where)
+        # one flight: label program, endianness flip and proving hash are
+        # enqueued back to back and only the (bb,) hash values come back.
+        # The label pipeline emits BE word groups, the proving hash eats
+        # LE; the words never leave the device in between.
+        t0 = time.perf_counter_ns()
+        vals = np.asarray(proving.proving_hash_jit(
+            chal_b, nonce_b, lo, hi, scrypt.words_to_le(
+                scrypt.scrypt_labels_jit(cw8, lo, hi, n=n, impl=impl))))
+        if tr is not None:
+            tracing.interval("device.flight", t0,
+                             {"program": "labels_proving", "lanes": bb,
+                              "d2h_bytes": vals.nbytes})
+            tr["lanes"] += bb
+            tr["syncs"] += 1
+            tr["h2d_bytes"] += h2d
+            tr["d2h_bytes"] += vals.nbytes
+        values[sel] = vals[:b]
 
     # 3) threshold check per item
     with tracing.span("post.verify.threshold"):

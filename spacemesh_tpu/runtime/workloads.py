@@ -11,8 +11,9 @@ enumerate them without importing every pipeline:
                     commitment words), VRF minimum folded per tenant on
                     host (runtime/scheduler.py).
 * ``prove_scan``  — the streaming prover's scan step (post/prover.py).
-* ``verify``      — the batched POST verifier's recompute shapes
-                    (per-lane commitments + proving hash).
+* ``verify``      — the batched POST verifier's one flight: per-lane
+                    label program, ``words_to_le``, per-lane proving
+                    hash (post/verifier.py).
 * ``k2pow``       — the SHA-256 nonce-search batch (ops/pow.py).
 * ``k2pow_verify`` — the per-item-prefix k2pow witness verification
                     batch (ops/pow.py verify_many; the verifyd service
@@ -164,41 +165,36 @@ def _warm_prove_scan(n: int, batch: int) -> dict:
 
 
 def _warm_verify(n: int, batch: int) -> dict:
-    import jax.numpy as jnp
+    import jax
     import numpy as np
 
-    from ..ops import proving
+    from ..ops import proving, scrypt
 
-    # the verifier's second pass: proving-hash values over the
-    # recomputed labels (its first pass shares init_pack's per-lane
-    # label executable)
+    # post/verifier.verify_many's one flight at this bucket: the
+    # per-lane label executable (init_pack's), then words_to_le and the
+    # proving hash over per-lane challenges and nonces, fed from the
+    # device-resident label words. Placement is all a mesh changes.
     doc = _warm_init_pack(n, batch)
-    cw = jnp.asarray(proving.challenge_words(bytes(32)))
-    idx = np.arange(batch, dtype=np.uint64)
-    lo_h = (idx & 0xFFFFFFFF).astype(np.uint32)
-    hi_h = (idx >> 32).astype(np.uint32)
-    lo, hi = jnp.asarray(lo_h), jnp.asarray(hi_h)
-    lw = jnp.zeros((4, batch), jnp.uint32)
-    _timed(doc, "proving_hash",
-           lambda: proving.proving_hash_jit(cw, jnp.uint32(7), lo, hi, lw))
+    lo, hi = scrypt.split_indices(np.arange(batch, dtype=np.uint64))
+    host = [np.broadcast_to(proving.challenge_words(bytes(32))[:, None],
+                            (8, batch)),
+            np.full(batch, 7, np.uint32), lo, hi,
+            np.zeros((4, batch), np.uint32)]
+    placements = [("", None)]
     if doc.get("pack_devices", 1) > 1:
-        # the verify farm's sharded batch: per-lane challenges/nonces,
-        # GSPMD-partitioned proving hash (post/verifier.py mesh path)
         from ..ops import autotune
-        from ..parallel import mesh as pmesh
+        from ..parallel import topology
 
         devs, _ = autotune.resolve_auto_mesh(n, batch)
-        lay = pmesh.topology.get().layouts_for_devices(devs)
-        chal_b = np.broadcast_to(
-            np.asarray(proving.challenge_words(bytes(32)))[:, None],
-            (8, batch)).copy()
-        _timed(doc, f"proving_hash_mesh{len(devs)}",
+        lay = topology.get().layouts_for_devices(devs)
+        placements.append((f"_mesh{len(devs)}", [
+            lay.lane, lay.batch, lay.batch, lay.batch, lay.lane]))
+    for suffix, where in placements:
+        chal, nonce, lo, hi, lw = jax.device_put(host, where)
+        _timed(doc, "words_to_le" + suffix, lambda: scrypt.words_to_le(lw))
+        _timed(doc, "proving_hash" + suffix,
                lambda: proving.proving_hash_jit(
-                   lay.put_lane(chal_b),
-                   lay.put_batch(np.full(batch, 7, np.uint32)),
-                   lay.put_batch(lo_h), lay.put_batch(hi_h),
-                   pmesh.words_to_le(
-                       lay.put_lane(np.zeros((4, batch), np.uint32)))))
+                   chal, nonce, lo, hi, scrypt.words_to_le(lw)))
     return doc
 
 
@@ -256,7 +252,7 @@ PROVE_SCAN = register(WorkloadKind(
     "prove_scan", "streaming prove scan step (compact+merge on device)",
     _warm_prove_scan))
 VERIFY = register(WorkloadKind(
-    "verify", "batched POST verify recompute (per-lane labels + hash)",
+    "verify", "batched POST verify flight (per-lane labels -> LE -> hash)",
     _warm_verify))
 K2POW = register(WorkloadKind(
     "k2pow", "SHA-256 k2pow nonce-search batch", _warm_k2pow))

@@ -157,10 +157,13 @@ def test_packed_init_steady_state_zero_new_compiles(tuner, tmp_path):
 # --- sharded farm verify: ragged flat batches -----------------------------
 
 
+@pytest.mark.parametrize("devices", [2, 4])
 @pytest.mark.parametrize("count", [1, 7, 1039])
-def test_farm_verify_sharded_matches_single_device(tuner, count):
-    """verify_many over a mesh-routed batch returns the same verdicts as
-    the single-device pass at ragged spot-check totals."""
+def test_farm_verify_sharded_matches_single_device(tuner, count, devices):
+    """verify_many has ONE device path (ISSUE 24) and a mesh changes
+    only where the arrays are placed: over a mesh-routed batch it
+    returns the verdicts of the one-device pass, at ragged spot-check
+    totals and with mixed verdicts."""
     from spacemesh_tpu.post import verifier
     from spacemesh_tpu.post.prover import Proof, ProofParams
 
@@ -175,15 +178,19 @@ def test_farm_verify_sharded_matches_single_device(tuner, count):
             hashlib.sha256(b"vcommit%d" % i).digest(),
             N, total_labels))
     seed = b"topology-seed".ljust(32, b"\0")
+    bucket = scrypt.shape_bucket(count)
 
     autotune.reset_memo()
+    assert autotune.resolve_auto_mesh(N, bucket)[0] is None
     single = verifier.verify_many(items, p, seed)
-    _seed_mesh_winner(tuner, N, scrypt.shape_bucket(count), devices=4)
+    _seed_mesh_winner(tuner, N, bucket, devices=devices)
     sharded = verifier.verify_many(items, p, seed)
     assert sharded == single
-    devs, _ = autotune.resolve_auto_mesh(N, scrypt.shape_bucket(count))
-    if scrypt.shape_bucket(count) % 4 == 0:
-        assert devs is not None and len(devs) == 4
+    if count > 1:
+        assert True in single and False in single
+    if bucket % devices == 0:
+        devs, _ = autotune.resolve_auto_mesh(N, bucket)
+        assert devs is not None and len(devs) == devices
 
 
 # --- mesh-shape autotune winners ------------------------------------------

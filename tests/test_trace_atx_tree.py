@@ -107,8 +107,9 @@ def test_one_request_is_one_tree(wl):
     calls = named["post.verify"]
     batch_ids = {b["args"]["id"] for b in post_batches}
     order = ["post.verify.checks", "post.verify.pack", "romix.upload",
-             "romix.pad", "romix.dispatch", "post.verify.relayout",
-             "post.verify.threshold"]
+             "romix.dispatch", "post.verify.threshold"]
+    assert "romix.pad" not in named          # the host pads, in numpy
+    assert "post.verify.relayout" not in named   # the labels stay up
     for call in calls:
         assert call["args"]["parent"] in batch_ids
         kids = sorted((e for e in evs
@@ -118,15 +119,18 @@ def test_one_request_is_one_tree(wl):
         stages = [k["name"] for k in kids if k["name"] in order]
         assert stages == sorted(stages, key=order.index)
         assert set(stages) == set(order)
-        flights = [k for k in kids if k["name"] == "device.flight"]
-        assert {f["args"]["program"] for f in flights} == \
-            {"labels_fused", "proving_hash"}
-        for f in flights:
-            assert f["args"]["lanes"] == call["args"]["lanes"]
-            assert f["args"]["d2h_bytes"] > 0
+        # one flight: label program and proving hash back to back, only
+        # the hash values (one u32 a lane) come back, in one sync
+        (flight,) = [k for k in kids if k["name"] == "device.flight"]
+        assert flight["args"]["program"] == "labels_proving"
+        assert flight["args"]["lanes"] == call["args"]["lanes"]
+        assert flight["args"]["d2h_bytes"] == 4 * call["args"]["lanes"]
+        assert call["args"]["syncs"] == 1
+        assert call["args"]["d2h_bytes"] == 4 * call["args"]["lanes"]
         (rd,) = [k for k in kids if k["name"] == "romix.dispatch"]
         assert rd["args"]["batch"] == call["args"]["lanes"]
         assert rd["args"]["valid"] <= rd["args"]["batch"]
+        assert _inside(rd, flight)
     k3 = wl.post_params.k3
     distinct = {r.key(): r for r in wl.requests
                 if isinstance(r, PostRequest)}
@@ -141,8 +145,9 @@ def test_one_request_is_one_tree(wl):
     assert total["proofs"] == len(distinct)
     assert total["host_rejected"] == len(distinct) - len(host_ok)
     assert total["lanes_valid"] == k3 * len(host_ok)
-    assert total["syncs"] == 2 * len(calls)
-    assert total["h2d_bytes"] > 0 and total["d2h_bytes"] > 0
+    assert total["syncs"] == len(calls)
+    assert total["d2h_bytes"] == 4 * total["lanes"]
+    assert total["h2d_bytes"] == 19 * 4 * total["lanes"]
     if len(calls) == 1:
         # 6 proofs padded to 8 by the farm; the pad repeats the batch's
         # first proof, so 7 or 8 of the 8 reach the device
