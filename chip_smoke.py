@@ -277,6 +277,10 @@ def _check_scan_step(prover, step, challenge: bytes) -> None:
 
 def run_prove(dep: Deployment, data_dir: Path, challenge: bytes, params,
               doc: dict):
+    """One proof through the default path, checked against the serial
+    prover and the XLA step: does it START and agree. What a proof costs
+    is the benchmark's to say (cell ``prove-mainnet.scan``, PERF.md),
+    not this phase's seconds."""
     from spacemesh_tpu.post.prover import Prover
 
     prover = Prover(data_dir, params)

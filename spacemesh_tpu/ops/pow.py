@@ -79,12 +79,20 @@ def below_target_jit(digest_words, target_words):
     return out
 
 
+@jax.jit
+def _first_block_jit(block):
+    # jitted so that every search shares one compiled program: called
+    # eagerly, the two fori_loops of sha256_compress close over fresh
+    # functions, and JAX compiles both anew on every call (two backend
+    # compiles a prove: tests/test_pow_proving.py)
+    return sha256_compress(jnp.asarray(IV), block)
+
+
 def prefix_state(challenge: bytes, node_id: bytes) -> np.ndarray:
     """Midstate after absorbing challenge||node_id (the first block)."""
     if len(challenge) != 32 or len(node_id) != 32:
         raise ValueError("challenge and node_id must be 32 bytes")
-    block = jnp.asarray(_words_be(challenge + node_id))
-    return np.asarray(sha256_compress(jnp.asarray(IV), block))
+    return np.asarray(_first_block_jit(_words_be(challenge + node_id)))
 
 
 def pow_hash(challenge: bytes, node_id: bytes, nonce: int) -> bytes:

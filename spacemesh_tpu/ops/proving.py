@@ -179,6 +179,17 @@ def merge_hits(hit_counts, hit_carry, batch_counts, local_pos, hit_valid,
     return hit_counts + batch_counts, batch_counts, hit_carry
 
 
+def compact_and_merge(mask, hit_counts, hit_carry, start_lo, start_hi, *,
+                      max_hits: int):
+    """The epilogue both scan steps share, each phase under its named
+    scope (op metadata only: ``scan_compact``, ``scan_merge``)."""
+    with jax.named_scope("scan_compact"):
+        counts, pos, ok = compact_hits(mask, max_hits=max_hits)
+    with jax.named_scope("scan_merge"):
+        return merge_hits(hit_counts, hit_carry, counts, pos, ok,
+                          start_lo, start_hi)
+
+
 @functools.partial(jax.jit, static_argnames=("n_nonces", "max_hits"),
                    donate_argnums=(6, 7))
 def prove_scan_step_jit(challenge_words, nonce_base, idx_lo, idx_hi,
@@ -194,13 +205,13 @@ def prove_scan_step_jit(challenge_words, nonce_base, idx_lo, idx_hi,
     host fetch is ``batch_counts``.
     """
     b = idx_lo.shape[0]
-    mask = _scan_mask(challenge_words, nonce_base, idx_lo, idx_hi,
-                      label_words, threshold, n_nonces=n_nonces)
-    lane = jnp.arange(b, dtype=jnp.uint32)
-    mask = mask & (lane[None, :] < valid)
-    counts, pos, ok = compact_hits(mask, max_hits=max_hits)
-    return merge_hits(hit_counts, hit_carry, counts, pos, ok,
-                      start_lo, start_hi)
+    with jax.named_scope("scan_kernel"):
+        mask = _scan_mask(challenge_words, nonce_base, idx_lo, idx_hi,
+                          label_words, threshold, n_nonces=n_nonces)
+        lane = jnp.arange(b, dtype=jnp.uint32)
+        mask = mask & (lane[None, :] < valid)
+    return compact_and_merge(mask, hit_counts, hit_carry, start_lo,
+                             start_hi, max_hits=max_hits)
 
 
 def init_hit_state(n_nonces: int, cap: int):

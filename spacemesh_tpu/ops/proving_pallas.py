@@ -23,7 +23,7 @@ Output:
 One u32 of hit bits per lane is the whole kernel output (the (n_nonces, B)
 mask would be 4-16x the bytes); the surrounding jit unpacks it and
 ``prove_scan_step_pallas`` runs the same compaction epilogue as the XLA
-step (ops/proving.py compact_hits/merge_hits), so the mask never crosses
+step (ops/proving.py compact_and_merge), so the mask never crosses
 PCIe and the only per-batch D2H is the (n_nonces,) count vector.
 
 Grid: lane tiles of LANE_TILE. ``interpret=True`` runs the kernel on CPU
@@ -155,12 +155,12 @@ def prove_scan_step_pallas(challenge_words, nonce_base, idx_lo, idx_hi,
     Same contract: donated (hit_counts, hit_carry) device state, per-batch
     D2H limited to the (n_nonces,) batch count vector.
     """
-    mask = _scan_pallas(challenge_words, nonce_base, idx_lo, idx_hi,
-                        label_words, threshold, valid,
-                        n_nonces=n_nonces, interpret=interpret)
-    counts, pos, ok = proving.compact_hits(mask, max_hits=max_hits)
-    return proving.merge_hits(hit_counts, hit_carry, counts, pos, ok,
-                              start_lo, start_hi)
+    with jax.named_scope("scan_kernel"):
+        mask = _scan_pallas(challenge_words, nonce_base, idx_lo, idx_hi,
+                            label_words, threshold, valid,
+                            n_nonces=n_nonces, interpret=interpret)
+    return proving.compact_and_merge(mask, hit_counts, hit_carry, start_lo,
+                                     start_hi, max_hits=max_hits)
 
 
 def proving_scan(challenge: bytes, nonce_base: int, indices, labels: np.ndarray,

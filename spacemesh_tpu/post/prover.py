@@ -208,22 +208,29 @@ class Prover:
     # -- entry points -------------------------------------------------------
 
     def prove(self, challenge: bytes) -> Proof:
-        if self.pipelined:
+        """One whole proof, k2pow gate included. Under a trace capture
+        it is one ``prove.proof`` span (docs/OBSERVABILITY.md) that
+        says how the proof was reached: ``nonce`` and, on the pipelined
+        path, ``passes``, ``labels_swept``, ``early_exited``."""
+        with tracing.span("prove.proof",
+                          {"challenge": challenge.hex()[:16]}
+                          if tracing.is_enabled() else None) as sp:
+            if not self.pipelined:
+                proof = self.prove_serial(challenge)
+                sp.set(nonce=proof.nonce)
+                return proof
             session = self.session(challenge)
             try:
                 while True:
                     proof = session.step()
                     if proof is not None:
+                        st = session.stats
+                        sp.set(nonce=proof.nonce, passes=st.windows,
+                               labels_swept=st.labels_swept,
+                               early_exited=st.early_exited)
                         return proof
             finally:
                 session.close()
-        try:
-            return self._prove_serial(challenge, self._pow(challenge))
-        finally:
-            # drop the store's cached read fds: PostClient builds a fresh
-            # Prover per challenge, so a long-lived worker would otherwise
-            # leak one fd per postdata file per proving session
-            self.store.close()
 
     def session(self, challenge: bytes, tenant: str = "-") -> "ProveSession":
         """A resumable streaming prove: each ``step()`` is one quantum —
@@ -354,10 +361,10 @@ class Prover:
         b = self.batch_labels
         ng = self.nonce_group
         cap = max(p.k2, 1)
-        traced = tracing.is_enabled()
         wsp = tracing.span("prove.window",
                            {"window": nonce_base, "groups": groups,
-                            "labels": total} if traced else None)
+                            "labels": total}
+                           if tracing.is_enabled() else None)
         wsp.__enter__()
         reader = None
         try:
@@ -371,6 +378,11 @@ class Prover:
                     carry = pmesh.replicate(mesh, carry)
                 states.append([counts, carry])
             host_counts = np.zeros(ng * groups, dtype=np.int64)
+            # a device scalar is a host->device transfer of its own
+            # (0.5 ms each on a v5e's host, PERF.md section 6): each
+            # group's base nonce goes up once a pass, and a batch's
+            # count and start once a batch, not once a step call
+            bases = [jnp.uint32(nonce_base + g * ng) for g in range(groups)]
             reader = self.store.start_reader(ranges, self.readers,
                                              self.reader_queue)
             metrics.post_prove_windows.inc()
@@ -379,41 +391,58 @@ class Prover:
 
             def dispatch(item):
                 start, count = item
+                # asked per batch: a capture may start in mid-pass
+                traced = tracing.is_enabled()
                 tr = time.perf_counter()
                 with tracing.span("prove.read_wait",
                                   {"window": nonce_base, "start": start}
                                   if traced else None):
                     raw = reader.get()
                 stats.read_wait_s += time.perf_counter() - tr
-                labels = np.frombuffer(raw, dtype=np.uint8).reshape(
-                    count, scrypt.LABEL_BYTES)
-                if count < b:  # pad-and-trim: one shape per pass
-                    labels = np.concatenate([
-                        labels,
-                        np.zeros((b - count, scrypt.LABEL_BYTES),
-                                 np.uint8)])
-                idx = np.arange(start, start + b, dtype=np.uint64)
-                lo, hi = scrypt.split_indices(idx)
-                lw = scrypt.labels_to_words(labels)
-                jlo, jhi, jlw = (jnp.asarray(lo), jnp.asarray(hi),
-                                 jnp.asarray(lw))
+                with tracing.span("prove.convert", {"window": nonce_base}
+                                  if traced else None):
+                    labels = np.frombuffer(raw, dtype=np.uint8).reshape(
+                        count, scrypt.LABEL_BYTES)
+                    if count < b:  # pad-and-trim: one shape per pass
+                        labels = np.concatenate([
+                            labels,
+                            np.zeros((b - count, scrypt.LABEL_BYTES),
+                                     np.uint8)])
+                    idx = np.arange(start, start + b, dtype=np.uint64)
+                    lo, hi = scrypt.split_indices(idx)
+                    lw = scrypt.labels_to_words(labels)
+                h2d = lo.nbytes + hi.nbytes + lw.nbytes
+                with tracing.span("prove.upload",
+                                  {"window": nonce_base, "h2d_bytes": h2d}
+                                  if traced else None):
+                    jlo, jhi, jlw = (jnp.asarray(lo), jnp.asarray(hi),
+                                     jnp.asarray(lw))
+                metrics.post_prove_h2d_bytes.inc(h2d)
+                # the batch's device.flight runs from here to the fetch
+                # of its count vectors (_retire)
+                t_flight = time.perf_counter_ns() if traced else 0
                 bcs = []
-                for g in range(groups):
-                    counts, carry = states[g]
-                    counts, bc, carry = step(
-                        cw, jnp.uint32(nonce_base + g * ng), jlo, jhi,
-                        jlw, thr, counts, carry, jnp.uint32(count),
-                        jnp.uint32(start & 0xFFFFFFFF),
-                        jnp.uint32(start >> 32))
-                    states[g] = [counts, carry]
-                    bcs.append(bc)
+                with tracing.span("prove.enqueue",
+                                  {"window": nonce_base, "groups": groups,
+                                   "batch": b, "nonces": groups * ng}
+                                  if traced else None):
+                    valid = jnp.uint32(count)
+                    start_lo = jnp.uint32(start & 0xFFFFFFFF)
+                    start_hi = jnp.uint32(start >> 32)
+                    for g in range(groups):
+                        counts, carry = states[g]
+                        counts, bc, carry = step(
+                            cw, bases[g], jlo, jhi, jlw, thr, counts, carry,
+                            valid, start_lo, start_hi)
+                        states[g] = [counts, carry]
+                        bcs.append(bc)
                 # progress must advance PER BATCH, here in the callback
                 # — folding the engine's count in after the pass would
                 # freeze the liveness watchdog for the whole disk pass
                 # (ProveSession registers it on stats.batches)
                 stats.batches += 1
                 metrics.post_prove_batches.inc()
-                return start + count, bcs
+                return start + count, bcs, count, t_flight
 
             def retire(ticket):
                 retired_end[0] = ticket[0]
@@ -428,7 +457,7 @@ class Prover:
                 attrs=lambda it: {"window": nonce_base, "start": it[0],
                                   "count": it[1]},
                 retire_attrs=lambda tk: {"window": nonce_base,
-                                         "end": tk[0]})
+                                         "end": tk[0], "count": tk[2]})
             rw0 = stats.read_wait_s
             res = pipe.run(ranges, dispatch, retire)
             exited = res is not None
@@ -463,16 +492,23 @@ class Prover:
         provably cannot reach k2 with the labels left in this pass (lower
         windows already failed their full pass, so the winner is final and
         identical to the serial prover's end-of-pass pick)."""
-        scanned_end, bcs = item
+        scanned_end, bcs, count, t_flight = item
         p = self.params
         ng = self.nonce_group
         tr = time.perf_counter()
         # the engine's prove.retire span (runtime/engine.py) is open here
+        d2h = 0
         for g, bc in enumerate(bcs):
             vec = np.asarray(bc)
             host_counts[g * ng:(g + 1) * ng] += vec
-            stats.d2h_bytes += vec.nbytes
-            metrics.post_prove_d2h_bytes.inc(vec.nbytes)
+            d2h += vec.nbytes
+        stats.d2h_bytes += d2h
+        metrics.post_prove_d2h_bytes.inc(d2h)
+        if t_flight:
+            tracing.interval("device.flight", t_flight,
+                             {"program": "prove_scan", "labels": count,
+                              "groups": len(bcs), "d2h_bytes": d2h,
+                              "window": nonce_base})
         stats.retire_s += time.perf_counter() - tr
         qualified = host_counts >= p.k2
         if not qualified.any():

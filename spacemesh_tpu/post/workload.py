@@ -8,6 +8,13 @@ search. Node id, commitment, challenge and store geometry are fixed: the
 winning nonce — and both provers' full proofs — are deterministic, and
 ``compare_serial_vs_pipelined`` refuses to report a number unless the two
 paths produced bit-identical proofs and the verifier accepts them.
+
+Not the yardstick for prove: that is the benchmark's cell
+``prove-mainnet.scan`` (BENCHMARK.json, PERF.md), which runs whole proofs
+at mainnet's K1=26 / K2=37 through the default Prover on the chip and
+checks each against a plain reference prover. This fixture's k1 > k2
+regime never needs a second pass and decides its winner early; it goes
+with bench.py (ROADMAP D1).
 """
 
 from __future__ import annotations

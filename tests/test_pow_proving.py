@@ -169,3 +169,27 @@ def test_proving_scan_matches_single_nonce():
     for k in range(4):
         vals = proving.proving_hashes(CH, 3 + k, idx, labels)
         assert np.array_equal(mask[k], vals < t)
+
+
+def test_second_search_compiles_nothing():
+    """Every proof begins with a k2pow search over a new challenge: once
+    one search has run, the next must find every program compiled (the
+    first block's SHA-256 ran eagerly once and compiled its two loops
+    anew on every call)."""
+    import jax.monitoring
+
+    compiled = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, secs, **kw: compiled.append(kw.get("fun_name"))
+        if event == "/jax/core/compile/backend_compile_duration" else None)
+    diff = bytes([0x0f]) + bytes([0xff]) * 31
+    first = k2pow.search(hashlib.sha256(b"a").digest(), NID, diff,
+                         batch=1 << 10)
+    n = len(compiled)
+    second = k2pow.search(hashlib.sha256(b"b").digest(), NID, diff,
+                          batch=1 << 10)
+    assert first is not None and second is not None
+    assert compiled[n:] == []
+    # and the searches still find what hashlib confirms
+    assert k2pow.verify(hashlib.sha256(b"a").digest(), NID, diff, first)
+    assert k2pow.verify(hashlib.sha256(b"b").digest(), NID, diff, second)

@@ -1,0 +1,135 @@
+"""One proof, one tree: ``Prover.prove`` with the span tracer on closes
+into a single tree from ``prove.proof`` down to one ``device.flight``
+per batch, and the spans carry the counts the benchmark's per-layer
+readers take (docs/OBSERVABILITY.md, docs/POST_PROVING.md)."""
+
+import hashlib
+
+import pytest
+
+from spacemesh_tpu.post import initializer
+from spacemesh_tpu.post.prover import ProofParams, Prover
+from spacemesh_tpu.utils import metrics, tracing
+
+K2 = 37
+NG, GROUPS, BATCH = 16, 2, 2048
+STAGES = ("prove.read_wait", "prove.convert", "prove.upload",
+          "prove.enqueue")
+
+
+@pytest.fixture(autouse=True)
+def _tracer_off():
+    tracing.stop()
+    yield
+    tracing.stop()
+
+
+@pytest.fixture(scope="module")
+def capture(tmp_path_factory):
+    d = tmp_path_factory.mktemp("prove-tree")
+    initializer.initialize(
+        d, node_id=hashlib.sha256(b"tree-node").digest(),
+        commitment=hashlib.sha256(b"tree-commitment").digest(),
+        num_units=4, labels_per_unit=2500, scrypt_n=2, batch_size=4096)
+    prover = Prover(d, ProofParams(
+        k1=26, k2=K2, k3=K2,
+        pow_difficulty=bytes.fromhex("0fffffffffffffff" + "00" * 24)),
+        batch_labels=BATCH, nonce_group=NG, window_groups=GROUPS,
+        use_pallas=False, mesh=None)
+    h2d0 = sum(metrics.post_prove_h2d_bytes.sample().values())
+    tracing.start(capacity=1 << 14, jax_bridge=False)
+    proof = prover.prove(hashlib.sha256(b"tree-challenge").digest())
+    tracing.stop()
+    doc = tracing.export()
+    tracing.validate(doc)
+    assert doc["otherData"]["dropped_spans"] == 0
+    h2d = sum(metrics.post_prove_h2d_bytes.sample().values()) - h2d0
+    evs = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+    return proof, prover.last_stats, evs, h2d
+
+
+def _named(evs):
+    out = {}
+    for e in evs:
+        out.setdefault(e["name"], []).append(e)
+    return out
+
+
+def _ancestors(e, by_id):
+    while e["args"].get("parent") in by_id:
+        e = by_id[e["args"]["parent"]]
+        yield e
+
+
+def test_one_proof_is_one_tree(capture):
+    proof, stats, evs, _h2d = capture
+    by_id = {e["args"]["id"]: e for e in evs}
+    named = _named(evs)
+    (root,) = named["prove.proof"]
+    assert root["args"]["nonce"] == proof.nonce
+    assert root["args"]["passes"] == stats.windows == len(
+        named["prove.window"])
+    assert root["args"]["labels_swept"] == stats.labels_swept
+    assert root["args"]["early_exited"] == stats.early_exited
+    # everything the proof did hangs under it
+    for name in ("prove.k2pow", "prove.window", "prove.dispatch",
+                 "prove.retire", "device.flight") + STAGES:
+        assert named[name], name
+        for e in named[name]:
+            assert root in list(_ancestors(e, by_id)), name
+    (k2pow,) = named["prove.k2pow"]
+    assert not any(a["name"] == "prove.window"
+                   for a in _ancestors(k2pow, by_id))
+    windows = {w["args"]["id"] for w in named["prove.window"]}
+    for name in ("prove.dispatch", "prove.retire"):
+        assert {e["args"]["parent"] for e in named[name]} <= windows
+
+
+def test_every_batch_has_its_stages_and_its_flight(capture):
+    _proof, stats, evs, _h2d = capture
+    named = _named(evs)
+    dispatches = {e["args"]["id"]: e for e in named["prove.dispatch"]}
+    assert len(dispatches) == stats.batches
+    for name in STAGES:     # one of each, inside its batch's dispatch
+        assert sorted(e["args"]["parent"] for e in named[name]) \
+            == sorted(dispatches), name
+        for e in named[name]:
+            d = dispatches[e["args"]["parent"]]
+            assert d["ts"] <= e["ts"] and \
+                e["ts"] + e["dur"] <= d["ts"] + d["dur"] + 2
+    for e in named["prove.enqueue"]:
+        assert (e["args"]["groups"], e["args"]["batch"],
+                e["args"]["nonces"]) == (GROUPS, BATCH, GROUPS * NG)
+    # a flight per retired batch: from its enqueue to its counts fetched
+    retires = {e["args"]["id"]: e for e in named["prove.retire"]}
+    flights = named["device.flight"]
+    assert sorted(f["args"]["parent"] for f in flights) == sorted(retires)
+    starts = sorted(e["ts"] for e in named["prove.enqueue"])
+    for f in flights:
+        r = retires[f["args"]["parent"]]
+        assert f["args"]["program"] == "prove_scan"
+        assert f["args"]["labels"] == r["args"]["count"] <= BATCH
+        assert f["args"]["d2h_bytes"] == GROUPS * NG * 4
+        assert f["ts"] + f["dur"] <= r["ts"] + r["dur"] + 2
+        # it starts where the batch's enqueue does (the clock is read
+        # just before the span opens)
+        assert any(0 <= s - f["ts"] <= 500 for s in starts)
+    # an early exit abandons the batches still in flight: those were
+    # dispatched and never retired
+    assert len(retires) <= len(dispatches)
+    assert sum(r["args"]["count"] for r in retires.values()) \
+        == stats.labels_swept
+
+
+def test_upload_bytes_are_24_a_label_dispatched(capture):
+    _proof, _stats, evs, h2d = capture
+    named = _named(evs)
+    sent = sum(e["args"]["h2d_bytes"] for e in named["prove.upload"])
+    assert sent == 24 * sum(e["args"]["batch"]
+                            for e in named["prove.enqueue"])
+    assert h2d == sent      # the counter counts what the spans say
+
+
+def test_spans_are_free_when_the_tracer_is_off():
+    assert tracing.span("prove.proof", None) is tracing._NOP
+    assert tracing.span("prove.enqueue", None) is tracing._NOP
