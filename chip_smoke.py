@@ -239,40 +239,49 @@ def run_init(dep: Deployment, data_dir: Path, node_id: bytes,
 
 
 def _check_scan_step(prover, step, challenge: bytes) -> None:
-    """``step`` (the Prover's default) against
-    ``proving.prove_scan_step_jit`` on one real batch of the store (a
-    ragged tail and an index carry past 2^32 included)."""
+    """``step`` (the window step the Prover binds by default: one
+    program over every nonce group of a pass, its lane indices made on
+    the device) against ``proving.prove_scan_step_jit`` group by group
+    on one real batch of the store (a ragged tail and an index carry
+    past 2^32 included)."""
     import jax.numpy as jnp
     import numpy as np
 
     from spacemesh_tpu.ops import proving, scrypt
 
     b, ng, cap = prover.batch_labels, prover.nonce_group, prover.params.k2
+    groups = prover.window_groups
     count = min(b, prover.meta.total_labels) - 5
     labels = np.zeros((b, scrypt.LABEL_BYTES), np.uint8)
     labels[:count] = np.frombuffer(
         prover.store.read_labels(0, count), np.uint8).reshape(count, -1)
+    prover.store.close()
     start = 2**32 - 7
     lo, hi = scrypt.split_indices(np.arange(start, start + b,
                                             dtype=np.uint64))
-    args = (jnp.asarray(proving.challenge_words(challenge)), jnp.uint32(16),
-            jnp.asarray(lo), jnp.asarray(hi),
-            jnp.asarray(scrypt.labels_to_words(labels)),
-            # ~64x the proof threshold so every nonce row carries hits
-            jnp.uint32(proving.threshold_u32(prover.params.k1 * 64,
-                                             prover.meta.total_labels)))
-    tail = (jnp.uint32(count), jnp.uint32(start & 0xFFFFFFFF),
-            jnp.uint32(start >> 32))
-    prover.store.close()
-    outs = []
-    for fn in (step, lambda *a: proving.prove_scan_step_jit(
-            *a, n_nonces=ng, max_hits=cap)):
-        outs.append([np.asarray(x) for x in fn(
-            *args, *proving.init_hit_state(ng, cap), *tail)])
-    check(int(outs[1][1].min()) > 0, "scan-step check saw an empty row")
-    for got, want in zip(*outs):
-        check(np.array_equal(got, want),
-              "default scan step != proving.prove_scan_step_jit")
+    cw = jnp.asarray(proving.challenge_words(challenge))
+    lw = jnp.asarray(scrypt.labels_to_words(labels))
+    # ~64x the proof threshold so every nonce row carries hits
+    thr = jnp.uint32(proving.threshold_u32(prover.params.k1 * 64,
+                                           prover.meta.total_labels))
+    words = [count, start & 0xFFFFFFFF, start >> 32]
+    bases = 16 + ng * np.arange(groups)
+    got = [np.asarray(x) for x in step(
+        cw, jnp.asarray(bases, jnp.uint32), lw,
+        jnp.asarray(words, jnp.uint32), thr,
+        *proving.init_hit_state(groups * ng, cap))]
+    per_group = [proving.prove_scan_step_jit(
+        cw, jnp.uint32(base), jnp.asarray(lo), jnp.asarray(hi), lw, thr,
+        *proving.init_hit_state(ng, cap), *map(jnp.uint32, words),
+        n_nonces=ng, max_hits=cap) for base in bases]
+    # counts and batch counts stack by row; the carry is (2, rows, cap)
+    want = [np.concatenate([np.asarray(o[i]) for o in per_group], axis=ax)
+            for i, ax in enumerate((0, 0, 1))]
+    check(int(want[1].min()) > 0, "scan-step check saw an empty row")
+    for g, w in zip(got, want):
+        check(np.array_equal(g, w),
+              "default window step != proving.prove_scan_step_jit "
+              "group by group")
 
 
 def run_prove(dep: Deployment, data_dir: Path, challenge: bytes, params,

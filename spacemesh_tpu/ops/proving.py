@@ -214,6 +214,66 @@ def prove_scan_step_jit(challenge_words, nonce_base, idx_lo, idx_hi,
                              start_hi, max_hits=max_hits)
 
 
+def lane_indices(b: int, start_lo, start_hi, sharding=None):
+    """Global label indices of a batch's ``b`` lanes, made on the device:
+    ``start + lane`` as (lo, hi) u32 halves, the carry into the hi word
+    taken the way ``merge_hits`` takes it for hit positions. ``sharding``
+    pins the lane axis where the label words are lane-sharded."""
+    lane = jnp.arange(b, dtype=jnp.uint32)
+    if sharding is not None:
+        lane = jax.lax.with_sharding_constraint(lane, sharding)
+    lo = start_lo + lane
+    return lo, start_hi + (lo < lane).astype(jnp.uint32)
+
+
+def scan_window(group_step, challenge_words, bases, label_words, meta,
+                threshold, hit_counts, hit_carry, *, lane_sharding=None):
+    """The window step both backends share: ONE program per label batch
+    that runs ``group_step`` (a per-group scan step, its ``n_nonces`` and
+    ``max_hits`` bound) once per base in ``bases`` over the same batch.
+
+    ``meta`` is the batch's three u32 words ``[valid, start_lo,
+    start_hi]``, uploaded beside ``label_words`` (4, B); the lane indices
+    are made here (:func:`lane_indices`), not sent. The hit state is one
+    pair for the whole window, group-major: ``(groups * n_nonces,)``
+    counts and ``(2, groups * n_nonces, cap)`` carry; row ``g * n_nonces
+    + k`` is nonce ``bases[g] + k``. ``group_step`` is the cached inner
+    jit, so its body is traced and lowered once however many groups the
+    window has. Returns (hit_counts', batch_counts, hit_carry') like the
+    per-group step, each over all the window's nonces."""
+    groups = bases.shape[0]
+    ng = hit_counts.shape[0] // groups
+    valid, start_lo, start_hi = meta[0], meta[1], meta[2]
+    idx_lo, idx_hi = lane_indices(label_words.shape[1], start_lo, start_hi,
+                                  lane_sharding)
+    outs = [group_step(challenge_words, bases[g], idx_lo, idx_hi,
+                       label_words, threshold,
+                       hit_counts[g * ng:(g + 1) * ng],
+                       hit_carry[:, g * ng:(g + 1) * ng],
+                       valid, start_lo, start_hi)
+            for g in range(groups)]
+    counts, batch_counts, carry = zip(*outs)
+    return (jnp.concatenate(counts), jnp.concatenate(batch_counts),
+            jnp.concatenate(carry, axis=1))
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("n_nonces", "max_hits", "lane_sharding"),
+                   donate_argnums=(5, 6))
+def prove_scan_step_window(challenge_words, bases, label_words, meta,
+                           threshold, hit_counts, hit_carry, *,
+                           n_nonces: int, max_hits: int, lane_sharding=None):
+    """One pipelined prove step over a whole nonce window: every group of
+    ``bases`` through :func:`prove_scan_step_jit`'s body in ONE program
+    (:func:`scan_window`), so a batch is one upload, one program call and
+    one ``(groups * n_nonces,)`` count vector back."""
+    return scan_window(
+        functools.partial(prove_scan_step_jit, n_nonces=n_nonces,
+                          max_hits=max_hits),
+        challenge_words, bases, label_words, meta, threshold, hit_counts,
+        hit_carry, lane_sharding=lane_sharding)
+
+
 def init_hit_state(n_nonces: int, cap: int):
     """Fresh (hit_counts, hit_carry) device state for one prove pass."""
     return (jnp.zeros(n_nonces, jnp.int32),

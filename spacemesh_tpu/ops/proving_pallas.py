@@ -24,7 +24,9 @@ One u32 of hit bits per lane is the whole kernel output (the (n_nonces, B)
 mask would be 4-16x the bytes); the surrounding jit unpacks it and
 ``prove_scan_step_pallas`` runs the same compaction epilogue as the XLA
 step (ops/proving.py compact_and_merge), so the mask never crosses
-PCIe and the only per-batch D2H is the (n_nonces,) count vector.
+PCIe. A prove session runs ``prove_scan_step_window_pallas``: that step
+once per nonce group of the pass, in one program over one uploaded batch,
+and the only per-batch D2H is its one count vector.
 
 Grid: lane tiles of LANE_TILE. ``interpret=True`` runs the kernel on CPU
 (the test path); on TPU the same call compiles via Mosaic.
@@ -161,6 +163,23 @@ def prove_scan_step_pallas(challenge_words, nonce_base, idx_lo, idx_hi,
                             n_nonces=n_nonces, interpret=interpret)
     return proving.compact_and_merge(mask, hit_counts, hit_carry, start_lo,
                                      start_hi, max_hits=max_hits)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("n_nonces", "max_hits", "interpret"),
+                   donate_argnums=(5, 6))
+def prove_scan_step_window_pallas(challenge_words, bases, label_words, meta,
+                                  threshold, hit_counts, hit_carry, *,
+                                  n_nonces: int, max_hits: int,
+                                  interpret: bool = False):
+    """Pallas-backed twin of ops.proving.prove_scan_step_window: the
+    kernel runs once per group of ``bases`` (``n_nonces`` each), all in
+    one program over one uploaded batch."""
+    return proving.scan_window(
+        functools.partial(prove_scan_step_pallas, n_nonces=n_nonces,
+                          max_hits=max_hits, interpret=interpret),
+        challenge_words, bases, label_words, meta, threshold, hit_counts,
+        hit_carry)
 
 
 def proving_scan(challenge: bytes, nonce_base: int, indices, labels: np.ndarray,

@@ -117,30 +117,35 @@ def scrypt_labels_sharded(mesh: Mesh, commitment_words, idx_lo, idx_hi,
     return scrypt.scrypt_labels_jit(cw, idx_lo, idx_hi, n=n, impl=impl)
 
 
-def prove_step_sharded(mesh: Mesh, challenge_words, nonce_base, idx_lo,
-                       idx_hi, label_words, threshold, hit_counts, hit_carry,
-                       valid, start_lo, start_hi, *, n_nonces: int,
-                       max_hits: int):
-    """One sharded streaming-prove step (the multichip prove path).
+def prove_batch_shardings(mesh: Mesh) -> list[NamedSharding]:
+    """Where the two arrays of one prove batch's upload go: the (4, B)
+    label words lane-sharded, the batch's start/count words replicated
+    (``jax.device_put([label_words, meta], prove_batch_shardings(mesh))``
+    is the prover's one transfer a batch)."""
+    lay = _layouts(mesh)
+    return [lay.lane, lay.replicated]
 
-    Label lanes are striped over the mesh exactly like
-    ``labels_with_min_sharded`` stripes init batches; the Salsa20/8 sweep
-    is embarrassingly parallel per lane, and GSPMD lowers the compaction
-    epilogue's small reductions/gathers to ICI collectives. The donated
-    (hit_counts, hit_carry) state stays replicated (see
-    ops/proving.py merge_hits); the prover replicates it via
-    ``replicate()`` before the first batch of a pass. Batch size must
+
+def prove_window_step_sharded(mesh: Mesh, challenge_words, bases,
+                              label_words, meta, threshold, hit_counts,
+                              hit_carry, *, n_nonces: int, max_hits: int):
+    """One sharded streaming-prove window step (the multichip prove path):
+    ``proving.prove_scan_step_window`` with the label lanes striped over
+    the mesh exactly like ``labels_with_min_sharded`` stripes init
+    batches. The program makes its lane indices under the same sharding;
+    the Salsa20/8 sweep is embarrassingly parallel per lane, and GSPMD
+    lowers the compaction epilogue's small reductions/gathers to ICI
+    collectives. The donated (hit_counts, hit_carry) state stays
+    replicated (see ops/proving.py merge_hits); the prover replicates it
+    via ``replicate()`` before the first batch of a pass. Batch size must
     divide by the mesh size — the prover's pad-and-trim already makes
     every batch the full ``batch_labels``.
     """
     lay = _layouts(mesh)
-    idx_lo = lay.put_batch(idx_lo)
-    idx_hi = lay.put_batch(idx_hi)
-    lw = lay.put_lane(label_words)
-    return proving.prove_scan_step_jit(
-        jnp.asarray(challenge_words), nonce_base, idx_lo, idx_hi, lw,
-        threshold, hit_counts, hit_carry, valid, start_lo, start_hi,
-        n_nonces=n_nonces, max_hits=max_hits)
+    return proving.prove_scan_step_window(
+        jnp.asarray(challenge_words), bases, lay.put_lane(label_words), meta,
+        threshold, hit_counts, hit_carry, n_nonces=n_nonces,
+        max_hits=max_hits, lane_sharding=lay.batch)
 
 
 @functools.partial(jax.jit, static_argnames=("n",))

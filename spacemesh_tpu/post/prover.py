@@ -9,12 +9,20 @@ the init side's (post/initializer.py):
 
   read      — a bounded background reader pool (post/data.py LabelReader)
               prefetches label batches while the device scans;
-  dispatch  — up to K batches in flight, each one compiled program
-              (``prove_scan_step_jit`` / ``prove_scan_step_pallas``) that
-              scans a nonce group, compacts hits on device and merges them
-              into a *donated* running hit state — ragged tails are padded
-              to the full batch shape so one shape compiles per pass;
-  retire    — the only per-batch D2H is a (nonce_group,) count vector; the
+  dispatch  — up to K batches in flight; a batch crosses to the device
+              ONCE: one ``jax.device_put`` of its label words and its
+              three start/count words (16 B a label: the program makes
+              its own lane indices), then one compiled program, the
+              window step (``prove_scan_step_window`` /
+              ``prove_scan_step_window_pallas``), that scans every nonce
+              group of the pass, compacts hits on device and merges them
+              into ONE *donated* running hit state — ragged tails are
+              padded to the full batch shape so one shape compiles per
+              pass;
+  retire    — the only per-batch D2H is ONE (window_groups * nonce_group,)
+              count vector, its copy started right after the enqueue
+              (``copy_to_host_async``) so that it has landed by the time
+              the batch retires, ``inflight - 1`` batches later; the
               packed (nonce, index) hit pairs are fetched once per pass.
 
 One disk pass covers a whole nonce *window* (``window_groups`` groups per
@@ -26,7 +34,8 @@ That rule makes the pipelined proof bit-identical to the legacy serial
 scan's (kept as ``prove_serial`` — the bench baseline and fallback).
 
 On multi-device the label lanes are sharded over the mesh per batch
-(parallel/mesh.py prove_step_sharded), the way init shards its batches.
+(parallel/mesh.py prove_window_step_sharded), the way init shards its
+batches.
 
 A proof for challenge ``ch`` is:
     nonce     — the winning proving nonce
@@ -66,6 +75,41 @@ def _env_int(name: str, default: int) -> int:
         return default
 
 
+def default_window_groups(platform: str) -> int:
+    """Nonce groups per disk pass where the caller names none: on TPU
+    disk bytes are the scarce resource and device FLOPs nearly free, so
+    the window widens there."""
+    return _env_int("SPACEMESH_PROVE_WINDOW_GROUPS",
+                    4 if platform == "tpu" else 1)
+
+
+def bucket_batch(batch_labels: int, use_pallas: bool) -> int:
+    """The one compiled batch shape for a requested size: rounded up to
+    the compaction segment (the Pallas lane tile on that path), then to
+    its power-of-two shape bucket (``Prover.__init__`` says why)."""
+    tile = proving_pallas.LANE_TILE if use_pallas else proving.HIT_SEGMENT
+    return scrypt.shape_bucket(-(-max(batch_labels, tile) // tile) * tile)
+
+
+def window_step(nonce_group: int, max_hits: int, *, use_pallas: bool,
+                mesh=None):
+    """The window step of one backend with its static arguments bound
+    (``Prover.scan_step`` for a store's prover; the warmers for the
+    program a default prover will run): sharded XLA on a mesh, else the
+    Pallas step or the XLA step on one device."""
+    if mesh is not None:
+        from ..parallel import mesh as pmesh
+        return functools.partial(pmesh.prove_window_step_sharded, mesh,
+                                 n_nonces=nonce_group, max_hits=max_hits)
+    if use_pallas:
+        return functools.partial(
+            proving_pallas.prove_scan_step_window_pallas,
+            n_nonces=nonce_group, max_hits=max_hits,
+            interpret=accel.pallas_interpret())
+    return functools.partial(proving.prove_scan_step_window,
+                             n_nonces=nonce_group, max_hits=max_hits)
+
+
 @dataclasses.dataclass
 class Proof:
     nonce: int
@@ -98,11 +142,12 @@ class ProverStats:
 
     windows: int = 0          # nonce windows swept
     batches: int = 0          # label batches dispatched
+    retire_ready: int = 0     # of them: count vector landed before retire
     labels_swept: int = 0     # labels covered across all passes
     read_wait_s: float = 0.0  # blocked on the reader pool
     read_io_s: float = 0.0    # filesystem time inside the reader pool
     dispatch_s: float = 0.0   # host time converting + enqueueing batches
-    retire_s: float = 0.0     # blocked fetching per-batch count vectors
+    retire_s: float = 0.0     # reading per-batch count vectors
     d2h_bytes: int = 0        # compacted device->host traffic
     early_exited: bool = False
     elapsed_s: float = 0.0
@@ -150,17 +195,14 @@ class Prover:
         # land on ONE prove_scan_step executable instead of minting one
         # each (ops/scrypt.py shape_bucket; both tiles are powers of two,
         # so bucketing preserves the tile multiple)
-        tile = proving_pallas.LANE_TILE if use_pallas else proving.HIT_SEGMENT
-        self.batch_labels = scrypt.shape_bucket(
-            -(-max(batch_labels, tile) // tile) * tile)
+        self.batch_labels = bucket_batch(batch_labels, use_pallas)
         if pipelined is None:
             pipelined = os.environ.get(
                 "SPACEMESH_PROVE_PIPELINE", "1") not in ("0", "off")
         self.pipelined = pipelined
         self.window_groups = max(window_groups if window_groups is not None
-                                 else _env_int("SPACEMESH_PROVE_WINDOW_GROUPS",
-                                               4 if self._platform == "tpu"
-                                               else 1), 1)
+                                 else default_window_groups(self._platform),
+                                 1)
         self.inflight = max(inflight if inflight is not None
                             else _env_int("SPACEMESH_PROVE_INFLIGHT",
                                           DEFAULT_INFLIGHT), 1)
@@ -321,28 +363,20 @@ class Prover:
     # -- streaming pipeline -------------------------------------------------
 
     def scan_step(self):
-        """The scan step a pipelined prove runs, bound ONCE per prove:
+        """The window step a pipelined prove runs, bound ONCE per prove:
         ``(step, mesh, impl)`` — the callable, the mesh it shards over
         (None on one device) and which backend it is (``xla-sharded``,
-        ``pallas`` or ``xla``). ProveSession runs exactly this, and
-        chip_smoke.py reports and checks it."""
+        ``pallas`` or ``xla``). All three take ``(challenge_words, bases,
+        label_words, meta, threshold, hit_counts, hit_carry)`` and return
+        ``(hit_counts, batch_counts, hit_carry)`` over every nonce of the
+        window (ops/proving.py scan_window). ProveSession runs exactly
+        this, and chip_smoke.py reports and checks it."""
         mesh = self._resolve_mesh()
         impl = "xla-sharded" if mesh is not None else (
             "pallas" if self.use_pallas else "xla")
-        return self._make_step(mesh), mesh, impl
-
-    def _make_step(self, mesh):
-        ng, cap = self.nonce_group, max(self.params.k2, 1)
-        if mesh is not None:
-            from ..parallel import mesh as pmesh
-            return functools.partial(pmesh.prove_step_sharded, mesh,
-                                     n_nonces=ng, max_hits=cap)
-        if self.use_pallas:
-            return functools.partial(
-                proving_pallas.prove_scan_step_pallas, n_nonces=ng,
-                max_hits=cap, interpret=accel.pallas_interpret())
-        return functools.partial(proving.prove_scan_step_jit,
-                                 n_nonces=ng, max_hits=cap)
+        step = window_step(self.nonce_group, max(self.params.k2, 1),
+                           use_pallas=self.use_pallas, mesh=mesh)
+        return step, mesh, impl
 
     def _scan_window(self, cw, thr, nonce_base, groups, step, mesh, stats,
                      tenant: str = "-"):
@@ -351,11 +385,13 @@ class Prover:
 
         The bounded read->dispatch->retire window is the shared runtime
         engine's (runtime/engine.py); this method supplies the prove
-        callbacks. Under a trace capture the pass is one ``prove.window``
-        span and every per-batch read/dispatch/retire span carries the
-        SAME ``window`` attribute (the pass's base nonce), so a timeline
-        groups a window's whole ladder even when batches from two
-        windows interleave."""
+        callbacks. A batch crosses the host-device boundary once in each
+        direction: one upload, one program, one count vector back. Under
+        a trace capture the pass is one ``prove.window`` span and every
+        per-batch read/dispatch/retire span carries the SAME ``window``
+        attribute (the pass's base nonce), so a timeline groups a
+        window's whole ladder even when batches from two windows
+        interleave."""
         meta, p = self.meta, self.params
         total = meta.total_labels
         b = self.batch_labels
@@ -369,20 +405,21 @@ class Prover:
         reader = None
         try:
             ranges = [(s, min(b, total - s)) for s in range(0, total, b)]
-            states = []
-            for _ in range(groups):
-                counts, carry = proving.init_hit_state(ng, cap)
-                if mesh is not None:
-                    from ..parallel import mesh as pmesh
-                    counts = pmesh.replicate(mesh, counts)
-                    carry = pmesh.replicate(mesh, carry)
-                states.append([counts, carry])
+            # ONE donated hit state for the whole window, group-major;
+            # a mesh changes placement and nothing else
+            state = list(proving.init_hit_state(groups * ng, cap))
+            where = None
+            if mesh is not None:
+                from ..parallel import mesh as pmesh
+                state = [pmesh.replicate(mesh, x) for x in state]
+                where = pmesh.prove_batch_shardings(mesh)
             host_counts = np.zeros(ng * groups, dtype=np.int64)
-            # a device scalar is a host->device transfer of its own
-            # (0.5 ms each on a v5e's host, PERF.md section 6): each
-            # group's base nonce goes up once a pass, and a batch's
-            # count and start once a batch, not once a step call
-            bases = [jnp.uint32(nonce_base + g * ng) for g in range(groups)]
+            # the groups' base nonces go up once a pass; a batch's count
+            # and start travel with its labels (a device scalar made
+            # here would be a host->device transfer of its own: 0.5 ms
+            # each on a v5e's host, PERF.md section 6)
+            bases = jnp.asarray(
+                nonce_base + ng * np.arange(groups), dtype=jnp.uint32)
             reader = self.store.start_reader(ranges, self.readers,
                                              self.reader_queue)
             metrics.post_prove_windows.inc()
@@ -401,48 +438,43 @@ class Prover:
                 stats.read_wait_s += time.perf_counter() - tr
                 with tracing.span("prove.convert", {"window": nonce_base}
                                   if traced else None):
-                    labels = np.frombuffer(raw, dtype=np.uint8).reshape(
-                        count, scrypt.LABEL_BYTES)
+                    # a view of the bytes as read, one row of four LE
+                    # words a label; its transpose is the program's
+                    # word-major (4, B) and is laid out by the upload
+                    words = np.frombuffer(raw, dtype="<u4").reshape(count, 4)
                     if count < b:  # pad-and-trim: one shape per pass
-                        labels = np.concatenate([
-                            labels,
-                            np.zeros((b - count, scrypt.LABEL_BYTES),
-                                     np.uint8)])
-                    idx = np.arange(start, start + b, dtype=np.uint64)
-                    lo, hi = scrypt.split_indices(idx)
-                    lw = scrypt.labels_to_words(labels)
-                h2d = lo.nbytes + hi.nbytes + lw.nbytes
+                        words = np.concatenate([
+                            words, np.zeros((b - count, 4), words.dtype)])
+                    host = [words.T.astype(np.uint32, copy=False),
+                            np.array([count, start & 0xFFFFFFFF,
+                                      start >> 32], dtype=np.uint32)]
+                h2d = sum(a.nbytes for a in host)
                 with tracing.span("prove.upload",
-                                  {"window": nonce_base, "h2d_bytes": h2d}
+                                  {"window": nonce_base, "h2d_bytes": h2d,
+                                   "arrays": len(host)}
                                   if traced else None):
-                    jlo, jhi, jlw = (jnp.asarray(lo), jnp.asarray(hi),
-                                     jnp.asarray(lw))
+                    lw, words = jax.device_put(host, where)
                 metrics.post_prove_h2d_bytes.inc(h2d)
-                # the batch's device.flight runs from here to the fetch
-                # of its count vectors (_retire)
+                # the batch's device.flight runs from here to the read
+                # of its count vector (_retire)
                 t_flight = time.perf_counter_ns() if traced else 0
-                bcs = []
                 with tracing.span("prove.enqueue",
                                   {"window": nonce_base, "groups": groups,
-                                   "batch": b, "nonces": groups * ng}
+                                   "batch": b, "nonces": groups * ng,
+                                   "programs": 1}
                                   if traced else None):
-                    valid = jnp.uint32(count)
-                    start_lo = jnp.uint32(start & 0xFFFFFFFF)
-                    start_hi = jnp.uint32(start >> 32)
-                    for g in range(groups):
-                        counts, carry = states[g]
-                        counts, bc, carry = step(
-                            cw, bases[g], jlo, jhi, jlw, thr, counts, carry,
-                            valid, start_lo, start_hi)
-                        states[g] = [counts, carry]
-                        bcs.append(bc)
+                    state[0], bc, state[1] = step(cw, bases, lw, words, thr,
+                                                  *state)
+                    # the copy starts now and has landed when the batch
+                    # retires, inflight - 1 batches from here
+                    bc.copy_to_host_async()
                 # progress must advance PER BATCH, here in the callback
                 # — folding the engine's count in after the pass would
                 # freeze the liveness watchdog for the whole disk pass
                 # (ProveSession registers it on stats.batches)
                 stats.batches += 1
                 metrics.post_prove_batches.inc()
-                return start + count, bcs, count, t_flight
+                return start + count, bc, count, t_flight
 
             def retire(ticket):
                 retired_end[0] = ticket[0]
@@ -479,36 +511,36 @@ class Prover:
         if qualified.size == 0:
             return None, None
         w = int(qualified[0])
-        counts, carry = states[w // ng]
-        indices = proving.decode_hits(counts, carry, w % ng, p.k2)
+        counts, carry = state
+        indices = proving.decode_hits(counts, carry, w, p.k2)
         stats.d2h_bytes += carry.nbytes + counts.nbytes
         metrics.post_prove_d2h_bytes.inc(carry.nbytes + counts.nbytes)
         return nonce_base + w, indices
 
     def _retire(self, item, host_counts, total, stats,
                 nonce_base: int = 0) -> bool:
-        """Fetch one batch's per-nonce count vectors; True on sound early
-        exit: some nonce has k2 hits and every lower nonce in the window
-        provably cannot reach k2 with the labels left in this pass (lower
-        windows already failed their full pass, so the winner is final and
-        identical to the serial prover's end-of-pass pick)."""
-        scanned_end, bcs, count, t_flight = item
+        """Read one batch's count vector (one per-nonce count for every
+        nonce of the window); True on sound early exit: some nonce has k2
+        hits and every lower nonce in the window provably cannot reach k2
+        with the labels left in this pass (lower windows already failed
+        their full pass, so the winner is final and identical to the
+        serial prover's end-of-pass pick)."""
+        scanned_end, bc, count, t_flight = item
         p = self.params
-        ng = self.nonce_group
         tr = time.perf_counter()
         # the engine's prove.retire span (runtime/engine.py) is open here
-        d2h = 0
-        for g, bc in enumerate(bcs):
-            vec = np.asarray(bc)
-            host_counts[g * ng:(g + 1) * ng] += vec
-            d2h += vec.nbytes
-        stats.d2h_bytes += d2h
-        metrics.post_prove_d2h_bytes.inc(d2h)
+        ready = bc.is_ready()   # the prefetch (dispatch) hid the fetch
+        vec = np.asarray(bc)    # the batch's one blocking sync
+        host_counts += vec
+        stats.retire_ready += ready
+        stats.d2h_bytes += vec.nbytes
+        metrics.post_prove_d2h_bytes.inc(vec.nbytes)
         if t_flight:
             tracing.interval("device.flight", t_flight,
                              {"program": "prove_scan", "labels": count,
-                              "groups": len(bcs), "d2h_bytes": d2h,
-                              "window": nonce_base})
+                              "groups": len(vec) // self.nonce_group,
+                              "d2h_bytes": vec.nbytes, "syncs": 1,
+                              "ready": ready, "window": nonce_base})
         stats.retire_s += time.perf_counter() - tr
         qualified = host_counts >= p.k2
         if not qualified.any():

@@ -10,7 +10,8 @@ enumerate them without importing every pipeline:
                     program over many identities' lanes (per-lane
                     commitment words), VRF minimum folded per tenant on
                     host (runtime/scheduler.py).
-* ``prove_scan``  — the streaming prover's scan step (post/prover.py).
+* ``prove_scan``  — the streaming prover's window step: the one program
+                    a batch a default Prover binds here (post/prover.py).
 * ``verify``      — the batched POST verifier's one flight: per-lane
                     label program, ``words_to_le``, per-lane proving
                     hash (post/verifier.py).
@@ -142,25 +143,32 @@ def _warm_init_pack(n: int, batch: int) -> dict:
 
 
 def _warm_prove_scan(n: int, batch: int) -> dict:
+    import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from ..ops import proving, scrypt
+    from ..ops import proving
+    from ..post import prover
+    from ..utils import accel
 
-    b = scrypt.shape_bucket(-(-batch // proving.HIT_SEGMENT)
-                            * proving.HIT_SEGMENT)
-    ng, cap = 16, 37  # prover defaults (nonce_group, k2)
+    # the window step a default Prover binds on this platform, at its
+    # shapes (post/prover.py): the Pallas step wherever it runs
+    # compiled, every nonce group of a pass in the one program
+    use_pallas = not accel.pallas_interpret()
+    b = prover.bucket_batch(batch, use_pallas)
+    ng, cap = prover.DEFAULT_NONCE_GROUP, prover.ProofParams().k2
+    groups = prover.default_window_groups(jax.devices()[0].platform)
+    step = prover.window_step(ng, cap, use_pallas=use_pallas)
     cw = jnp.asarray(proving.challenge_words(bytes(32)))
-    idx = np.arange(b, dtype=np.uint64)
-    lo, hi = scrypt.split_indices(idx)
-    lw = jnp.zeros((4, b), jnp.uint32)
-    counts, carry = proving.init_hit_state(ng, cap)
-    doc: dict = {"batch": b}
-    _timed(doc, "prove_scan_step",
-           lambda: proving.prove_scan_step_jit(
-               cw, jnp.uint32(0), jnp.asarray(lo), jnp.asarray(hi), lw,
-               jnp.uint32(1 << 30), counts, carry, jnp.uint32(b),
-               jnp.uint32(0), jnp.uint32(0), n_nonces=ng, max_hits=cap))
+    bases = jnp.asarray(ng * np.arange(groups), jnp.uint32)
+    lw, words = jax.device_put([np.zeros((4, b), np.uint32),
+                                np.array([b, 0, 0], np.uint32)])
+    counts, carry = proving.init_hit_state(groups * ng, cap)
+    doc: dict = {"batch": b, "nonce_group": ng, "groups": groups,
+                 "pallas": use_pallas}
+    _timed(doc, "prove_scan_step_window",
+           lambda: step(cw, bases, lw, words, jnp.uint32(1 << 30), counts,
+                        carry))
     return doc
 
 

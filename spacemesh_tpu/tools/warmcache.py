@@ -17,8 +17,8 @@ What gets compiled per (N, bucketed batch):
 * when the mesh race says ``devices > 1``: the GSPMD-sharded twins via
   parallel/mesh.py — the executables the streaming initializer and the
   bench mesh headline hit;
-* with ``--prove``: the streaming prover's scan step at its default
-  (bucketed) batch.
+* with ``--prove``: the streaming prover's window step (the program a
+  default Prover runs on this platform) at its default (bucketed) batch.
 
 Because decisions are taken through ops/autotune.py, a cold host races
 first (and persists the winners beside the cache), so one warmcache run
@@ -144,30 +144,14 @@ def _warm_shape(n: int, batch: int, mesh_ok: bool) -> dict:
 
 
 def _warm_prove(batch: int) -> dict:
-    """Compile the streaming prover's scan step at its bucketed batch."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
+    """Compile the window step a default streaming prover runs here, at
+    its bucketed batch (the runtime's ``prove_scan`` recipe)."""
+    from ..runtime import workloads
 
-    from ..ops import proving, scrypt
-
-    b = scrypt.shape_bucket(-(-batch // proving.HIT_SEGMENT)
-                            * proving.HIT_SEGMENT)
-    ng, cap = 16, 37  # prover defaults (nonce_group, k2)
-    cw = jnp.asarray(proving.challenge_words(bytes(32)))
-    idx = np.arange(b, dtype=np.uint64)
-    lo, hi = scrypt.split_indices(idx)
-    lw = jnp.zeros((4, b), jnp.uint32)
-    counts, carry = proving.init_hit_state(ng, cap)
-    t0 = time.perf_counter()
-    out = proving.prove_scan_step_jit(
-        cw, jnp.uint32(0), jnp.asarray(lo), jnp.asarray(hi), lw,
-        jnp.uint32(1 << 30), counts, carry, jnp.uint32(b),
-        jnp.uint32(0), jnp.uint32(0), n_nonces=ng, max_hits=cap)
-    jax.block_until_ready(out)
-    dt = round(time.perf_counter() - t0, 2)
-    _log(f"  prove_scan_step b={b}: {dt}s")
-    return {"batch": b, "nonce_group": ng, "compile_s": dt}
+    doc = workloads.get("prove_scan").warm(0, batch)
+    _log(f"  prove_scan_step_window b={doc['batch']} "
+         f"groups={doc['groups']}: {doc['prove_scan_step_window']}s")
+    return doc
 
 
 def _warm_runtime_kinds(n: int, batch: int, pack_lanes: int) -> dict:

@@ -383,7 +383,8 @@ post_prove_d2h_bytes = REGISTRY.counter(
     "bytes copied device->host by the prover (compacted hits, not masks)")
 post_prove_h2d_bytes = REGISTRY.counter(
     "post_prove_h2d_bytes_total",
-    "bytes copied host->device by the prover (label words and index halves)")
+    "bytes copied host->device by the prover (label words and each "
+    "batch's three start/count words)")
 post_prove_labels_per_sec = REGISTRY.gauge(
     "post_prove_labels_per_sec",
     "store labels covered per second by the last prove call")

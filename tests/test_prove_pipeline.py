@@ -79,6 +79,43 @@ def test_sharded_backend_matches_serial(unit, serial_proof, monkeypatch):
     assert prover.prove(CH) == serial_proof
 
 
+@pytest.mark.parametrize("backend", ["pallas", "mesh"])
+def test_wide_window_backends_match_serial(unit, serial_proof, monkeypatch,
+                                           backend):
+    # the window step over several nonce groups (one program a batch)
+    # picks the serial winner on the other two backends too
+    d, _ = unit
+    kw = {"use_pallas": True, "mesh": None}
+    if backend == "mesh":
+        monkeypatch.setenv("SPACEMESH_MESH", "1")
+        kw = {}
+    prover = Prover(d, PARAMS, batch_labels=1024, window_groups=3, **kw)
+    assert prover.scan_step()[2] == {"pallas": "pallas",
+                                     "mesh": "xla-sharded"}[backend]
+    assert prover.prove(CH) == serial_proof
+
+
+def test_one_count_vector_a_batch_and_its_prefetch_is_counted(unit):
+    # k1 < k2 here: no early exit, every dispatched batch retires
+    d, meta = unit
+    params = ProofParams(k1=8, k2=12, k3=8, pow_difficulty=bytes([255]) * 32)
+    prover = Prover(d, params, batch_labels=512, window_groups=4)
+    before = metrics.post_prove_d2h_bytes.sample().get((), 0.0)
+    proof = prover.prove(CH)
+    stats = prover.last_stats
+    assert proof == Prover(d, params, batch_labels=512).prove_serial(CH)
+    ng, groups = prover.nonce_group, prover.window_groups
+    per_pass = meta.total_labels // prover.batch_labels
+    assert stats.batches == stats.windows * per_pass
+    # per retired batch ONE (groups * ng,) i32 vector; per deciding pass
+    # the one state pair (counts + lo/hi carry of k2 slots a nonce)
+    state = groups * ng * 4 + 2 * groups * ng * params.k2 * 4
+    assert stats.d2h_bytes == stats.batches * groups * ng * 4 + state
+    assert metrics.post_prove_d2h_bytes.sample().get((), 0.0) - before \
+        == stats.d2h_bytes
+    assert 0 <= stats.retire_ready <= stats.batches
+
+
 def test_ragged_tail_single_shape(unit, serial_proof):
     # 2048 labels with batch 768: ragged 512-label tail is padded, not
     # recompiled or path-flipped; proof unchanged

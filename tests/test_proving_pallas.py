@@ -3,6 +3,7 @@
 import hashlib
 
 import numpy as np
+import pytest
 
 from spacemesh_tpu.ops import proving, proving_pallas, scrypt
 
@@ -90,3 +91,92 @@ def test_step_equivalence_window_crossing_group_boundary():
     for counts, hits in (xla, pallas):
         assert np.array_equal(counts, want_counts)
         assert hits == want_hits
+
+
+# -- the window step: every nonce group of a pass in ONE program ------------
+
+
+def _window_backends():
+    import functools
+
+    import jax
+
+    from spacemesh_tpu.parallel import mesh as pmesh
+
+    def sharded(*a, **kw):
+        mesh = pmesh.data_mesh(jax.devices())
+        a = list(a)
+        a[5], a[6] = (pmesh.replicate(mesh, x) for x in a[5:7])
+        return pmesh.prove_window_step_sharded(mesh, *a, **kw)
+
+    return {"xla": proving.prove_scan_step_window,
+            "pallas": functools.partial(
+                proving_pallas.prove_scan_step_window_pallas,
+                interpret=True),
+            "mesh": sharded}
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas", "mesh"])
+def test_window_step_equals_per_group_steps(backend):
+    # one window-step program a batch == ``groups`` per-group steps a
+    # batch, bit for bit (counts, batch counts, carry), over two batches:
+    # the second a ragged tail whose lanes cross 2^32, so the device-made
+    # indices carry into the hi word where the host-made ones did
+    import jax.numpy as jnp
+
+    groups, ng, cap, b = 3, 4, 8, 1024
+    first = (1 << 32) - b - 100
+    batches = [(first, b), (first + b, 700)]
+    bases = 5 + ng * np.arange(groups)
+    cw = jnp.asarray(proving.challenge_words(CH))
+    # ~4 hits a nonce a batch: some rows fill partly, some overflow ``cap``
+    thr = jnp.uint32(proving.threshold_u32(4, b))
+    step = _window_backends()[backend]
+    state = proving.init_hit_state(groups * ng, cap)
+    ref = [proving.init_hit_state(ng, cap) for _ in range(groups)]
+    for start, count in batches:
+        idx = np.arange(start, start + b, dtype=np.uint64)
+        labels = np.zeros((b, scrypt.LABEL_BYTES), np.uint8)
+        labels[:count] = scrypt.scrypt_labels(COMMIT, idx[:count], n=2)
+        lw = scrypt.labels_to_words(labels)
+        words = [count, start & 0xFFFFFFFF, start >> 32]
+        counts, bc, carry = step(
+            cw, jnp.asarray(bases, jnp.uint32), jnp.asarray(lw),
+            jnp.asarray(words, jnp.uint32), thr, *state,
+            n_nonces=ng, max_hits=cap)
+        state = (counts, carry)
+        lo, hi = scrypt.split_indices(idx)
+        want_bc = []
+        for g in range(groups):
+            c, gbc, h = proving.prove_scan_step_jit(
+                cw, jnp.uint32(bases[g]), jnp.asarray(lo), jnp.asarray(hi),
+                jnp.asarray(lw), thr, *ref[g], *map(jnp.uint32, words),
+                n_nonces=ng, max_hits=cap)
+            ref[g] = (c, h)
+            want_bc.append(np.asarray(gbc))
+        assert np.array_equal(np.asarray(bc), np.concatenate(want_bc))
+        assert np.array_equal(np.asarray(counts), np.concatenate(
+            [np.asarray(c) for c, _ in ref]))
+        assert np.array_equal(np.asarray(carry), np.concatenate(
+            [np.asarray(h) for _, h in ref], axis=1))
+    assert np.asarray(bc).shape == (groups * ng,)
+    got = np.asarray(state[0])
+    assert got.min() < cap < got.max(), "want rows under AND over cap"
+    # the carried indices are global, and past 2^32 where they should be
+    rows = [proving.decode_hits(*state, k, cap) for k in range(groups * ng)]
+    assert all(r == sorted(r) and first <= r[0] and r[-1] < first + b + 700
+               for r in rows)
+    assert any(r[-1] >= 1 << 32 for r in rows)
+
+
+def test_lane_indices_carry_into_the_hi_word():
+    import jax.numpy as jnp
+
+    start = (7 << 32) - 3
+    lo, hi = proving.lane_indices(8, jnp.uint32(start & 0xFFFFFFFF),
+                                  jnp.uint32(start >> 32))
+    want_lo, want_hi = scrypt.split_indices(
+        np.arange(start, start + 8, dtype=np.uint64))
+    assert np.array_equal(np.asarray(lo), want_lo)
+    assert np.array_equal(np.asarray(hi), want_hi)
+    assert lo.dtype == hi.dtype == jnp.uint32

@@ -57,6 +57,7 @@ def build(name, fn, *args, **kw):
     m = c.memory_analysis()
     out[name] = {"temp": m.temp_size_in_bytes,
                  "all_gather": "all-gather" in c.as_text()}
+    return c
 
 
 # init: the fused label + VRF min-scan program at mainnet N (a small
@@ -79,6 +80,19 @@ build("prove_step_pallas", proving_pallas.prove_scan_step_pallas,
       *step_args, n_nonces=ng, max_hits=cap, interpret=False)
 build("prove_mask_pallas", proving_pallas.proving_scan_pallas,
       *step_args[:6], n_nonces=ng, interpret=False)
+# ... and the window steps a session runs: every nonce group of a pass
+# (4 on tpu) in one program over one uploaded batch, indices made on
+# the device
+groups = 4
+window_args = (sds((8,)), sds((groups,)), sds((4, b)), sds((3,)), sds(()),
+               sds((groups * ng,), jnp.int32), sds((2, groups * ng, cap)))
+build("prove_window_xla", proving.prove_scan_step_window, *window_args,
+      n_nonces=ng, max_hits=cap)
+c = build("prove_window_pallas", proving_pallas.prove_scan_step_window_pallas,
+          *window_args, n_nonces=ng, max_hits=cap, interpret=False)
+out["prove_window_pallas"]["kernel_calls"] = sum(
+    "custom-call" in line and "_scan_pallas" in line
+    for line in c.as_text().splitlines())
 
 # k2pow: search (one 2^16-nonce batch) and batched witness verification
 b = 1 << 16
@@ -124,9 +138,16 @@ def test_tpu_default_programs_compile_for_v5e(lowered):
     # the Pallas scan step is the tpu default (and stays reachable via
     # use_pallas=True), so Mosaic must keep compiling it
     for name in ("labels_min_fused", "prove_step_xla", "prove_step_pallas",
-                 "prove_mask_pallas", "pow_hash", "pow_below_target",
+                 "prove_mask_pallas", "prove_window_xla",
+                 "prove_window_pallas", "pow_hash", "pow_below_target",
                  "pow_verify"):
         assert name in lowered, name
+
+
+def test_window_step_keeps_the_kernel_ops_the_trace_metrics_match(lowered):
+    # scan_roofline / scan_kernel_share sum the device time of op events
+    # named ``_scan_pallas ... custom-call``: one per nonce group
+    assert lowered["prove_window_pallas"]["kernel_calls"] == 4
 
 
 def test_labels_shard_over_four_chips_without_collectives(lowered):

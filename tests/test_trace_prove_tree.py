@@ -121,13 +121,44 @@ def test_every_batch_has_its_stages_and_its_flight(capture):
         == stats.labels_swept
 
 
-def test_upload_bytes_are_24_a_label_dispatched(capture):
+def test_upload_bytes_are_16_a_label_dispatched(capture):
+    # label words only: the program makes its own lane indices; the
+    # batch's count and start ride along as three u32 words
     _proof, _stats, evs, h2d = capture
     named = _named(evs)
     sent = sum(e["args"]["h2d_bytes"] for e in named["prove.upload"])
-    assert sent == 24 * sum(e["args"]["batch"]
-                            for e in named["prove.enqueue"])
+    assert sent == sum(16 * e["args"]["batch"] + 12
+                       for e in named["prove.enqueue"])
     assert h2d == sent      # the counter counts what the spans say
+
+
+def test_a_batch_crosses_the_boundary_once_each_way(capture):
+    # the mechanism of ISSUE 28, pinned as PR 24's was for post.verify:
+    # per batch ONE upload call, ONE program, ONE count vector back
+    _proof, stats, evs, _h2d = capture
+    named = _named(evs)
+    by_parent = {}
+    for name in ("prove.upload", "prove.enqueue"):
+        for e in named[name]:
+            by_parent.setdefault((e["args"]["parent"], name), []).append(e)
+    for d in named["prove.dispatch"]:
+        (up,) = by_parent[d["args"]["id"], "prove.upload"]
+        (enq,) = by_parent[d["args"]["id"], "prove.enqueue"]
+        assert up["args"]["arrays"] == 2    # both in one device_put
+        assert up["args"]["h2d_bytes"] == 16 * BATCH + 12
+        assert enq["args"]["programs"] == 1
+        assert enq["args"]["groups"] == GROUPS
+    flights = named["device.flight"]
+    assert len(flights) == len(named["prove.retire"])
+    for f in flights:
+        assert f["args"]["syncs"] == 1
+        assert f["args"]["groups"] == GROUPS
+        assert f["args"]["ready"] in (True, False)
+    # the prefetch's hit count is in the stats and equals the trace's
+    assert stats.retire_ready == sum(
+        bool(f["args"]["ready"]) for f in flights)
+    assert 0 <= stats.retire_ready <= stats.batches
+    assert "retire_ready" in stats.as_dict()
 
 
 def test_spans_are_free_when_the_tracer_is_off():
