@@ -13,10 +13,9 @@ runner whose parent never imports JAX.)
 
 Prints THREE JSON lines for the init side. The headline first:
   {"metric": "post_init_labels_per_sec...", "value": N, "unit": "labels/s",
-   "vs_baseline": N, "impl": "xla"|"xla-rows"|"pallas", "chunk": ...,
-   "tuned": "race"|"cache"|..., "fused": true}
-("impl"/"chunk" are the ROMix kernel decision the autotuner raced and
-persisted — ops/autotune.py, docs/ROMIX_KERNEL.md — and "fused" records
+   "vs_baseline": N, "impl": "xla", "chunk": null, "fused": true}
+("impl"/"chunk" are constants since the label kernel became one path —
+docs/ROMIX_KERNEL.md — and "fused" records
 that expand->romix->finish ran as one jitted program), then the
 kernel-only rate, isolating the memory-hard ROMix core from the PBKDF2
 envelope + pipeline overhead around it:
@@ -57,11 +56,11 @@ until the winning nonce is decided — the streaming pipeline's sound early
 exit plus read/compute overlap is what the speedup measures
 (docs/POST_PROVING.md).
 
-After the kernel-only line, the MESH headline (ISSUE 6): the autotuned
+After the kernel-only line, the MESH headline (ISSUE 6): the
 multi-device path — label lanes sharded over virtual host devices on the
 CPU (8 forced, the same count every test/driver entry point already
-configures), device count and layout chosen by the autotuner's
-mesh race (ops/autotune.py) — measured in a SUBPROCESS so the forced
+configures) where the mesh rule shards (parallel/mesh.py auto_mesh: on
+the CPU only under SPACEMESH_MESH) — measured in a SUBPROCESS so the forced
 host-device split cannot degrade the single-device lines above it. The
 probe returns the sha256 digest of its sharded labels; the parent
 recomputes the single-device digest (only when a mesh rate was actually
@@ -134,9 +133,8 @@ BENCH_SIM_FABRIC_MP_SHARDS (worker count; default min(cores, light//64))
 BENCH_SIM_FABRIC_MP_MIN_SPEEDUP (the >= 1.5x floor, enforced only
 where the parent and every worker get their own core),
 JAX_COMPILATION_CACHE_DIR (moves the compile cache out of the
-checkout's .cache/ — utils/accel.py), plus the kernel
-overrides SPACEMESH_ROMIX / SPACEMESH_ROMIX_CHUNK /
-SPACEMESH_ROMIX_AUTOTUNE / SPACEMESH_MESH (docs/ROMIX_KERNEL.md).
+checkout's .cache/ — utils/accel.py), plus SPACEMESH_MESH
+(docs/ROMIX_KERNEL.md).
 """
 
 import hashlib
@@ -186,43 +184,38 @@ def cpu_labels_per_sec(commitment: bytes, n: int, count: int) -> float:
 
 
 def measure_mesh(n: int, batch: int, reps: int) -> dict:
-    """Measure the autotuned multi-device label path for one shape.
+    """Measure the multi-device label path for one shape.
 
-    Runs the full decide (mesh dimension included — this races and
-    persists on a cold host), shards the same (commitment, indices)
-    batch the single-device headline used over the winning device count,
-    and returns a JSON-able doc carrying the sha256 ``digest`` of the
-    sharded labels — the caller compares it against the single-device
-    digest before reporting any rate. ``devices`` is 1 when the tuner
-    honestly concluded single-device wins on this host."""
+    Shards the same (commitment, indices) batch the single-device
+    headline used over the mesh the rule gives (parallel/mesh.py
+    auto_mesh), and returns a JSON-able doc carrying the sha256
+    ``digest`` of the sharded labels — the caller compares it against
+    the single-device digest before reporting any rate. ``devices`` is
+    1 when the rule keeps the batch on one device."""
     import jax
     import numpy as np
 
-    from spacemesh_tpu.ops import autotune, scrypt
-
-    decision = autotune.decide(n, batch, max_devices=None)
-    doc = {"devices": decision.devices, "impl": decision.impl,
-           "chunk": decision.chunk, "tuned": decision.source,
-           "devices_visible": jax.device_count()}
-    if decision.devices <= 1:
-        return doc
+    from spacemesh_tpu.ops import scrypt
     from spacemesh_tpu.parallel import mesh as pmesh
 
-    mesh = pmesh.data_mesh(jax.devices()[:decision.devices])
+    mesh = pmesh.auto_mesh(batch)
+    doc = {"devices": mesh.size if mesh else 1, "impl": "xla",
+           "chunk": None, "tuned": "rule",
+           "devices_visible": jax.device_count()}
+    if mesh is None:
+        return doc
     commitment = hashlib.sha256(b"bench-commitment").digest()
     cw = scrypt.commitment_to_words(commitment)
     idx = np.arange(batch, dtype=np.uint64)
     lo, hi = scrypt.split_indices(idx)
     t0 = time.perf_counter()
-    words = pmesh.scrypt_labels_sharded(mesh, cw, lo, hi, n=n,
-                                        impl=decision.impl)
+    words = pmesh.scrypt_labels_sharded(mesh, cw, lo, hi, n=n)
     words.block_until_ready()
     doc["compile_s"] = round(time.perf_counter() - t0, 2)
     doc["digest"] = hashlib.sha256(
         scrypt.labels_to_bytes(np.asarray(words))).hexdigest()
     t0 = time.perf_counter()
-    outs = [pmesh.scrypt_labels_sharded(mesh, cw, lo, hi, n=n,
-                                        impl=decision.impl)
+    outs = [pmesh.scrypt_labels_sharded(mesh, cw, lo, hi, n=n)
             for _ in range(reps)]
     jax.block_until_ready(outs)
     doc["labels_per_sec"] = round(reps * batch / (time.perf_counter() - t0),
@@ -471,10 +464,11 @@ def multi_tenant_bench() -> None:
     # how the packer's dispatch actually routed at the pack bucket: the
     # same tuned routing runtime/scheduler.py _dispatch_pack consults —
     # 1 means the tuner honestly kept single-device on this host
-    from spacemesh_tpu.ops import autotune, scrypt
+    from spacemesh_tpu.ops import scrypt
+    from spacemesh_tpu.parallel import mesh as pmesh
 
-    devs, _d = autotune.resolve_auto_mesh(n, scrypt.shape_bucket(pack))
-    pack_devices = len(devs) if devs is not None else 1
+    pack_mesh = pmesh.auto_mesh(scrypt.shape_bucket(pack))
+    pack_devices = pack_mesh.size if pack_mesh is not None else 1
     log(f"multi-tenant: sequential {best_seq * 1e3:.0f}ms "
         f"({seq_rate:,.0f} labels/s), scheduled {best_mt * 1e3:.0f}ms "
         f"({mt_rate:,.0f} labels/s, {mt_rate / seq_rate:.2f}x, "
@@ -1047,7 +1041,6 @@ def sim_fabric_bench() -> None:
 
     def run_one(fabric: str, tag: str) -> dict | None:
         env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   SPACEMESH_ROMIX_AUTOTUNE="off",
                    SPACEMESH_SIM_FABRIC=fabric)
         try:
             r = subprocess.run(
@@ -1153,7 +1146,6 @@ def sim_fabric_mp_bench() -> None:
 
     def run_one(w: int, tag: str) -> dict | None:
         env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   SPACEMESH_ROMIX_AUTOTUNE="off",
                    SPACEMESH_SIM_FABRIC="",
                    SPACEMESH_SIM_SHARDS=str(w))
         try:
@@ -1325,27 +1317,16 @@ def main() -> None:
     if best_rate == 0.0:
         raise SystemExit(f"all batch sizes failed: {failed_batches}")
 
-    # the kernel choice (xla / xla-rows / pallas, lane chunk) was raced
-    # and persisted by ops/autotune.py inside the first measure() call;
-    # a second bench run on this host reuses the persisted winner with
-    # no re-race (docs/ROMIX_KERNEL.md)
-    from spacemesh_tpu.ops import autotune
-
-    decision = autotune.decide(n, best_batch)
-    log(f"romix kernel: impl={decision.impl} chunk={decision.chunk} "
-        f"(source={decision.source})")
-
-    # kernel-only throughput: the ROMix stage alone on the autotune
-    # calibration workload — isolates the memory-hard core from the
-    # PBKDF2 envelope + host dispatch that the headline number includes
-    x = jnp.asarray(autotune.calibration_block(best_batch))
-    interpret = decision.impl == "pallas" and accel.pallas_interpret()
+    # kernel-only throughput: the ROMix stage alone on a fixed random
+    # block — isolates the memory-hard core from the PBKDF2 envelope +
+    # host dispatch that the headline number includes
+    x = jnp.asarray(np.random.RandomState(7).randint(
+        0, 2**32, size=(32, best_batch), dtype=np.uint64).astype(np.uint32))
 
     def romix_only():
-        return scrypt.romix_tuned(x, n=n, impl=decision.impl,
-                                  chunk=decision.chunk, interpret=interpret)
+        return scrypt._stage_romix_xla(x, n=n)
 
-    romix_only().block_until_ready()  # compile (shared with the race)
+    romix_only().block_until_ready()  # compile
     t0 = time.perf_counter()
     jax.block_until_ready([romix_only() for _ in range(reps)])
     kernel_rate = reps * best_batch / (time.perf_counter() - t0)
@@ -1389,9 +1370,8 @@ def main() -> None:
         "value": round(best_rate, 1),
         "unit": "labels/s",
         "vs_baseline": round(best_rate / cpu_rate, 2),
-        "impl": decision.impl,
-        "chunk": decision.chunk,
-        "tuned": decision.source,
+        "impl": "xla",
+        "chunk": None,
         "fused": True,  # expand->romix->finish as one jitted program
         "failed_batches": failed_batches,
     })
@@ -1399,8 +1379,8 @@ def main() -> None:
         "metric": "post_init_kernel_labels_per_sec",
         "value": round(kernel_rate, 1),
         "unit": "labels/s",
-        "impl": decision.impl,
-        "chunk": decision.chunk,
+        "impl": "xla",
+        "chunk": None,
         "batch": best_batch,
     })
     if mesh_doc is not None and mesh_doc.get("labels_per_sec"):
@@ -1424,7 +1404,7 @@ def main() -> None:
             #                         exits non-zero before this line
         })
     elif mesh_doc is not None:
-        log(f"mesh: autotuner kept single-device "
+        log(f"mesh: the mesh rule kept single-device "
             f"(devices={mesh_doc.get('devices')}); no mesh headline")
 
     # compile cost of the winning shape, reported separately: near-zero on

@@ -155,15 +155,13 @@ def _scrypt_ref(commitment: bytes, index: int, n: int) -> bytes:
                           n=n, r=1, p=1, dklen=16)
 
 
-def _decision_doc(n: int, batch: int) -> dict:
-    """The kernel decision the mesh-aware entry points resolve for this
-    shape (memoized: the SAME object initialize()/verify_many got)."""
-    from spacemesh_tpu.ops import autotune
+def _decision_doc(batch: int) -> dict:
+    """Where a batch of this width runs: the rule initialize() and
+    verify_many() asked (parallel/mesh.py auto_mesh)."""
+    from spacemesh_tpu.parallel import mesh as pmesh
 
-    devs, d = autotune.resolve_auto_mesh(n, batch)
-    return {"impl": d.impl, "chunk": d.chunk,
-            "devices": len(devs) if devs else 1, "source": d.source,
-            "batch": batch}
+    mesh = pmesh.auto_mesh(batch)
+    return {"devices": mesh.size if mesh else 1, "batch": batch}
 
 
 # --- phases ------------------------------------------------------------
@@ -190,8 +188,7 @@ def run_init(dep: Deployment, data_dir: Path, node_id: bytes,
         labels=total, labels_per_s=round(res.labels_per_s, 1),
         fraction_of_4su=total / (dep.num_units * MAINNET_UNIT_LABELS),
         vrf_nonce=res.vrf_nonce, stages=res.stats.as_dict(),
-        decision=_decision_doc(dep.scrypt_n,
-                               scrypt.shape_bucket(min(dep.init_batch,
+        decision=_decision_doc(scrypt.shape_bucket(min(dep.init_batch,
                                                        total))),
         devices_used=devices_used)
     check(doc["decision"]["devices"] == devices_used,
@@ -357,8 +354,7 @@ def run_verify(dep: Deployment, items, swapped: int, params, doc: dict):
           f"verify_many at K3={dep.k3_synced} -> {synced}, want {want}")
     lanes = 2 * params.k2  # the third item fails its pow witness on host
     doc.update(full=full, synced=synced,
-               decision=_decision_doc(dep.scrypt_n,
-                                      scrypt.shape_bucket(lanes)))
+               decision=_decision_doc(scrypt.shape_bucket(lanes)))
     return full
 
 

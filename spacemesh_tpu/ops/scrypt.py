@@ -22,27 +22,20 @@ Kernel structure (docs/ROMIX_KERNEL.md):
   fewer, 4x wider XLA ops than the scalar-word unrolling, which is what
   the op-dispatch-bound XLA:CPU backend needs (measured 6.4x on the
   ROMix stage; the rowround reuses the same dataflow after a lane roll).
-* ROMix has two interchangeable, bit-identical V layouts: word-major
-  (N, 32, B) — dense u32 tiles on TPU, one fused gather — and
-  contiguous-row (N*B, 32) — one lane's row is 128 contiguous bytes, the
-  layout the Pallas kernel (ops/romix_pallas.py) uses for its DMAs.
-* The batch can be processed in sequential lane CHUNKS (`lax.map`) so the
-  V working set (N * 128 bytes per lane) fits a cache/VMEM budget.
+* ROMix keeps V word-major, (N, 32, B): dense u32 tiles on the TPU, the
+  data-dependent read one fused per-lane gather, the WHOLE batch in one
+  pass (lane chunks of 256 and 1,024 ran 29% and 13% slower than the
+  whole batch at 8,192 lanes on a v5e: ROADMAP S3).
 * The whole label pipeline — PBKDF2 expand, ROMix, PBKDF2 finish, and
   optionally the VRF min-scan — compiles as ONE jitted program with a
   donated scan carry, so HMAC block state never round-trips through HBM
-  between stages. (The historical three-program split guarded against an
-  XLA:CPU simplifier loop that the rolled SHA-256 compression loops in
-  ops/sha256.py already avoid; the fused pipeline is re-verified against
-  hashlib in tests/test_scrypt.py and tests/test_romix_autotune.py.)
+  between stages; it is verified against hashlib in tests/test_scrypt.py
+  and tests/test_romix.py.
 
-Which (implementation, chunk) wins is decided per (platform, N, batch) by
-ops/autotune.py — raced once on a calibration workload, persisted next to
-the XLA compile cache, overridable via SPACEMESH_ROMIX /
-SPACEMESH_ROMIX_CHUNK. Every entry point (post/initializer.py,
-post/prover.py, parallel/mesh.py, bench.py, tools/profiler.py) goes
-through `scrypt_labels_jit` / `scrypt_labels_with_min` and therefore
-picks up the tuned kernel with zero configuration.
+There is one kernel and nothing selects it: every entry point
+(post/initializer.py, post/verifier.py, parallel/mesh.py) goes through
+`scrypt_labels_jit` / `scrypt_labels_with_min`. Where a batch runs (one
+device or a lane-sharded mesh) is parallel/mesh.py's `auto_mesh`.
 
 TPU layout note: the batch is the MINOR dimension everywhere — block state
 is (32, B) — so u32 tiles are fully dense ((8,128) tiling pads a trailing
@@ -64,7 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..utils import accel, sanitize, tracing
+from ..utils import sanitize, tracing
 from .sha256 import byteswap32, hmac_midstates, sha256_compress
 
 LABEL_BYTES = 16  # reference: 16-byte labels, 2^32 per 64 GiB unit
@@ -176,76 +169,6 @@ def romix_r1(x, n: int, *, mix_phase: bool = True):
         return lax.fori_loop(0, n, mix, x)
 
 
-def romix_r1_rows(x, n: int, *, mix_phase: bool = True):
-    """ROMix with the contiguous-row V layout: (n*B, 32), one lane's row
-    is 128 contiguous bytes (the layout ops/romix_pallas.py DMAs around).
-
-    Bit-identical to :func:`romix_r1`; trades the word-major gather's
-    read amplification (32 strided words per lane) for one contiguous
-    row read plus a (B, 32) transpose per iteration. Raced against the
-    other variants by ops/autotune.py.
-    """
-    b = x.shape[1]
-    v0 = jnp.zeros((n * b, 32), dtype=jnp.uint32)
-
-    def fill(i, carry):
-        v, xx = carry
-        v = lax.dynamic_update_slice_in_dim(v, xx.T, i * b, axis=0)
-        return v, blockmix_r1(xx)
-
-    with jax.named_scope("romix_fill"):
-        v, x = lax.fori_loop(0, n, fill, (v0, x))
-    if not mix_phase:
-        return x
-    lanes = jnp.arange(b, dtype=jnp.uint32)
-
-    def mix(_, xx):
-        j = xx[16] % jnp.uint32(n)
-        rows = (j * jnp.uint32(b) + lanes).astype(jnp.int32)
-        vj = jnp.take(v, rows, axis=0)  # (B, 32): contiguous per lane
-        return blockmix_r1(xx ^ vj.T)
-
-    with jax.named_scope("romix_mix"):
-        return lax.fori_loop(0, n, mix, x)
-
-
-def _romix_chunked(fn, x, n: int, chunk: int | None, **kw):
-    """Run ``fn`` over sequential lane chunks (``lax.map``) so only one
-    chunk's V (n * 128 * chunk bytes) is live at a time. Lanes are padded
-    to a chunk multiple and trimmed — pad lanes run wasted ROMix work, at
-    most chunk-1 of them per call."""
-    b = x.shape[1]
-    if not chunk or chunk >= b:
-        return fn(x, n, **kw)
-    pad = -b % chunk
-    if pad:
-        x = jnp.concatenate([x, jnp.zeros((32, pad), jnp.uint32)], axis=1)
-    xc = jnp.moveaxis(x.reshape(32, -1, chunk), 1, 0)
-    out = lax.map(lambda c: fn(c, n, **kw), xc)
-    out = jnp.moveaxis(out, 0, 1).reshape(32, -1)
-    return out[:, :b] if pad else out
-
-
-def _romix_dispatch(blk, *, n: int, impl: str, chunk: int | None,
-                    interpret: bool, mix_phase: bool = True):
-    if impl == "pallas":
-        from .romix_pallas import romix_pallas_padded
-
-        # the Pallas kernel already tiles lanes (per-tile V scratch), so
-        # the outer chunk is meaningless there
-        return romix_pallas_padded(blk, n=n, interpret=interpret,
-                                   mix_phase=mix_phase)
-    fn = romix_r1_rows if impl == "xla-rows" else romix_r1
-    return _romix_chunked(fn, blk, n, chunk, mix_phase=mix_phase)
-
-
-romix_tuned = jax.jit(
-    _romix_dispatch,
-    static_argnames=("n", "impl", "chunk", "interpret", "mix_phase"))
-"""Jitted ROMix with an explicit (impl, chunk) choice — the entry the
-autotune race and the profiler's --romix stage view share."""
-
-
 def _hmac_finish(outer_mid, inner_digest):
     """Outer HMAC compression over a 32-byte inner digest batch (8, B)."""
     b = inner_digest.shape[1]
@@ -308,9 +231,9 @@ def _expand(commitment_words, idx_lo, idx_hi):
                                                idx_lo, idx_hi)
 
 
-# standalone per-stage jits: kept for the profiler's stage-timing view
-# and for any caller that wants a single stage; production labeling goes
-# through the fused single-program pipelines below
+# standalone per-stage jits for the profiler's stage-timing view
+# (tools/profiler.py --romix); labeling goes through the fused
+# single-program pipelines below
 _stage_expand = jax.jit(_expand)
 
 _stage_romix_xla = jax.jit(romix_r1, static_argnames=("n", "mix_phase"))
@@ -321,55 +244,18 @@ def _stage_finish(inner_mid, outer_mid, blk):
     return _pbkdf2_second(inner_mid, outer_mid, blk)[:4]
 
 
-# --- tuned dispatch -----------------------------------------------------
-
-
-def _tunable(*arrays) -> bool:
-    """Autotuned chunking/impl selection only applies when the inputs are
-    concrete and single-device: under a tracer (parallel/mesh.py jits
-    around these wrappers) or a multi-device sharding, the lane-chunk
-    reshape would fight GSPMD's batch partitioning, so those callers get
-    the plain XLA path unless the env overrides say otherwise."""
+def _pads_eagerly(*arrays) -> bool:
+    """Whether the eager bucket pad (:func:`_bucket_lanes`) applies: only
+    to concrete, single-device inputs. Under a tracer (parallel/mesh.py
+    jits around these wrappers) there is nothing to pad eagerly, and a
+    multi-device batch was bucketed on the host by its caller."""
     for a in arrays:
         if isinstance(a, jax.core.Tracer):
             return False
         s = getattr(a, "sharding", None)
-        if s is not None:
-            try:
-                if len(s.device_set) > 1:
-                    return False
-            except Exception:  # noqa: BLE001 — exotic array types
-                pass
+        if s is not None and len(s.device_set) > 1:
+            return False
     return True
-
-
-def _plan(n: int, batch: int, *arrays, impl: str | None = None,
-          chunk: int | None = None):
-    """-> (autotune.Decision, interpret flag) for one call.
-
-    ``impl``/``chunk`` are caller overrides (the mesh entry points in
-    parallel/mesh.py pass the raced mesh winner's layout through here);
-    they skip the autotune lookup.
-
-    There is no fallback between impls: whichever kernel the decision
-    names either runs or raises (a Pallas kernel is only ever selected
-    by an explicit SPACEMESH_ROMIX=pallas — ops/autotune.py keeps it out
-    of every raced default set)."""
-    from . import autotune
-
-    platform = jax.default_backend()
-    if impl is not None:
-        if chunk is not None and chunk >= batch:
-            chunk = None
-        d = autotune.Decision(impl, chunk, "caller")
-    elif not _tunable(*arrays):
-        impl_env, chunk_env, chunk_set, _ = autotune.read_env()
-        d = autotune.Decision(impl_env or "xla",
-                              chunk_env if chunk_set else None, "untuned")
-    else:
-        d = autotune.decide(n, batch, platform=platform)
-    # non-pallas impls keep interpret=False in their static jit key
-    return d, d.impl == "pallas" and accel.pallas_interpret()
 
 
 def pad_lanes(a: np.ndarray, pad: int) -> np.ndarray:
@@ -420,77 +306,53 @@ def compiled_shape_count() -> int:
     return _labels_fused._cache_size() + _labels_min_fused._cache_size()
 
 
-def _stage_romix(blk, *, n: int):
-    """ROMix stage dispatch under the autotuned (impl, chunk) decision.
-
-    Kept for callers that run the stages separately; the fused pipelines
-    below inline the same dispatch into one program."""
-    d, interpret = _plan(n, blk.shape[1], blk)
-    return romix_tuned(blk, n=n, impl=d.impl, chunk=d.chunk,
-                       interpret=interpret)
-
-
 # --- fused single-program pipelines -------------------------------------
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("n", "impl", "chunk", "interpret"))
-def _labels_fused(commitment_words, idx_lo, idx_hi, *, n: int, impl: str,
-                  chunk: int | None, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("n",))
+def _labels_fused(commitment_words, idx_lo, idx_hi, *, n: int):
     """expand -> ROMix -> finish as ONE XLA program: PBKDF2/HMAC block
     state stays on device between stages instead of round-tripping
     through HBM as three executables' inputs/outputs."""
     inner_mid, outer_mid, blk = _expand(commitment_words, idx_lo, idx_hi)
-    blk = _romix_dispatch(blk, n=n, impl=impl, chunk=chunk,
-                          interpret=interpret)
+    blk = romix_r1(blk, n)
     return _pbkdf2_second(inner_mid, outer_mid, blk)[:4]
 
 
-def _labels_enqueue(commitment_words, idx_lo, idx_hi, *, n: int,
-                    impl: str | None = None, chunk: int | None = None):
+def _labels_enqueue(commitment_words, idx_lo, idx_hi, *, n: int):
     """:func:`scrypt_labels_jit` that also says when the label program
     was enqueued and at what width: ``(words, t0_ns, batch)``. The
     caller that fetches ``words`` closes the ``device.flight`` span
     from ``t0_ns`` (:func:`_run`)."""
     valid = None
-    if _tunable(commitment_words, idx_lo, idx_hi):
+    if _pads_eagerly(commitment_words, idx_lo, idx_hi):
         commitment_words, idx_lo, idx_hi, valid = _bucket_lanes(
             commitment_words, idx_lo, idx_hi)
     batch = int(idx_lo.shape[0])
     sanitize.on_jit_shape("labels_fused", batch)
-    d, interpret = _plan(n, batch, commitment_words, idx_lo, idx_hi,
-                         impl=impl, chunk=chunk)
     t0 = time.perf_counter_ns()
     # the span covers the ENQUEUE (trace+compile on a cache miss, else
     # async dispatch) — device time shows up in the XLA trace, which the
     # SPACEMESH_TRACE_JAX bridge lines these spans up against
     with tracing.span("romix.dispatch",
-                      {"impl": d.impl, "chunk": d.chunk, "n": n,
-                       "batch": batch,
+                      {"n": n, "batch": batch,
                        "valid": batch if valid is None else valid}
                       if tracing.is_enabled() else None):
-        words = _labels_fused(commitment_words, idx_lo, idx_hi, n=n,
-                              impl=d.impl, chunk=d.chunk,
-                              interpret=interpret)
+        words = _labels_fused(commitment_words, idx_lo, idx_hi, n=n)
     if valid is not None and valid != batch:
         words = words[:, :valid]
     return words, t0, batch
 
 
-def scrypt_labels_jit(commitment_words, idx_lo, idx_hi, *, n: int,
-                      impl: str | None = None, chunk: int | None = None):
+def scrypt_labels_jit(commitment_words, idx_lo, idx_hi, *, n: int):
     """Batch of labels. ``idx_lo/idx_hi``: (B,) u32 halves of label indices.
 
-    Returns (4, B) u32 BE words = B 16-byte labels (batch minor). One
-    fused program under the autotuned kernel decision (module
-    docstring), or under an explicit caller ``impl``/``chunk`` (the mesh
-    entry points pass the raced mesh winner through). Ragged batches are
-    padded to their power-of-two shape bucket and trimmed, so they reuse
-    the bucket's executable instead of compiling their own
-    (:func:`shape_bucket`; sharded/traced inputs skip the pad — mesh
-    callers pre-bucket on host)."""
-    return _labels_enqueue(commitment_words, idx_lo, idx_hi, n=n,
-                           impl=impl, chunk=chunk)[0]
+    Returns (4, B) u32 BE words = B 16-byte labels (batch minor), from
+    one fused program. Ragged batches are padded to their power-of-two
+    shape bucket and trimmed, so they reuse the bucket's executable
+    instead of compiling their own (:func:`shape_bucket`; sharded/traced
+    inputs skip the pad — mesh callers pre-bucket on host)."""
+    return _labels_enqueue(commitment_words, idx_lo, idx_hi, n=n)[0]
 
 
 # --- on-device VRF-nonce scan ----------------------------------------------
@@ -581,49 +443,39 @@ def _stage_minscan(words, idx_lo, idx_hi, carry):
     return _minscan(words, idx_lo, idx_hi, carry)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("n", "impl", "chunk", "interpret"),
-                   donate_argnums=(3,))
-def _labels_min_fused(commitment_words, idx_lo, idx_hi, carry, *, n: int,
-                      impl: str, chunk: int | None, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("n",), donate_argnums=(3,))
+def _labels_min_fused(commitment_words, idx_lo, idx_hi, carry, *, n: int):
     inner_mid, outer_mid, blk = _expand(commitment_words, idx_lo, idx_hi)
-    blk = _romix_dispatch(blk, n=n, impl=impl, chunk=chunk,
-                          interpret=interpret)
+    blk = romix_r1(blk, n)
     words = _pbkdf2_second(inner_mid, outer_mid, blk)[:4]
     new_carry, snapshot = _minscan(words, idx_lo, idx_hi, carry)
     return words, new_carry, snapshot
 
 
 def scrypt_labels_with_min(commitment_words, idx_lo, idx_hi, carry, *,
-                           n: int, impl: str | None = None,
-                           chunk: int | None = None):
+                           n: int):
     """Label batch + running VRF minimum, fully device-side.
 
     One host call enqueues ONE fused XLA program (PBKDF2 expand, ROMix,
-    finish, min-scan) under the autotuned kernel decision (or a caller
-    ``impl``/``chunk`` — see :func:`scrypt_labels_jit`); no data returns
-    to host. Returns ``(words, new_carry, snapshot)``; ``carry`` is
-    donated. Ragged batches pad to their shape bucket with the last
-    index repeated — the min-scan cannot tell the pad lanes from the
-    real last lane (same value, first-occurrence lane wins), so the
-    carry is exact and only ``words`` is trimmed.
+    finish, min-scan); no data returns to host. Returns ``(words,
+    new_carry, snapshot)``; ``carry`` is donated. Ragged batches pad to
+    their shape bucket with the last index repeated — the min-scan
+    cannot tell the pad lanes from the real last lane (same value,
+    first-occurrence lane wins), so the carry is exact and only
+    ``words`` is trimmed.
     """
     valid = None
-    if _tunable(commitment_words, idx_lo, idx_hi, carry):
+    if _pads_eagerly(commitment_words, idx_lo, idx_hi, carry):
         commitment_words, idx_lo, idx_hi, valid = _bucket_lanes(
             commitment_words, idx_lo, idx_hi)
     batch = int(idx_lo.shape[0])
     sanitize.on_jit_shape("labels_min_fused", batch)
-    d, interpret = _plan(n, batch, commitment_words, idx_lo, idx_hi, carry,
-                         impl=impl, chunk=chunk)
     with tracing.span("romix.dispatch",
-                      {"impl": d.impl, "chunk": d.chunk, "n": n,
-                       "batch": batch, "minscan": True,
+                      {"n": n, "batch": batch, "minscan": True,
                        "valid": batch if valid is None else valid}
                       if tracing.is_enabled() else None):
         words, new_carry, snap = _labels_min_fused(
-            commitment_words, idx_lo, idx_hi, carry, n=n, impl=d.impl,
-            chunk=d.chunk, interpret=interpret)
+            commitment_words, idx_lo, idx_hi, carry, n=n)
     if valid is not None and valid != batch:
         words = words[:, :valid]
     return words, new_carry, snap

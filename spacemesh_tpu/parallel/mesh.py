@@ -15,11 +15,17 @@ process and this module's entry points consume the persistent catalog
 Mainnet-scale example (BASELINE config 5): 16 smeshers x 4 SU on a
 v5e-8 = batch lanes striped across 8 chips; each chip labels its stripe
 and the host shards disk writes per smesher.
+
+WHERE a batch runs is one rule, :func:`auto_mesh`: every caller that
+routes a batch (post/initializer.py, post/prover.py, post/verifier.py,
+runtime/scheduler.py, runtime/workloads.py, chip_smoke.py) asks it and
+nothing else.
 """
 
 from __future__ import annotations
 
 import functools
+import os
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +36,8 @@ from ..ops.sha256 import byteswap32
 from . import topology
 
 DATA_AXIS = topology.DATA_AXIS
+
+ENV_MESH = "SPACEMESH_MESH"
 
 
 def data_mesh(devices=None) -> Mesh:
@@ -43,6 +51,39 @@ def data_mesh(devices=None) -> Mesh:
     return topology.get().layouts_for_devices(list(devices)).mesh
 
 
+def auto_mesh(batch: int) -> Mesh | None:
+    """The mesh a batch of ``batch`` lanes runs on, or None for one device.
+
+    On an accelerator: every visible device when there is more than
+    one. On the CPU: a single device. ``SPACEMESH_MESH`` forces either
+    way: ``0``/``off`` never shards, ``1``/``on`` takes every visible
+    device, an integer >= 2 that many (clipped to the visible devices),
+    unset/``auto`` leaves the rule above; anything else raises. None as
+    well when the batch does not divide by the device count: the lane
+    axis shards evenly or not at all."""
+    raw = (os.environ.get(ENV_MESH) or "").strip().lower()
+    devs = jax.devices()
+    if raw in ("", "auto"):
+        want = 1 if jax.default_backend() == "cpu" else len(devs)
+    elif raw in ("0", "off", "none", "false"):
+        want = 1
+    elif raw in ("1", "on"):
+        want = len(devs)
+    else:
+        try:
+            want = int(raw)
+        except ValueError:
+            raise ValueError(f"{ENV_MESH}={raw!r}: expected off/on/auto "
+                             "or a device count") from None
+        if want < 1:
+            raise ValueError(
+                f"{ENV_MESH}={raw!r}: device count must be >= 1")
+    want = min(want, len(devs))
+    if want <= 1 or batch % want:
+        return None
+    return data_mesh(devs[:want])
+
+
 def _layouts(mesh: Mesh) -> topology.MeshLayouts:
     return topology.get().layouts_for(mesh)
 
@@ -53,9 +94,8 @@ def _batch_sharding(mesh: Mesh) -> NamedSharding:
 
 def lane_sharding(mesh: Mesh) -> NamedSharding:
     """Sharding for word-major arrays: (words, B) — shard the minor/lane
-    axis (the autotuner's mesh race places its calibration block with
-    this, the same placement the sharded label entry points use). Served
-    from the topology catalog, never constructed per call."""
+    axis, the placement the sharded label entry points use. Served from
+    the topology catalog, never constructed per call."""
     return _layouts(mesh).lane
 
 
@@ -72,7 +112,7 @@ def replicate(mesh: Mesh, value) -> jax.Array:
 
 
 def labels_with_min_sharded(mesh: Mesh, commitment_words, idx_lo, idx_hi,
-                            carry, *, n: int, impl: str | None = None):
+                            carry, *, n: int):
     """Sharded label batch chained to the on-device VRF min-scan.
 
     Lane axis sharded over the mesh; the (6,) running-minimum carry is
@@ -80,14 +120,6 @@ def labels_with_min_sharded(mesh: Mesh, commitment_words, idx_lo, idx_hi,
     all-reduces under GSPMD. Returns ``(words, new_carry, snapshot)`` like
     scrypt.scrypt_labels_with_min, with ``words`` lane-sharded so the host
     can fetch and stripe each device's shard to disk independently.
-
-    Kernel choice: ``impl`` carries the autotuned mesh winner's layout
-    (ops/autotune.py races both mesh shapes per device count); when None,
-    multi-device shardings pin the ROMix dispatch to the plain word-major
-    XLA kernel (a sequential lane-chunk would fight GSPMD's batch
-    partitioning — ops/scrypt.py ``_tunable``). The SPACEMESH_ROMIX /
-    SPACEMESH_ROMIX_CHUNK overrides still win for operators who have
-    measured their mesh (docs/ROMIX_KERNEL.md).
     """
     lay = _layouts(mesh)
     idx_lo = lay.put_batch(idx_lo)
@@ -96,17 +128,15 @@ def labels_with_min_sharded(mesh: Mesh, commitment_words, idx_lo, idx_hi,
     if cw.ndim == 2:
         cw = lay.put_lane(cw)
     return scrypt.scrypt_labels_with_min(cw, idx_lo, idx_hi,
-                                         lay.replicate(carry), n=n,
-                                         impl=impl)
+                                         lay.replicate(carry), n=n)
 
 
 def scrypt_labels_sharded(mesh: Mesh, commitment_words, idx_lo, idx_hi,
-                          *, n: int, impl: str | None = None):
+                          *, n: int):
     """Label batch sharded over the mesh. Batch size must divide evenly.
 
     ``commitment_words``: (8,) shared or (8, B) per-lane (multi-identity).
-    Returns (4, B) u32 BE words with the lane axis sharded. ``impl`` as
-    in :func:`labels_with_min_sharded`.
+    Returns (4, B) u32 BE words with the lane axis sharded.
     """
     lay = _layouts(mesh)
     idx_lo = lay.put_batch(idx_lo)
@@ -114,7 +144,7 @@ def scrypt_labels_sharded(mesh: Mesh, commitment_words, idx_lo, idx_hi,
     cw = jnp.asarray(commitment_words)
     if cw.ndim == 2:
         cw = lay.put_lane(cw)
-    return scrypt.scrypt_labels_jit(cw, idx_lo, idx_hi, n=n, impl=impl)
+    return scrypt.scrypt_labels_jit(cw, idx_lo, idx_hi, n=n)
 
 
 def prove_batch_shardings(mesh: Mesh) -> list[NamedSharding]:

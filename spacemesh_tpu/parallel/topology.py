@@ -12,8 +12,8 @@ Before this module, every sharded entry point re-derived
 replicated VRF carry per batch, evicting a donated buffer that was
 already resident). This module is the one place sharding objects are
 constructed; everything else — parallel/mesh.py's entry points, the
-autotuner's mesh race, the multi-tenant packer, the prover's window
-scans, the verify farm's batch recompute — consumes the catalog.
+multi-tenant packer, the prover's window scans, the verify farm's
+batch recompute — consumes the catalog.
 spacecheck rule SC010 holds the line: ``Mesh(`` / ``NamedSharding(``
 construction inside functions of the hot-path modules is a finding.
 
@@ -32,10 +32,11 @@ The topology is built lazily on first use from the devices visible at
 that moment — entry points that want the virtual host devices call
 ``accel.ensure_host_devices()`` BEFORE first backend use, exactly as
 they already do (tests' conftest, tools/warmcache.py, bench.py probes).
-``SPACEMESH_MESH`` routing stays where it was (ops/autotune.py: the
-grammar is unchanged and decides HOW MANY devices a dispatch uses); the
-topology only answers WHICH mesh/layout objects serve that count, and
-guarantees each count maps to one Mesh object per process.
+HOW MANY devices a dispatch uses is parallel/mesh.py's ``auto_mesh``
+(every visible accelerator, one CPU device, ``SPACEMESH_MESH`` forces
+either way); the topology only answers WHICH mesh/layout objects serve
+that count, and guarantees each count maps to one Mesh object per
+process.
 """
 
 from __future__ import annotations
@@ -70,9 +71,6 @@ class MeshLayouts:
         # word-major (words, B) arrays: shard the minor/lane axis
         # spacecheck: ok=SC010 persistent catalog, built once per count
         self.lane = NamedSharding(self.mesh, P(None, DATA_AXIS))
-        # row-major (B, words) arrays (the contiguous-row ROMix layout)
-        # spacecheck: ok=SC010 persistent catalog, built once per count
-        self.row = NamedSharding(self.mesh, P(DATA_AXIS, None))
         # spacecheck: ok=SC010 persistent catalog, built once per count
         self.replicated = NamedSharding(self.mesh, P())
 

@@ -48,7 +48,6 @@ import time
 from pathlib import Path
 from typing import Callable
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -162,32 +161,17 @@ class Initializer:
     # -- mesh routing -------------------------------------------------------
 
     def _resolve_plan(self, batch_hint: int):
-        """-> (mesh | None, autotune.Decision) for this session.
-
-        An accelerator backend shards over every visible device (four
-        chips of a v5e host: four non-empty shards per batch, the same
-        store bytes as one chip — PERF.md Bring-up). On the CPU the
-        autotuned winner decides (ops/autotune.py mesh dimension): the
-        op-dispatch-bound label kernel usually wins sharded over the
-        virtual host devices, and the race has measured by how much on
-        THIS host — zero configuration, SPACEMESH_MESH still forces
-        either way."""
-        from ..ops import autotune
-
-        n = self.meta.scrypt_n
+        """-> the mesh this session's batches run on, or None for one
+        device: the caller's ``mesh=`` when it gave one, else the one
+        rule every caller shares (parallel/mesh.py ``auto_mesh``: every
+        visible device on an accelerator, one on the CPU, SPACEMESH_MESH
+        forces either way)."""
         if self._mesh_arg is None:
-            return None, autotune.decide(n, batch_hint)
+            return None
         if self._mesh_arg != "auto":
-            mesh = self._mesh_arg if self._mesh_arg.size > 1 else None
-            return mesh, autotune.decide(n, batch_hint)
-        # ONE definition of the auto routing, shared with post/prover.py
-        # (autotune.resolve_auto_mesh: tuned winner on the CPU, every
-        # device on an accelerator, SPACEMESH_MESH forces either way)
-        devs, d = autotune.resolve_auto_mesh(n, batch_hint)
-        if devs is None:
-            return None, d
+            return self._mesh_arg if self._mesh_arg.size > 1 else None
         from ..parallel import mesh as pmesh
-        return pmesh.data_mesh(devs), d
+        return pmesh.auto_mesh(batch_hint)
 
     # -- the pipeline -------------------------------------------------------
 
@@ -201,26 +185,19 @@ class Initializer:
         stats = PipelineStats()
         cw = scrypt.commitment_to_words(commitment)
 
-        # resolve (and if needed race+persist) the kernel + mesh choice up
-        # front so the session logs what it will run with and the first
-        # dispatch doesn't absorb the calibration race silently
-        # (ops/autotune.py). The decision is taken at the BUCKETED batch —
-        # the executable shape every batch of this session (ragged tail
-        # included) actually runs at (ops/scrypt.py shape_bucket).
+        # the mesh is resolved up front, at the BUCKETED batch — the
+        # executable shape every batch of this session (ragged tail
+        # included) runs at (ops/scrypt.py shape_bucket) — so the
+        # session logs where it will run
+        mesh = None
         if total > written0:
             batch_hint = scrypt.shape_bucket(min(self.batch,
                                                  total - written0))
-            mesh, decision = self._resolve_plan(batch_hint)
-            print(f"romix kernel: impl={decision.impl} "
-                  f"chunk={decision.chunk} devices={mesh.size if mesh else 1}"
-                  f" (source={decision.source})", file=sys.stderr, flush=True)
+            mesh = self._resolve_plan(batch_hint)
+            print(f"init: batch={batch_hint} "
+                  f"devices={mesh.size if mesh else 1}",
+                  file=sys.stderr, flush=True)
             metrics.post_mesh_devices.set(mesh.size if mesh else 1)
-        else:  # nothing to do: never pay a race/compile for a no-op resume
-            from ..ops import autotune
-
-            mesh, decision = None, autotune.default_decision(
-                jax.default_backend(), self.meta.scrypt_n, self.batch)
-        self._decision = decision
 
         # resumed (or fresh) running-minimum carry for the VRF scan
         resumed = None
@@ -283,7 +260,6 @@ class Initializer:
                                {"total": total, "resume_at": written0,
                                 "batch": self.batch,
                                 "devices": mesh.size if mesh else 1,
-                                "impl": decision.impl,
                                 "tenant": self.tenant}
                                if tracing.is_enabled() else None)
         session.__enter__()
@@ -384,13 +360,8 @@ class Initializer:
             idx = np.arange(start, start + padded, dtype=np.uint64)
             idx[count:] = start + count - 1
             lo, hi = scrypt.split_indices(idx)
-            # the raced mesh winner's layout rides along; an untuned mesh
-            # (explicit mesh= arg, forced SPACEMESH_MESH with racing off)
-            # keeps the pinned plain-XLA dispatch (impl=None)
-            impl = self._decision.impl if self._decision.devices > 1 \
-                else None
             return pmesh.labels_with_min_sharded(mesh, cw, lo, hi, carry,
-                                                 n=n, impl=impl)
+                                                 n=n)
         idx = np.arange(start, start + count, dtype=np.uint64)
         lo, hi = scrypt.split_indices(idx)
         return scrypt.scrypt_labels_with_min(

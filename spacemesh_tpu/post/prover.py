@@ -229,23 +229,10 @@ class Prover:
                 raise ValueError(
                     f"batch_labels {self.batch_labels} not divisible by "
                     f"the {mesh.size}-device mesh; pick a multiple")
-        else:
-            from ..ops import autotune
-
-            # ONE definition of the auto routing, shared with
-            # post/initializer.py (autotune.resolve_auto_mesh). The race
-            # measures the label kernel, not the proving scan — but both
-            # are op-dispatch-bound embarrassingly-lane-parallel sweeps,
-            # so the tuned device count transfers.
-            devs, _ = autotune.resolve_auto_mesh(self.meta.scrypt_n,
-                                                 self.batch_labels)
-            if devs is None:
-                return None
-            from ..parallel import mesh as pmesh
-            mesh = pmesh.data_mesh(devs)
-        if mesh.size <= 1 or self.batch_labels % mesh.size:
-            return None
-        return mesh
+            return mesh if mesh.size > 1 else None
+        # the rule every caller shares (parallel/mesh.py auto_mesh)
+        from ..parallel import mesh as pmesh
+        return pmesh.auto_mesh(self.batch_labels)
 
     # -- entry points -------------------------------------------------------
 

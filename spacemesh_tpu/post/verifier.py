@@ -21,9 +21,10 @@ import time
 import jax
 import numpy as np
 
-from ..ops import autotune
 from ..ops import pow as k2pow
 from ..ops import proving, scrypt
+from ..parallel import mesh as pmesh
+from ..parallel import topology
 from ..utils import tracing
 from .prover import Proof, ProofParams
 
@@ -155,18 +156,13 @@ def _verify_many(items: list[VerifyItem], p: ProofParams, seed: bytes,
             cw8, chal_b, nonce_b, lo, hi = (
                 scrypt.pad_lanes(a, bb - b)
                 for a in (cw8, chals[:, sel], nonces[sel], lo, hi))
-            # the shared tuned mesh routing (SPACEMESH_MESH forces; CPU
-            # consults the raced winner) — the verify farm's batch
-            # recompute is a label batch like any other, so it shards
-            # like one. Placement is the only thing a mesh changes.
-            devs, d = autotune.resolve_auto_mesh(n, bb)
-            where, impl = None, None    # one device: the tuned (impl, chunk)
-            if devs is not None and len(devs) > 1 and bb % len(devs) == 0:
-                from ..parallel import topology
-
-                lay = topology.get().layouts_for_devices(devs)
+            # the verify farm's batch recompute is a label batch like any
+            # other, so it shards like one (parallel/mesh.py auto_mesh).
+            # Placement is the only thing a mesh changes.
+            mesh, where = pmesh.auto_mesh(bb), None
+            if mesh is not None:
+                lay = topology.get().layouts_for(mesh)
                 where = [lay.lane, lay.lane, lay.batch, lay.batch, lay.batch]
-                impl = d.impl           # the raced mesh winner's layout
             host = [cw8, chal_b, nonce_b, lo, hi]
             h2d = sum(a.nbytes for a in host)
         with tracing.span("romix.upload",
@@ -179,7 +175,7 @@ def _verify_many(items: list[VerifyItem], p: ProofParams, seed: bytes,
         t0 = time.perf_counter_ns()
         vals = np.asarray(proving.proving_hash_jit(
             chal_b, nonce_b, lo, hi, scrypt.words_to_le(
-                scrypt.scrypt_labels_jit(cw8, lo, hi, n=n, impl=impl))))
+                scrypt.scrypt_labels_jit(cw8, lo, hi, n=n))))
         if tr is not None:
             tracing.interval("device.flight", t0,
                              {"program": "labels_proving", "lanes": bb,

@@ -4,7 +4,7 @@ The four device pipelines — POST init (post/initializer.py), POST prove
 (post/prover.py), the verification farm (verify/farm.py) and the k2pow
 nonce search (ops/pow.py) — used to each carry a private copy of the
 same machinery: bounded in-flight dispatch, donated carry state,
-pad-and-trim ragged tails, autotune consultation, device-failure
+pad-and-trim ragged tails, mesh routing, device-failure
 fallback, per-stage spans and metrics.  ROADMAP items #1/#2 (and the
 review-fix history in ADVICE.md) argue that class of subtle code should
 exist ONCE.  This package is that once:

@@ -799,7 +799,8 @@ class TenantScheduler:
         import jax.numpy as jnp
         import numpy as np
 
-        from ..ops import autotune, scrypt
+        from ..ops import scrypt
+        from ..parallel import mesh as pmesh
 
         segments, n = pack
         lanes = sum(s.count for s in segments)
@@ -812,17 +813,15 @@ class TenantScheduler:
         metrics.runtime_pack_occupancy.observe(lanes)
         metrics.runtime_pack_tenants.observe(
             len({s.job.tenant.id for s in segments}))
-        # the tuned mesh routing every mesh-aware entry point shares
-        # (SPACEMESH_MESH forces; CPU consults the raced winner). Packs
-        # dispatch at their shape bucket either way — one executable per
-        # (n, bucket) — so the bucket is what the mesh must divide.
+        # the mesh rule every entry point shares (parallel/mesh.py
+        # auto_mesh). Packs dispatch at their shape bucket either way —
+        # one executable per (n, bucket) — so the bucket is what the
+        # mesh must divide.
         bucket = scrypt.shape_bucket(lanes)
-        devs, d = autotune.resolve_auto_mesh(n, bucket)
-        if devs is not None and len(devs) > 1 and bucket % len(devs) == 0:
-            from ..parallel import mesh as pmesh
-
-            # mesh callers pre-bucket on host (ops/scrypt.py _tunable
-            # skips padding for sharded inputs): repeat the last lane —
+        mesh = pmesh.auto_mesh(bucket)
+        if mesh is not None:
+            # mesh callers pre-bucket on host (ops/scrypt.py skips its
+            # eager pad for sharded inputs): repeat the last lane —
             # a real commitment/index, so padding lanes recompute a real
             # label and stay branch-free; _retire_pack slices only the
             # segment-addressed lanes
@@ -833,8 +832,7 @@ class TenantScheduler:
                 idx = np.concatenate(
                     [idx, np.repeat(idx[-1:], bucket - lanes)])
             lo, hi = scrypt.split_indices(idx)
-            words = pmesh.scrypt_labels_sharded(
-                pmesh.data_mesh(devs), cw, lo, hi, n=n, impl=d.impl)
+            words = pmesh.scrypt_labels_sharded(mesh, cw, lo, hi, n=n)
         else:
             lo, hi = scrypt.split_indices(idx)
             # scrypt_labels_jit pads ragged packs to their shape bucket

@@ -125,20 +125,16 @@ def _warm_init_pack(n: int, batch: int) -> dict:
     _timed(doc, "labels_fused_perlane",
            lambda: scrypt.scrypt_labels_jit(
                jnp.asarray(cw), jnp.asarray(lo), jnp.asarray(hi), n=n))
-    # when the tuned routing shards packs at this bucket, the sharded
-    # twin is a DIFFERENT executable (GSPMD-partitioned) — warm it too,
-    # or the first real pack dispatch pays the compile
-    from ..ops import autotune
+    # when packs at this bucket shard, the sharded twin is a DIFFERENT
+    # executable (GSPMD-partitioned) — warm it too, or the first real
+    # pack dispatch pays the compile
+    from ..parallel import mesh as pmesh
 
-    devs, d = autotune.resolve_auto_mesh(n, batch)
-    if devs is not None and len(devs) > 1 and batch % len(devs) == 0:
-        from ..parallel import mesh as pmesh
-
-        mesh = pmesh.data_mesh(devs)
-        _timed(doc, f"labels_fused_perlane_mesh{len(devs)}",
-               lambda: pmesh.scrypt_labels_sharded(
-                   mesh, cw, lo, hi, n=n, impl=d.impl))
-        doc["pack_devices"] = len(devs)
+    mesh = pmesh.auto_mesh(batch)
+    if mesh is not None:
+        _timed(doc, f"labels_fused_perlane_mesh{mesh.size}",
+               lambda: pmesh.scrypt_labels_sharded(mesh, cw, lo, hi, n=n))
+        doc["pack_devices"] = mesh.size
     return doc
 
 
@@ -189,13 +185,14 @@ def _warm_verify(n: int, batch: int) -> dict:
             np.full(batch, 7, np.uint32), lo, hi,
             np.zeros((4, batch), np.uint32)]
     placements = [("", None)]
-    if doc.get("pack_devices", 1) > 1:
-        from ..ops import autotune
+    from ..parallel import mesh as pmesh
+
+    mesh = pmesh.auto_mesh(batch)
+    if mesh is not None:
         from ..parallel import topology
 
-        devs, _ = autotune.resolve_auto_mesh(n, batch)
-        lay = topology.get().layouts_for_devices(devs)
-        placements.append((f"_mesh{len(devs)}", [
+        lay = topology.get().layouts_for(mesh)
+        placements.append((f"_mesh{mesh.size}", [
             lay.lane, lay.batch, lay.batch, lay.batch, lay.lane]))
     for suffix, where in placements:
         chal, nonce, lo, hi, lw = jax.device_put(host, where)

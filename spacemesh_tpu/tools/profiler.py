@@ -261,30 +261,22 @@ def romix_roofline(n: int, r: int = 1, p: int = 1,
     return out
 
 
-def romix_benchmark(n: int, batch: int, reps: int = 2,
-                    include_pallas: bool = False) -> dict:
+def romix_benchmark(n: int, batch: int, reps: int = 2) -> dict:
     """Per-stage timings of the label kernel — expand (PBKDF2 first),
-    fill (ROMix phase 1), mix (ROMix phase 2), finish (PBKDF2 second) —
-    for the tuned XLA variant and, only with --romix-pallas, the Pallas
-    DMA kernel (an explicit request: it raises where the kernel cannot
-    run), on the SAME calibration workload the autotuner races
-    (ops/autotune.py). The fill/mix split runs the kernel once with the
-    mix phase compiled out and subtracts."""
-    import jax
+    fill (ROMix phase 1), mix (ROMix phase 2), finish (PBKDF2 second).
+    The fill/mix split runs ``romix_r1`` once with the mix phase
+    compiled out and subtracts."""
     import jax.numpy as jnp
     import numpy as np
 
-    from ..ops import autotune, scrypt
-    from ..utils import accel
-
-    platform = jax.default_backend()
-    decision = autotune.decide(n, batch, platform=platform)
+    from ..ops import scrypt
 
     commitment = hashlib.sha256(b"profiler-romix").digest()
     cw = jnp.asarray(scrypt.commitment_to_words(commitment))
     lo_, hi_ = scrypt.split_indices(np.arange(batch, dtype=np.uint64))
     lo, hi = jnp.asarray(lo_), jnp.asarray(hi_)
-    x = jnp.asarray(autotune.calibration_block(batch))
+    x = jnp.asarray(np.random.RandomState(7).randint(
+        0, 2**32, size=(32, batch), dtype=np.uint64).astype(np.uint32))
 
     def best_of(fn):
         fn().block_until_ready()  # compile + warm
@@ -295,54 +287,36 @@ def romix_benchmark(n: int, batch: int, reps: int = 2,
             t = min(t, time.perf_counter() - t0)
         return t
 
-    # the PBKDF2 envelope stages are implementation-independent
     expand_s = best_of(lambda: scrypt._stage_expand(cw, lo, hi)[2])
     inner, outer, blk0 = scrypt._stage_expand(cw, lo, hi)
     finish_s = best_of(lambda: scrypt._stage_finish(inner, outer, blk0))
-
-    rows = []
-    variants = [(decision.impl if decision.impl != "pallas" else "xla",
-                 decision.chunk)]
-    if include_pallas:
-        variants.append(("pallas", None))
-    for impl, chunk in variants:
-        interpret = impl == "pallas" and accel.pallas_interpret()
-        if interpret:
-            _log("pallas timings run in INTERPRET mode (every DMA "
-                 "executes in Python) — correctness-grade, not perf")
-        kw = dict(n=n, impl=impl, chunk=chunk, interpret=interpret)
-        fill_s = best_of(functools.partial(
-            scrypt.romix_tuned, x, mix_phase=False, **kw))
-        romix_s = best_of(functools.partial(scrypt.romix_tuned, x, **kw))
-        total = expand_s + romix_s + finish_s
-        rate = round(batch / total, 1)
-        # roofline against the ROMix phase alone (the only stage that
-        # touches V): the PBKDF2 envelope would dilute the bandwidth
-        # number with compute that moves no scratch memory
-        roof = romix_roofline(n, labels_per_sec=batch / romix_s)
-        line = (f"{impl}: {roof['bytes_per_label']:,} B/label, "
-                f"{roof['salsa20_8_per_label']:,} salsa20/8 calls/label")
-        if "achieved_gbps" in roof:
-            line += f", {roof['achieved_gbps']} GB/s achieved"
-        if "utilization" in roof:
-            line += (f" = {roof['utilization'] * 100:.1f}% of "
-                     f"{roof['roofline_gbps']} GB/s roofline")
-        elif "achieved_gbps" in roof:
-            line += (" (set SPACEMESH_ROOFLINE_GBPS=<peak> for a "
-                     "utilization fraction)")
-        _log(line)
-        rows.append({
-            "impl": impl, "chunk": chunk, "interpret": interpret,
+    fill_s = best_of(functools.partial(
+        scrypt._stage_romix_xla, x, n=n, mix_phase=False))
+    romix_s = best_of(functools.partial(scrypt._stage_romix_xla, x, n=n))
+    total = expand_s + romix_s + finish_s
+    # roofline against the ROMix phase alone (the only stage that
+    # touches V): the PBKDF2 envelope would dilute the bandwidth
+    # number with compute that moves no scratch memory
+    roof = romix_roofline(n, labels_per_sec=batch / romix_s)
+    line = (f"romix: {roof['bytes_per_label']:,} B/label, "
+            f"{roof['salsa20_8_per_label']:,} salsa20/8 calls/label")
+    if "achieved_gbps" in roof:
+        line += f", {roof['achieved_gbps']} GB/s achieved"
+    if "utilization" in roof:
+        line += (f" = {roof['utilization'] * 100:.1f}% of "
+                 f"{roof['roofline_gbps']} GB/s roofline")
+    elif "achieved_gbps" in roof:
+        line += (" (set SPACEMESH_ROOFLINE_GBPS=<peak> for a "
+                 "utilization fraction)")
+    _log(line)
+    return {"scrypt_n": n, "batch": batch,
             "stages": {"expand_s": round(expand_s, 4),
                        "fill_s": round(fill_s, 4),
                        "mix_s": round(max(romix_s - fill_s, 0.0), 4),
                        "finish_s": round(finish_s, 4)},
             "romix_s": round(romix_s, 4),
-            "labels_per_sec": rate,
-            "roofline": roof,
-        })
-    return {"scrypt_n": n, "batch": batch,
-            "decision": decision.as_json(), "impls": rows}
+            "labels_per_sec": round(batch / total, 1),
+            "roofline": roof}
 
 
 def verify_benchmark(counts: list[int], reps: int = 2) -> dict:
@@ -539,15 +513,9 @@ def main(argv=None) -> int:
                     "(read/dispatch/retire) vs the legacy serial scan")
     ap.add_argument("--romix", action="store_true",
                     help="profile the label kernel per stage (expand/fill/"
-                    "mix/finish) under the autotuned decision "
-                    "(docs/ROMIX_KERNEL.md)")
-    ap.add_argument("--romix-batch", type=int, default=None,
-                    help="label lanes for --romix (default: the autotune "
-                    "calibration batch)")
-    ap.add_argument("--romix-pallas", action="store_true",
-                    help="include the Pallas kernel in --romix (off-TPU: "
-                    "interpret mode, minutes-slow, correctness-grade; on "
-                    "TPU Mosaic refuses it today and this raises)")
+                    "mix/finish) (docs/ROMIX_KERNEL.md)")
+    ap.add_argument("--romix-batch", type=int, default=512,
+                    help="label lanes for --romix")
     ap.add_argument("--prove-labels", type=int, default=16384,
                     help="store size for the --prove run")
     ap.add_argument("--prove-batch", type=int, default=2048)
@@ -560,7 +528,7 @@ def main(argv=None) -> int:
     ap.add_argument("--cpu-labels", type=int, default=16,
                     help="labels for the OpenSSL reference measurement")
     ap.add_argument("--warm", action="store_true",
-                    help="pre-compile the autotuned winner shapes into "
+                    help="pre-compile the label-program shapes into "
                     "the persistent XLA cache (tools/warmcache.py)")
     ap.add_argument("--warm-batches", default="8192,4096,2048,1024,512",
                     help="batch sizes for --warm")
@@ -619,11 +587,7 @@ def main(argv=None) -> int:
         print(json.dumps(doc, indent=2))
         return 0
     if a.romix:
-        from ..ops import autotune
-
-        doc = romix_benchmark(
-            a.n, a.romix_batch or autotune.CAL_BATCH, reps=a.reps,
-            include_pallas=a.romix_pallas)
+        doc = romix_benchmark(a.n, a.romix_batch, reps=a.reps)
         print(json.dumps(doc, indent=2))
         return 0
     if a.prove:

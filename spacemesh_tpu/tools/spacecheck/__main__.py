@@ -69,8 +69,8 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--jobs", type=int, default=1, metavar="N",
                     help="fork-parallel rule execution (default: 1)")
     ap.add_argument("--cache", default=None, metavar="FILE",
-                    help="incremental findings cache (default: beside "
-                         "the autotune cache, $SPACEMESH_SPACECHECK_CACHE "
+                    help="incremental findings cache (default: under the "
+                         "checkout's .cache/, $SPACEMESH_SPACECHECK_CACHE "
                          "overrides; full-rule runs only)")
     ap.add_argument("--no-cache", action="store_true",
                     help="always recompute, never read/write the cache")

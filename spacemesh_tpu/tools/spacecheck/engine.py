@@ -228,7 +228,7 @@ class ProjectInfo:
 # A full run is (parse + pre-pass + N rules) x every file; rules keep
 # multiplying, and the CI spacecheck job runs BEFORE dependency install
 # on every push.  The cache persists per-file findings keyed by
-# ``(mtime, sha256)`` beside the autotune winners file, guarded by two
+# ``(mtime, sha256)`` under the checkout's cache root, guarded by two
 # whole-run digests that keep it SOUND for cross-file rules:
 #
 # * ``rules_digest`` — hash of engine.py + every rules/*.py source: any
@@ -248,8 +248,8 @@ CACHE_VERSION = 1
 
 
 def default_cache_path() -> str:
-    """Beside the autotune winners file, under the checkout's cache
-    root (utils/accel.py imports jax only inside functions — the
+    """Under the checkout's cache root, beside the compile cache
+    (utils/accel.py imports jax only inside functions — the
     analyzer must stay runnable before dependency install)."""
     explicit = os.environ.get(CACHE_ENV)
     if explicit:

@@ -19,8 +19,8 @@ teardown runs through fixtures):
   the probe.
 * **manual span brackets** — ``x.__enter__()`` requires
   ``x.__exit__(...)`` under a ``finally`` in the same function (the
-  autotune race uses exactly this shape; an unguarded exit loses the
-  span AND the contextvar reset on error).
+  initializer's session span uses exactly this shape; an unguarded
+  exit loses the span AND the contextvar reset on error).
 * **collectors** — ``<registry>.add_collector(...)`` has no remove;
   calling it anywhere a second construction can reach (i.e. inside a
   function) re-adds the hook forever. PR 7 keyed idempotence on a

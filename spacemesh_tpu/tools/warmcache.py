@@ -1,30 +1,27 @@
-"""Pre-warm the persistent XLA compile cache for the autotuned shapes.
+"""Pre-warm the persistent XLA compile cache for the label shapes.
 
 The label pipeline pays 17-26s of XLA compile per (N, batch) executable
 on a cold host — a cost that dominates every short session (bench runs,
 CI jobs, a node's first init batch after an upgrade). The persistent
 compile cache (utils/accel.py) already makes that once-per-machine;
-this tool makes it once-per-NOBODY by compiling exactly the shapes the
-autotuned winners will run — ahead of time, so tier-1/bench/operator
-sessions start warm (ISSUE 6; the CI warm-cache job publishes the
-resulting cache directory and every other job restores it).
+this tool makes it once-per-NOBODY by compiling the shapes ahead of
+time, so tier-1/bench/operator sessions start warm (ISSUE 6; the CI
+warm-cache job publishes the resulting cache directory and every other
+job restores it).
 
 What gets compiled per (N, bucketed batch):
 
 * the fused single-device label programs (``_labels_fused`` and the
-  min-scan variant) under the autotuned single-device decision — the
-  executables bench.py's sweep and the verifier's recomputes hit;
-* when the mesh race says ``devices > 1``: the GSPMD-sharded twins via
-  parallel/mesh.py — the executables the streaming initializer and the
-  bench mesh headline hit;
+  min-scan variant) — the executables the verifier's recomputes and a
+  one-device init hit;
+* where the mesh rule shards a batch of that width (parallel/mesh.py
+  ``auto_mesh``: more than one accelerator, or ``SPACEMESH_MESH``): the
+  GSPMD-sharded twins — the executables the streaming initializer hits;
 * with ``--prove``: the streaming prover's window step (the program a
   default Prover runs on this platform) at its default (bucketed) batch.
 
-Because decisions are taken through ops/autotune.py, a cold host races
-first (and persists the winners beside the cache), so one warmcache run
-leaves BOTH caches — executables and winners — ready. Shapes already in
-the cache deserialize in well under a second; the per-shape ``compile_s``
-in the output tells you which were actually cold.
+Shapes already in the cache deserialize in well under a second; the
+per-program seconds in the output tell you which were actually cold.
 
 Beyond the label shapes, every workload kind registered with the device
 runtime (runtime/workloads.py: fused init, packed multi-tenant init,
@@ -36,13 +33,8 @@ cache for every kind it can dispatch).  ``--no-runtime`` skips that.
 Usage:
   python -m spacemesh_tpu.tools.warmcache [--n 8192]
       [--batches 8192,4096,2048,1024,512] [--prove] [--no-mesh]
-      [--cached-shapes] [--no-runtime]
-      [--pack-lanes 4096]
+      [--no-runtime] [--pack-lanes 4096]
   python -m spacemesh_tpu.tools.profiler --warm      # same, via profiler
-
-``--cached-shapes`` additionally warms every shape that already has a
-persisted autotune winner for this platform (a machine that has run real
-workloads re-warms what those workloads used).
 """
 
 from __future__ import annotations
@@ -58,23 +50,6 @@ def _log(*a) -> None:
     print(*a, file=sys.stderr, flush=True)
 
 
-def _cached_shapes(platform: str) -> list[tuple[int, int]]:
-    """(n, batch) pairs with a persisted autotune winner on this host."""
-    from ..ops import autotune
-
-    out = set()
-    prefix = f"v{autotune.SCHEMA}:{platform}:"
-    for key in autotune._load_cache():
-        if not key.startswith(prefix):
-            continue
-        try:
-            n_part, b_part = key[len(prefix):].split(":")[:2]
-            out.add((int(n_part[1:]), int(b_part[1:])))
-        except (ValueError, IndexError):
-            continue
-    return sorted(out)
-
-
 def _warm_shape(n: int, batch: int, mesh_ok: bool) -> dict:
     """Compile (or cache-deserialize) every executable one (n, batch)
     shape runs at; returns per-program seconds."""
@@ -82,7 +57,7 @@ def _warm_shape(n: int, batch: int, mesh_ok: bool) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from ..ops import autotune, scrypt
+    from ..ops import scrypt
 
     commitment = hashlib.sha256(b"warmcache").digest()
     cw = scrypt.commitment_to_words(commitment)
@@ -98,10 +73,6 @@ def _warm_shape(n: int, batch: int, mesh_ok: bool) -> dict:
         doc["programs"][name] = round(time.perf_counter() - t0, 2)
         _log(f"  {name}: {doc['programs'][name]}s")
 
-    # single-device decision + fused programs (bench sweep, verifier)
-    d1 = autotune.decide(n, batch)
-    doc["impl"] = d1.impl
-    doc["chunk"] = d1.chunk
     timed("labels_fused", lambda: scrypt.scrypt_labels_jit(
         jcw, jlo, jhi, n=n))
     timed("labels_min_fused", lambda: scrypt.scrypt_labels_with_min(
@@ -109,37 +80,17 @@ def _warm_shape(n: int, batch: int, mesh_ok: bool) -> dict:
 
     if not mesh_ok:
         return doc
-    dm = autotune.decide(n, batch, max_devices=None)
-    doc["devices"] = dm.devices
-    if dm.devices <= 1 or batch % dm.devices:
-        return doc
     from ..parallel import mesh as pmesh
 
-    mesh = pmesh.data_mesh(jax.devices()[:dm.devices])
-    timed(f"labels_sharded_d{dm.devices}",
-          lambda: pmesh.scrypt_labels_sharded(mesh, cw, lo, hi, n=n,
-                                              impl=dm.impl))
-    timed(f"labels_min_sharded_d{dm.devices}",
+    mesh = pmesh.auto_mesh(batch)
+    doc["devices"] = mesh.size if mesh else 1
+    if mesh is None:
+        return doc
+    timed(f"labels_sharded_d{mesh.size}",
+          lambda: pmesh.scrypt_labels_sharded(mesh, cw, lo, hi, n=n))
+    timed(f"labels_min_sharded_d{mesh.size}",
           lambda: pmesh.labels_with_min_sharded(
-              mesh, cw, lo, hi, scrypt.vrf_carry_init(), n=n,
-              impl=dm.impl)[0])
-    # BOTH persisted mesh-shape winners (lane-sharded and V-sharded),
-    # not just the routed one: a later re-race or SPACEMESH_ROMIX flip
-    # that lands on the other layout must hit the compile cache, not pay
-    # a cold GSPMD compile mid-session
-    doc["mesh_shapes"] = {}
-    for shape in autotune.MESH_SHAPES:
-        sw = autotune.shape_winner(n, batch, shape, max_devices=None)
-        if sw is None or sw.devices <= 1 or batch % sw.devices:
-            continue
-        doc["mesh_shapes"][shape] = {"impl": sw.impl,
-                                     "devices": sw.devices}
-        if (sw.impl, sw.devices) == (dm.impl, dm.devices):
-            continue  # the routed winner above already compiled it
-        smesh = pmesh.data_mesh(jax.devices()[:sw.devices])
-        timed(f"labels_sharded_{shape}_d{sw.devices}",
-              lambda sm=smesh, si=sw.impl: pmesh.scrypt_labels_sharded(
-                  sm, cw, lo, hi, n=n, impl=si))
+              mesh, cw, lo, hi, scrypt.vrf_carry_init(), n=n)[0])
     return doc
 
 
@@ -181,7 +132,6 @@ def _warm_runtime_kinds(n: int, batch: int, pack_lanes: int) -> dict:
 
 def warm(n: int = 8192, batches: list[int] | None = None, *,
          mesh: bool = True, prove: bool = False,
-         cached_shapes: bool = False,
          runtime_kinds: bool = True, pack_lanes: int = 4096) -> dict:
     """Warm the persistent caches; returns a JSON-able report."""
     import os
@@ -190,7 +140,8 @@ def warm(n: int = 8192, batches: list[int] | None = None, *,
 
     if mesh and os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
         # BEFORE any backend use (jax.default_backend below instantiates
-        # it): expose the virtual host devices the mesh winners run on
+        # it): expose the virtual host devices a forced SPACEMESH_MESH
+        # shards over
         accel.ensure_host_devices()
     import jax
 
@@ -198,12 +149,10 @@ def warm(n: int = 8192, batches: list[int] | None = None, *,
     cache_dir = accel.enable_persistent_cache()
     _log(f"persistent compile cache: {cache_dir}")
 
-    from ..ops import autotune, scrypt
+    from ..ops import scrypt
 
     shapes = {(n, scrypt.shape_bucket(b))
               for b in (batches or [8192, 4096, 2048, 1024, 512])}
-    if cached_shapes:
-        shapes.update(_cached_shapes(platform))
     t0 = time.perf_counter()
     done = []
     for sn, sb in sorted(shapes):
@@ -218,7 +167,6 @@ def warm(n: int = 8192, batches: list[int] | None = None, *,
         "platform": platform,
         "devices_visible": jax.device_count(),
         "cache_dir": cache_dir,
-        "autotune_cache": autotune.cache_path(),
         "shapes": done,
         "elapsed_s": round(time.perf_counter() - t0, 1),
     }
@@ -235,7 +183,7 @@ def warm(n: int = 8192, batches: list[int] | None = None, *,
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="warmcache",
-        description="pre-compile the autotuned winner shapes into the "
+        description="pre-compile the label-program shapes into the "
                     "persistent XLA cache (docs/ROMIX_KERNEL.md)")
     ap.add_argument("--n", type=int, default=8192, help="scrypt N")
     ap.add_argument("--batches", default="8192,4096,2048,1024,512",
@@ -244,9 +192,6 @@ def main(argv=None) -> int:
                     help="skip the sharded (multi-device) programs")
     ap.add_argument("--prove", action="store_true",
                     help="also warm the streaming prover's scan step")
-    ap.add_argument("--cached-shapes", action="store_true",
-                    help="also warm every shape with a persisted "
-                    "autotune winner on this host")
     ap.add_argument("--no-runtime", action="store_true",
                     help="skip the registered runtime workload kinds "
                     "(fused/packed init, prove scan, verify, k2pow)")
@@ -256,7 +201,6 @@ def main(argv=None) -> int:
     a = ap.parse_args(argv)
     doc = warm(a.n, [int(b) for b in a.batches.split(",") if b],
                mesh=not a.no_mesh, prove=a.prove,
-               cached_shapes=a.cached_shapes,
                runtime_kinds=not a.no_runtime, pack_lanes=a.pack_lanes)
     print(json.dumps(doc, indent=2))
     return 0

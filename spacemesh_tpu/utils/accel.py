@@ -7,8 +7,8 @@ accelerator, starts a child, or pins a platform: a chip belongs to one
 process, so a probe child would itself take it.
 
 Everything a run generates outside its data directories — the XLA
-compile cache, the two tuner files that steer which program runs
-(ops/autotune.py, verifyd/batchtune.py), spacecheck's findings cache and
+compile cache, the batch tuner's file that steers verifyd's batch sizes
+(verifyd/batchtune.py), spacecheck's findings cache and
 the native libraries' build output — lives under ONE git-ignored
 directory inside the checkout (:data:`CACHE_ROOT`), so a run is a
 function of the checkout and nothing is written under ``~/.cache``.
@@ -76,24 +76,21 @@ def announce_platform(prog: str) -> None:
           file=sys.stderr, flush=True)
 
 
-DEFAULT_HOST_DEVICES = 8  # the autotuner's raced mesh grid is {1,2,4,8}
+DEFAULT_HOST_DEVICES = 8  # what tests/conftest.py and the CI jobs force
 
 
 def ensure_host_devices(count: int | None = None) -> int:
     """Expose ``count`` virtual CPU devices (XLA_FLAGS, this process AND
     children) so a CPU run can lane-shard label batches across them
-    (parallel/mesh.py; the autotuner races whether/how many win —
-    ops/autotune.py mesh dimension).
+    (parallel/mesh.py; on the CPU only a forced ``SPACEMESH_MESH`` or
+    an explicit ``mesh=`` shards).
 
     Must run BEFORE the first backend use — the flag is read when the
     CPU client is instantiated; afterwards it is inert (harmless). A
     pre-existing ``xla_force_host_platform_device_count`` flag (tests'
     conftest, the driver entry) is respected, as is
-    ``SPACEMESH_HOST_DEVICES`` (0/off disables). Oversubscription is
-    deliberate: more virtual devices than cores still wins on the
-    op-dispatch-bound label kernel (sequential per-device streams beat
-    one device's intra-op parallelism), and the race decides per host
-    how many to actually use. Returns the count in effect."""
+    ``SPACEMESH_HOST_DEVICES`` (0/off disables). Returns the count in
+    effect."""
     env = os.environ.get("SPACEMESH_HOST_DEVICES")
     if env is not None and env.lower() in ("0", "off", "none"):
         return 1

@@ -309,8 +309,8 @@ post_pipeline_meta_saves = REGISTRY.counter(
 post_pipeline_labels_per_sec = REGISTRY.gauge(
     "post_pipeline_labels_per_sec", "labels/s of the last init session")
 
-# autotuned device mesh (ops/autotune.py mesh dimension, consumed by
-# post/initializer.py + post/prover.py). Shard fetch seconds include the
+# the device mesh label batches shard over (parallel/mesh.py auto_mesh,
+# consumed by post/initializer.py + post/prover.py). Shard fetch seconds include the
 # first shard's wait for the sharded program to retire; the imbalance
 # gauge is (max-min)/max over the last batch's per-shard fetch seconds,
 # so a straggling device (or an unevenly split host thread pool) is
@@ -324,11 +324,6 @@ post_mesh_shard_labels_per_sec = REGISTRY.gauge(
 post_mesh_shard_imbalance = REGISTRY.gauge(
     "post_mesh_shard_imbalance",
     "(max-min)/max per-shard fetch seconds of the last sharded batch")
-
-# ROMix label kernel (ops/scrypt.py dispatch + ops/autotune.py)
-post_romix_autotune_races = REGISTRY.counter(
-    "post_romix_autotune_races_total",
-    "ROMix kernel autotune races run (persisted-winner cache misses)")
 
 # POST label-store reads (post/data.py LabelStore.read_labels — the serial
 # prover and the prefetching LabelReader pool both land here). The prove

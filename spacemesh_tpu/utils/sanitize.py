@@ -26,8 +26,8 @@ kinds — ``race``, ``slow-callback`` (alias ``slow``),
 
 3. **Compile-explosion guard** (the PR 6 compile-cost contract,
    enforced instead of hoped): the fused label pipelines may only be
-   dispatched at power-of-two lane buckets — the grid the autotuner
-   races and ``tools/warmcache.py`` pre-compiles. An off-bucket shape
+   dispatched at power-of-two lane buckets — the grid
+   ``tools/warmcache.py`` pre-compiles. An off-bucket shape
    means some caller bypassed the pad-and-trim wrappers and is about
    to pay a 17–26s XLA compile per ragged size; the guard raises
    :class:`SanitizeError` at the dispatch boundary with the offending
@@ -337,7 +337,7 @@ def on_jit_shape(fn_name: str, lanes: int) -> None:
         return
     _record(KIND_SHAPE,
             f"{fn_name} dispatched {lanes} lanes — outside the "
-            "power-of-two bucket grid the autotuner warms; some caller "
+            "power-of-two bucket grid warmcache compiles; some caller "
             "bypassed the pad-and-trim wrappers (shape_bucket)",
             span=tracing.current_id())
     raise SanitizeError(

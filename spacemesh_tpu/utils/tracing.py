@@ -554,7 +554,11 @@ def span_multiset_digest(doc) -> str:
     multiset — the replay-stable identity of a capture. Timestamps, span
     ids and durations are wall/ordering artifacts and stay out; under
     the sim's deterministic virtual clock the multiset is a pure
-    function of (seed, W), so same seed ⇒ byte-identical digest."""
+    function of (seed, W), so same seed ⇒ byte-identical digest.
+    ``xla.compile`` spans stay out as well: whether a program compiles
+    says what this PROCESS had compiled before the run began (the first
+    of two seeded runs in one process compiles, the second finds every
+    executable cached), not what the run did."""
     import hashlib
 
     roles = {}
@@ -563,7 +567,7 @@ def span_multiset_digest(doc) -> str:
             roles[ev["pid"]] = ev["args"]["name"]
     counts: dict[tuple, int] = {}
     for ev in doc.get("traceEvents", ()):
-        if ev.get("ph") in ("X", "i"):
+        if ev.get("ph") in ("X", "i") and ev["name"] != "xla.compile":
             key = (roles.get(ev["pid"], str(ev["pid"])), ev["name"])
             counts[key] = counts.get(key, 0) + 1
     h = hashlib.sha256()

@@ -6,16 +6,14 @@ network verification service (verifyd) sees workloads whose optimal
 batch size varies by orders of magnitude per kind: a k2pow witness
 batch amortizes device dispatch across thousands of lanes, a pure-Python
 ed25519 MSM check peaks around a few hundred signatures, a POST
-recompute is already near-flat past a handful of proofs.  Guessing those
-numbers per host is exactly the problem ops/autotune.py already solved
-for the ROMix kernel, so this module reuses its **race-and-persist**
-pattern:
+recompute is already near-flat past a handful of proofs.  Those numbers
+differ per host, so this module **races and persists** them:
 
 * :meth:`BatchTuner.ensure_raced` measures each kind's REAL backend at
   a ladder of candidate batch sizes on a deterministic calibration
   workload (once per host), and persists the measured ``batch ->
-  items/sec`` rows to ``<cache root>/verifyd_batchtune.json`` beside the
-  ROMix winners file — a second process skips the race entirely.
+  items/sec`` rows to ``<cache root>/verifyd_batchtune.json`` — a
+  second process skips the race entirely.
   ``SPACEMESH_VERIFYD_TUNE=off`` disables racing (static defaults +
   online refinement only); ``SPACEMESH_VERIFYD_TUNE_CACHE`` moves the
   file.  A corrupt or unreadable file is ignored and re-raced.
@@ -81,8 +79,7 @@ def race_enabled() -> bool:
 
 
 def cache_path() -> str:
-    """The measured-rates file, under the checkout's cache root (the
-    same placement rule as ops/autotune.cache_path)."""
+    """The measured-rates file, under the checkout's cache root."""
     explicit = os.environ.get(ENV_CACHE)
     if explicit:
         return os.path.expanduser(explicit)

@@ -35,20 +35,10 @@ os.environ.pop("SPACEMESH_LOG_JSON", None)
 # sanitize.enable() themselves — tests/test_spacecheck.py)
 os.environ.pop("SPACEMESH_SANITIZE", None)
 
-# the ROMix autotuner (ops/autotune.py) must stay deterministic and cheap
-# under test: no implicit candidate races, and never persist winners into
-# the developer's real cache root. The autotune tests opt back in with
-# monkeypatch (tests/test_romix_autotune.py).
-os.environ.setdefault("SPACEMESH_ROMIX_AUTOTUNE", "off")
-os.environ.setdefault(
-    "SPACEMESH_ROMIX_CACHE",
-    os.path.join(tempfile.gettempdir(),
-                 f"spacemesh-test-romix-{os.getpid()}.json"))
-
-# the verifyd batch tuner (verifyd/batchtune.py) mirrors the ROMix
-# autotuner's discipline: no implicit backend races under test, and
-# never persist measured rates into the developer's real cache root
-# (tests that want a race opt back in with monkeypatch)
+# the verifyd batch tuner (verifyd/batchtune.py) must stay deterministic
+# and cheap under test: no implicit backend races, and never persist
+# measured rates into the developer's real cache root (tests that want
+# a race opt back in with monkeypatch)
 os.environ.setdefault("SPACEMESH_VERIFYD_TUNE", "off")
 os.environ.setdefault(
     "SPACEMESH_VERIFYD_TUNE_CACHE",

@@ -49,6 +49,7 @@ def test_phases_hold_at_toy_scale():
     assert report["fallbacks_moved"] == {}
     for name in ("init", "prove", "verify", "verifyd"):
         assert {"wall_s", "compile_s"} <= set(report[name]), name
-    for name in ("init", "prove", "verify"):
-        assert {"impl", "devices", "source"} <= set(
-            report[name]["decision"]), name
+    for name in ("init", "verify"):
+        assert report[name]["decision"]["devices"] == 1, name
+        assert report[name]["decision"]["batch"] > 0, name
+    assert {"impl", "devices"} <= set(report["prove"]["decision"])

@@ -56,6 +56,18 @@ def test_pipelined_matches_serial(unit, serial_proof):
     assert prover.last_stats.batches > 0
 
 
+def test_prover_traces_no_label_program(unit, serial_proof):
+    # a prover never computes a label: building one, resolving where its
+    # batches run and proving must not trace (let alone compile) either
+    # label program — it asks the mesh rule, not the label kernel
+    d, _ = unit
+    before = scrypt.compiled_shape_count()
+    prover = Prover(d, PARAMS, batch_labels=512)
+    assert prover._resolve_mesh() is None  # the CPU rule: one device
+    assert prover.prove(CH) == serial_proof
+    assert scrypt.compiled_shape_count() == before
+
+
 def test_wide_window_matches_serial(unit, serial_proof):
     # window spanning several nonce groups still picks the serial winner
     d, _ = unit

@@ -76,6 +76,25 @@ def test_multi_commitment_labels_match_hashlib():
             np.zeros((2, 32), dtype=np.uint8), [1, 2, 3], n=16)
 
 
+@pytest.mark.parametrize("lanes", [37, 129, 148])
+def test_multi_commitment_labels_match_hashlib_at_bucket_edges(lanes):
+    """The verifier's widths: K2=37 indices of one proof (bucket 64), one
+    lane past a bucket (129 -> 256) and four proofs (148 -> 256), every
+    lane with its own commitment: each label equals hashlib's, so the
+    host pad repeats the last LANE (index and commitment) and the trim
+    drops exactly the pad."""
+    commits = np.stack([np.frombuffer(hashlib.sha256(b"v%d" % i).digest(),
+                                      dtype=np.uint8)
+                        for i in range(lanes)])
+    idx = (np.arange(lanes, dtype=np.uint64) * np.uint64(2654435761)
+           ) % np.uint64(2**34)
+    got = scrypt.scrypt_labels_multi(commits, idx, n=16)
+    assert got.shape == (lanes, scrypt.LABEL_BYTES)
+    for k in range(lanes):
+        assert bytes(got[k]) == cpu_label(bytes(commits[k]), int(idx[k]),
+                                          16), f"lane {k} of {lanes}"
+
+
 def test_sha256_words_vs_hashlib():
     from spacemesh_tpu.ops import sha256 as s
     for msg in (b"", b"abc", b"x" * 55, b"y" * 56, b"z" * 200):

@@ -1,15 +1,14 @@
 """The GSPMD data plane (ISSUE 16): one process-wide topology, persistent
 layout catalog, mesh-sharded pack/verify twins bit-identical to the
-single-device paths, and mesh-shape autotune winners that persist."""
+single-device paths."""
 
 import hashlib
-import json
 
 import jax
 import numpy as np
 import pytest
 
-from spacemesh_tpu.ops import autotune, scrypt
+from spacemesh_tpu.ops import scrypt
 from spacemesh_tpu.parallel import data_mesh, topology
 from spacemesh_tpu.parallel import mesh as pmesh
 
@@ -17,27 +16,12 @@ N = 4
 
 
 @pytest.fixture
-def tuner(tmp_path, monkeypatch):
-    """Fresh autotune world: private winners file, no overrides, no
-    memoized decisions (racing stays OFF via conftest)."""
-    path = tmp_path / "romix_autotune.json"
-    monkeypatch.setenv(autotune.ENV_CACHE, str(path))
-    monkeypatch.delenv(autotune.ENV_IMPL, raising=False)
-    monkeypatch.delenv(autotune.ENV_CHUNK, raising=False)
-    monkeypatch.delenv(autotune.ENV_MESH, raising=False)
-    autotune.reset_memo()
-    yield path
-    autotune.reset_memo()
-
-
-def _seed_mesh_winner(path, n, batch, devices, impl="xla"):
-    key = autotune._key("cpu", n, scrypt.shape_bucket(batch),
-                        autotune._device_cap(None))
-    doc = json.loads(path.read_text()) if path.exists() else {}
-    doc[key] = {"impl": impl, "chunk": None, "devices": devices,
-                "labels_per_sec": 9999.0}
-    path.write_text(json.dumps(doc))
-    autotune.reset_memo()
+def mesh_env(monkeypatch):
+    """-> a setter for SPACEMESH_MESH, which starts unset: the way a
+    test asks the mesh rule (parallel/mesh.py auto_mesh) for a sharded
+    run on the virtual CPU devices."""
+    monkeypatch.delenv(pmesh.ENV_MESH, raising=False)
+    return lambda devices: monkeypatch.setenv(pmesh.ENV_MESH, str(devices))
 
 
 # --- the topology singleton + persistent catalog --------------------------
@@ -86,7 +70,7 @@ def test_replicate_is_noop_for_resident_carry():
 
 @pytest.mark.parametrize("totals", [(1,), (7,), (7, 1039)],
                          ids=["1", "7", "7+1039"])
-def test_packed_init_sharded_bit_identity(tuner, tmp_path, totals):
+def test_packed_init_sharded_bit_identity(mesh_env, tmp_path, totals):
     """The TenantScheduler's pack dispatch routed over a 4-device mesh
     produces byte-identical label files and VRF nonces to the host
     reference at ragged totals (host pre-bucket pad + segment slicing)."""
@@ -94,7 +78,7 @@ def test_packed_init_sharded_bit_identity(tuner, tmp_path, totals):
     from spacemesh_tpu.runtime import TenantScheduler
 
     pack = 256
-    _seed_mesh_winner(tuner, N, pack, devices=4)
+    mesh_env(4)
     ids = [(f"t{i}", hashlib.sha256(b"tnode%d" % i).digest(),
             hashlib.sha256(b"tcommit%d" % i).digest(), total)
            for i, total in enumerate(totals)]
@@ -119,18 +103,18 @@ def test_packed_init_sharded_bit_identity(tuner, tmp_path, totals):
             hi = want[:, 8:].copy().view("<u8").ravel()
             assert meta.vrf_nonce == int(np.lexsort((lo, hi))[0]), tid
     # the routing the packer consulted really was the sharded one
-    devs, _ = autotune.resolve_auto_mesh(N, scrypt.shape_bucket(pack))
-    assert devs is not None and len(devs) == 4
+    mesh = pmesh.auto_mesh(scrypt.shape_bucket(pack))
+    assert mesh is not None and mesh.size == 4
 
 
-def test_packed_init_steady_state_zero_new_compiles(tuner, tmp_path):
+def test_packed_init_steady_state_zero_new_compiles(mesh_env, tmp_path):
     """A warm process dispatches sharded packs with ZERO new compiles:
     after the first pack at a bucket, compiled_shape_count() stays flat
     for every later pack at that bucket (acceptance criterion)."""
     from spacemesh_tpu.runtime import TenantScheduler
 
     pack = 128
-    _seed_mesh_winner(tuner, N, pack, devices=4)
+    mesh_env(4)
 
     def run(tag, totals):
         with TenantScheduler(workers=2, pack_lanes=pack) as sched:
@@ -159,7 +143,7 @@ def test_packed_init_steady_state_zero_new_compiles(tuner, tmp_path):
 
 @pytest.mark.parametrize("devices", [2, 4])
 @pytest.mark.parametrize("count", [1, 7, 1039])
-def test_farm_verify_sharded_matches_single_device(tuner, count, devices):
+def test_farm_verify_sharded_matches_single_device(mesh_env, count, devices):
     """verify_many has ONE device path (ISSUE 24) and a mesh changes
     only where the arrays are placed: over a mesh-routed batch it
     returns the verdicts of the one-device pass, at ragged spot-check
@@ -180,68 +164,13 @@ def test_farm_verify_sharded_matches_single_device(tuner, count, devices):
     seed = b"topology-seed".ljust(32, b"\0")
     bucket = scrypt.shape_bucket(count)
 
-    autotune.reset_memo()
-    assert autotune.resolve_auto_mesh(N, bucket)[0] is None
+    assert pmesh.auto_mesh(bucket) is None
     single = verifier.verify_many(items, p, seed)
-    _seed_mesh_winner(tuner, N, bucket, devices=devices)
+    mesh_env(devices)
     sharded = verifier.verify_many(items, p, seed)
     assert sharded == single
     if count > 1:
         assert True in single and False in single
     if bucket % devices == 0:
-        devs, _ = autotune.resolve_auto_mesh(N, bucket)
-        assert devs is not None and len(devs) == devices
-
-
-# --- mesh-shape autotune winners ------------------------------------------
-
-
-def _fake_rows(platform, n, combos):
-    """Synthetic race: V-sharded (xla-rows) wins at 4 devices, the best
-    lane-sharded row is xla at 2; single-device rows stay slow."""
-    rates = {("xla-rows", 4): 4000.0, ("xla-rows", 2): 2500.0,
-             ("xla", 2): 3000.0, ("xla", 4): 2900.0, ("xla", 8): 2800.0,
-             ("xla-rows", 8): 2600.0}
-    return [{"impl": impl, "chunk": chunk, "devices": d,
-             "shape": autotune.shape_of(impl),
-             "labels_per_sec": rates.get((impl, d), 100.0)}
-            for impl, chunk, d in combos]
-
-
-def test_mesh_shape_winner_persist_and_reread(tuner, monkeypatch):
-    """race() persists a winner PER mesh shape; shape_winner() re-reads
-    both from disk in a fresh memo world (the round-trip criterion)."""
-    monkeypatch.setenv(autotune.ENV_AUTOTUNE, "on")
-    monkeypatch.setattr(autotune, "_race_rows", _fake_rows)
-    d = autotune.decide(N, 512, platform="cpu", max_devices=None)
-    assert (d.impl, d.devices, d.mesh_shape) == ("xla-rows", 4, "vshard")
-
-    # fresh process: memos dropped, everything comes off the disk file
-    autotune.reset_memo()
-    monkeypatch.setenv(autotune.ENV_AUTOTUNE, "off")
-    lane = autotune.shape_winner(N, 512, "lane", platform="cpu",
-                                 max_devices=None)
-    vshard = autotune.shape_winner(N, 512, "vshard", platform="cpu",
-                                   max_devices=None)
-    assert (lane.impl, lane.devices, lane.mesh_shape) == ("xla", 2, "lane")
-    assert (vshard.impl, vshard.devices, vshard.mesh_shape) \
-        == ("xla-rows", 4, "vshard")
-    # and the overall cached winner still resolves (source=cache)
-    d2 = autotune.decide(N, 512, platform="cpu", max_devices=None)
-    assert (d2.impl, d2.devices, d2.source) == ("xla-rows", 4, "cache")
-    assert d2.mesh_shape == "vshard"
-
-
-def test_legacy_winner_entries_default_their_shape(tuner):
-    """Pre-shape winners files (written before ISSUE 16) resolve with
-    the shape implied by their impl — no re-race, no schema bump."""
-    _seed_mesh_winner(tuner, N, 512, devices=4, impl="xla-rows")
-    d = autotune.decide(N, 512, platform="cpu", max_devices=None)
-    assert (d.devices, d.mesh_shape) == (4, "vshard")
-    assert autotune.shape_winner(N, 512, "lane", platform="cpu",
-                                 max_devices=None) is None
-
-
-def test_shape_winner_rejects_unknown_shape(tuner):
-    with pytest.raises(ValueError):
-        autotune.shape_winner(N, 512, "diagonal", platform="cpu")
+        mesh = pmesh.auto_mesh(bucket)
+        assert mesh is not None and mesh.size == devices

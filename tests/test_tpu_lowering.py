@@ -60,12 +60,24 @@ def build(name, fn, *args, **kw):
     return c
 
 
-# init: the fused label + VRF min-scan program at mainnet N (a small
-# batch keeps the compile to seconds; the shape is otherwise the chip's)
-b = 128
-build("labels_min_fused", scrypt._labels_min_fused, sds((8,)), sds((b,)),
-      sds((b,)), sds((scrypt.VRF_CARRY_WORDS,)), n=N, impl="xla",
-      chunk=None, interpret=False)
+# the label program at mainnet N and the widths the cells dispatch: the
+# verifier's lane buckets (per-lane commitments, no min-scan) and init's
+# 8,192 lanes a chip with the VRF min-scan, on one chip and lane-sharded
+# over the four of a 2x2 host
+mesh = Mesh(np.array(topo.devices), ("data",))
+lanes = NamedSharding(mesh, P("data"))
+everywhere = NamedSharding(mesh, P())
+for b in (32, 64, 128, 256):
+    build(f"labels_{b}", scrypt._labels_fused, sds((8, b)), sds((b,)),
+          sds((b,)), n=N)
+b = 8192
+build(f"labels_{b}", scrypt._labels_min_fused, sds((8,)), sds((b,)),
+      sds((b,)), sds((scrypt.VRF_CARRY_WORDS,)), n=N)
+b = 32768
+build(f"labels_{b}x4", scrypt._labels_min_fused,
+      sds((8,), sharding=everywhere), sds((b,), sharding=lanes),
+      sds((b,), sharding=lanes),
+      sds((scrypt.VRF_CARRY_WORDS,), sharding=everywhere), n=N)
 
 # prove: both scan steps the Prover can bind on tpu (Pallas is the
 # single-device default, XLA the sharded one and the reference), at the
@@ -104,14 +116,11 @@ build("pow_verify", k2pow.pow_verify_batch_jit, sds((16, b)), sds((b,)),
 
 # four chips: the label batch lane-sharded over the 2x2 host
 b = 2048
-label_kw = dict(n=N, impl="xla", chunk=None, interpret=False)
 build("labels_single", scrypt._labels_fused, sds((8,)), sds((b,)),
-      sds((b,)), **label_kw)
-mesh = Mesh(np.array(topo.devices), ("data",))
-lanes = NamedSharding(mesh, P("data"))
+      sds((b,)), n=N)
 build("labels_sharded", scrypt._labels_fused,
-      sds((8,), sharding=NamedSharding(mesh, P())),
-      sds((b,), sharding=lanes), sds((b,), sharding=lanes), **label_kw)
+      sds((8,), sharding=everywhere),
+      sds((b,), sharding=lanes), sds((b,), sharding=lanes), n=N)
 
 print(json.dumps(out))
 """
@@ -137,7 +146,7 @@ def test_tpu_default_programs_compile_for_v5e(lowered):
     assert lowered["devices"] == 4
     # the Pallas scan step is the tpu default (and stays reachable via
     # use_pallas=True), so Mosaic must keep compiling it
-    for name in ("labels_min_fused", "prove_step_xla", "prove_step_pallas",
+    for name in ("labels_8192", "prove_step_xla", "prove_step_pallas",
                  "prove_mask_pallas", "prove_window_xla",
                  "prove_window_pallas", "pow_hash", "pow_below_target",
                  "pow_verify"):
@@ -157,3 +166,16 @@ def test_labels_shard_over_four_chips_without_collectives(lowered):
     # V dominates temp and shards with the lanes: a quarter per device
     ratio = sharded["temp"] / single["temp"]
     assert 0.2 < ratio < 0.3, ratio
+
+
+@pytest.mark.parametrize("lanes,chips", [(32, 1), (64, 1), (128, 1),
+                                         (256, 1), (8192, 1), (32768, 4)])
+def test_label_program_keeps_v_for_the_whole_batch(lowered, lanes, chips):
+    """At every width a cell dispatches the label program compiles, and
+    ROMix's V for the WHOLE batch (128 * N bytes a lane: 8 GiB a chip at
+    the init cells' shape) is in its temporary memory, under the chip's
+    16 GB: a lane chunk (29% slower at 256 lanes on the chip, ROADMAP
+    S3) cannot come back unseen."""
+    temp = lowered[f"labels_{lanes}" + (f"x{chips}" if chips > 1 else "")
+                   ]["temp"]
+    assert 128 * 8192 * lanes // chips <= temp < 16e9, temp
