@@ -442,6 +442,10 @@ verify_farm_dedup_hits = REGISTRY.counter(
     "requests coalesced onto an identical in-flight request")
 verify_farm_batches = REGISTRY.counter(
     "verify_farm_batches_total", "batches dispatched (label: kind)")
+verify_farm_batches_held = REGISTRY.counter(
+    "verify_farm_batches_held_total",
+    "batches that stood ready behind the in-flight cap before they "
+    "went (label: kind)")
 verify_farm_batch_occupancy = REGISTRY.histogram(
     "verify_farm_batch_occupancy", "requests per dispatched batch",
     buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512, float("inf")))

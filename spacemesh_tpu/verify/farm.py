@@ -19,6 +19,10 @@ inference serving applied to crypto verification.
   backend is idle — so a lone request never waits out the coalescing
   window (the window only pays off under load, which is also the only
   time it fills).
+* One POST batch is on the device at a time (DEVICE_INFLIGHT): what
+  arrives behind a flight gathers in the lanes and leaves as one wider
+  program when it returns, a power of two of items at a time. Host
+  kinds overlap up to ``max_inflight``.
 * Three lanes — BLOCK (block-critical: certificates, hare-adjacent) >
   GOSSIP > SYNC (backfill) — with per-lane queue bounds. A saturated
   sync lane backpressures its *submitters*; batch composition always
@@ -71,6 +75,14 @@ KIND_POST = "post"
 KIND_MEMBERSHIP = "membership"
 KIND_POW = "pow"
 KINDS = (KIND_SIG, KIND_VRF, KIND_POST, KIND_MEMBERSHIP, KIND_POW)
+# kinds whose backend is ONE program on the device's serial queue
+# (_verify_posts -> verify_many -> one device.flight): a second batch
+# in flight only queues behind the first, where nothing can merge it.
+# What arrives behind a flight stays in the lanes and leaves when the
+# flight returns, as one wider program: a label program's time grows
+# far slower than its lanes (PERF.md section 5 has the widths). Host
+# kinds run side by side on threads and keep max_inflight.
+DEVICE_INFLIGHT = {KIND_POST: 1}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -479,7 +491,7 @@ class VerificationFarm:
                 # one loop turn so same-tick submitters (gather bursts)
                 # land in this batch
                 await asyncio.sleep(0)
-                reason = await self._coalesce(kind, st)
+                reason, held_s = await self._coalesce(kind, st)
                 if self._closed:
                     break
                 # take() is NOT capped at the tuned target: the target
@@ -489,14 +501,25 @@ class VerificationFarm:
                 # target — capping at the target would lock a
                 # collapsed model in place (it could never measure a
                 # fuller batch again)
-                batch = st.lanes.take(self.max_batch)
+                n = min(st.lanes.count(), self.max_batch)
+                if kind in DEVICE_INFLIGHT and n:
+                    # a device batch is padded to a power of two
+                    # (_verify_posts): take a whole one and leave the
+                    # rest for the next flight, where it merges with
+                    # what arrives meanwhile, rather than fill a
+                    # quarter of a program with duplicates
+                    n = 1 << (n.bit_length() - 1)
+                batch = st.lanes.take(n)
                 if not batch:
                     continue
                 self._on_taken(batch)
+                if held_s > 0:
+                    metrics.verify_farm_batches_held.inc(kind=kind)
                 # why this batch, this size, now (farm.batch attributes)
                 why = ({"reason": reason, "inflight": len(st.inflight),
                         "target": self._batch_limit(kind),
-                        "left": st.lanes.count()}
+                        "left": st.lanes.count(),
+                        "held_ms": round(held_s * 1e3, 3)}
                        if tracing.is_enabled() else None)
                 task = self._loop.create_task(
                     self._dispatch(kind, batch, why))
@@ -528,11 +551,14 @@ class VerificationFarm:
         return bool(self._tuner.dispatch_now(kind, n,
                                              max(now - oldest, 0.0)))
 
-    async def _coalesce(self, kind: str, st: _KindState) -> str | None:
+    async def _coalesce(self, kind: str,
+                        st: _KindState) -> tuple[str | None, float]:
         """Hold the batch open until it is worth dispatching; returns
         the clause that let it go (``full`` | ``idle`` | ``deadline`` |
         ``tuner``, or ``block`` when only a pending BLOCK request got it
-        past the in-flight cap), None when there is nothing to take.
+        past the in-flight cap; None when there is nothing to take) and
+        the seconds the batch stood ready behind the in-flight cap: a
+        clause said go and no slot was free.
 
         Dispatch NOW when: the batch is full (the per-kind tuned target
         when a batch tuner is attached); the backend is idle (a lone
@@ -540,19 +566,24 @@ class VerificationFarm:
         pending deadline has passed and an in-flight slot is free; or
         the tuner's speculative model says the marginal wait for more
         items exceeds the predicted throughput gain. The in-flight cap
-        throttles small-batch churn under load — but a pending BLOCK
-        request bypasses the cap, so a saturated sync lane can never
-        delay block-critical dispatch beyond its deadline."""
+        (one batch of a device kind, DEVICE_INFLIGHT; max_inflight of
+        the others) throttles small-batch churn under load, and for a
+        device kind it is what merges: what gathers behind a flight
+        goes as one batch when it returns. A pending BLOCK request
+        bypasses the cap, so a saturated sync lane can never keep
+        block-critical work in the lanes beyond its deadline."""
+        held_since = capped_since = None
         while not self._closed:
             n = st.lanes.count()
             if n == 0:
-                return None
+                return None, 0.0
             # the in-flight cap gates EVERY dispatch (a full batch too:
             # spawning the whole backlog at once would flood the worker
             # pool and anything submitted later — block-critical work
             # included — would queue behind sleeping threads). Only a
             # pending BLOCK request bypasses the cap.
-            under_cap = len(st.inflight) < self.max_inflight
+            under_cap = len(st.inflight) < DEVICE_INFLIGHT.get(
+                kind, self.max_inflight)
             can_go = under_cap or bool(st.lanes.lanes[Lane.BLOCK])
             now = self._loop.time()
             if self._tuner is None:
@@ -572,8 +603,16 @@ class VerificationFarm:
                       if st.lanes.earliest_deadline() <= now
                       else "tuner" if self._tuner_go(kind, st, n, now)
                       else None)
+            if not can_go and capped_since is None:
+                capped_since = now
+            if go and held_since is None and capped_since is not None:
+                # ready since now, or since a deadline that passed
+                # while the cap held it and nothing woke the loop
+                held_since = max(min(now, st.lanes.earliest_deadline()),
+                                 capped_since)
             if can_go and go:
-                return go if under_cap else "block"
+                held_s = 0.0 if held_since is None else now - held_since
+                return (go if under_cap else "block"), held_s
             st.arrived.clear()
             arr = self._loop.create_task(st.arrived.wait())
             waits = {arr} | set(st.inflight)
@@ -584,6 +623,7 @@ class VerificationFarm:
             await asyncio.wait(waits, timeout=timeout,
                                return_when=asyncio.FIRST_COMPLETED)
             arr.cancel()
+        return None, 0.0
 
     def _promote(self, ent: _Pending, lane: Lane) -> None:
         """Move a still-queued pending entry to a higher-priority lane

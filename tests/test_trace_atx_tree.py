@@ -4,6 +4,7 @@ down to the device flights, and the spans carry the counts the
 benchmark's per-layer readers take (docs/OBSERVABILITY.md)."""
 
 import asyncio
+import time
 
 import pytest
 
@@ -167,3 +168,84 @@ def test_one_request_is_one_tree(wl):
         end = f["ts"] + f["dur"]
         assert any(r["ts"] - SLACK_US <= end
                    <= r["ts"] + r["dur"] + SLACK_US for r in retire)
+
+
+def test_a_merged_post_batch_still_closes_every_tree(wl):
+    """Three clients' requests, the later two gathered behind the first
+    one's flight and sent as ONE batch: each request is still one tree,
+    every ``farm.request`` names its ``farm.batch`` and is listed among
+    that batch's ``members``, and the verdicts are the inline ones."""
+    distinct = list({r.key(): r for r in wl.requests
+                     if isinstance(r, PostRequest)}.values())
+    others = [r for r in wl.requests if not isinstance(r, PostRequest)]
+    sent = [distinct[2 * i:2 * i + 2] + [others[i]] for i in range(3)]
+
+    async def go():
+        server = VerifydServer(post_params=wl.post_params,
+                               post_seed=wl.post_seed, workers=4)
+        farm = server.service.farm
+        farm.ed_verifier, farm.vrf_verifier = wl.ed, wl.vrf
+        real = farm._run_backend
+
+        def slow_post(kind, reqs):  # a flight long enough to arrive in
+            if kind == "post":
+                time.sleep(0.3)
+            return real(kind, reqs)
+
+        farm._run_backend = slow_post
+        clients = []
+        try:
+            port = await server.start()
+            for name in ("alice", "bob", "carol"):
+                clients.append(VerifydClient(f"http://127.0.0.1:{port}",
+                                             name, retry=None))
+                await clients[-1].register()
+            tracing.start(capacity=1 << 14, jax_bridge=False)
+            tasks = []
+            for c, items in zip(clients, sent):
+                tasks.append(asyncio.ensure_future(c.verify(items)))
+                await asyncio.sleep(0.08)
+            got = await asyncio.gather(*tasks)
+            tracing.stop()
+            return got
+        finally:
+            for c in clients:
+                await c.aclose()
+            await server.close()
+
+    got = asyncio.run(go())
+    assert got == [[wl.inline_verify(r) for r in items] for items in sent]
+    doc = tracing.export()
+    tracing.validate(doc)
+    evs = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+    by_id = {e["args"]["id"]: e for e in evs}
+    roots = [e for e in evs if e["name"] == "verifyd.http"]
+    assert len(roots) == 3
+
+    def root_of(e):
+        while "parent" in e["args"]:
+            e = by_id[e["args"]["parent"]]
+        return e
+
+    batches = {e["args"]["id"]: e for e in evs if e["name"] == "farm.batch"}
+    reqs = [e for e in evs if e["name"] == "farm.request"]
+    assert len(reqs) == 9
+    for r in reqs:
+        root = root_of(r)
+        assert root["name"] == "verifyd.http"
+        assert r["args"]["req"] == root["args"]["req"]
+        b = batches[r["args"]["batch"]]
+        assert r["args"]["id"] in b["args"]["members"]
+        assert b["args"]["kind"] == r["args"]["kind"]
+    post = sorted((b for b in batches.values()
+                   if b["args"]["kind"] == "post"), key=lambda e: e["ts"])
+    assert [b["args"]["n"] for b in post] == [2, 4]
+    first, merged = (b["args"] for b in post)
+    assert first["held_ms"] == 0 and merged["held_ms"] > 50
+    assert first["inflight"] == 0 and merged["inflight"] == 0
+    assert len({by_id[m]["args"]["req"] for m in merged["members"]}) == 2
+    # the one verifier call of the merged batch hangs under it, and so
+    # under no single request: two trees share it by `members`
+    calls = [e for e in evs if e["name"] == "post.verify"]
+    assert sorted(c["args"]["parent"] for c in calls) == sorted(
+        b["args"]["id"] for b in post)

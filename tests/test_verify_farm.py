@@ -9,6 +9,7 @@ item — batching is a scheduling change, never a semantic one.
 """
 
 import asyncio
+import collections
 import dataclasses
 import threading
 import time
@@ -19,12 +20,18 @@ from spacemesh_tpu.consensus import malfeasance
 from spacemesh_tpu.core import types
 from spacemesh_tpu.core.signing import Domain, EdSigner, EdVerifier
 from spacemesh_tpu.p2p.pubsub import PubSub
+from spacemesh_tpu.post.prover import Proof
+from spacemesh_tpu.post.verifier import VerifyItem
 from spacemesh_tpu.storage import db as dbmod
 from spacemesh_tpu.storage.cache import AtxCache
+from spacemesh_tpu.utils import metrics, tracing
 from spacemesh_tpu.verify import workload
 from spacemesh_tpu.verify.farm import (
+    KIND_POST,
+    KIND_SIG,
     FarmClosed,
     Lane,
+    PostRequest,
     SigRequest,
     VerificationFarm,
 )
@@ -352,6 +359,315 @@ def test_close_fails_pending_with_farm_closed():
             await farm.submit(_sig_reqs(1, salt=b"z")[0])
 
     asyncio.run(main())
+
+
+# --- one POST batch on the device at a time -------------------------------
+
+
+def _post_reqs(n, salt):
+    """Distinct POST requests for a stubbed backend (never verified)."""
+    return [PostRequest(VerifyItem(
+        proof=Proof(nonce=0, indices=[i], pow_nonce=0, k2=1),
+        challenge=salt.ljust(32, b"\0"), node_id=bytes(32),
+        commitment=bytes(32), scrypt_n=2, total_labels=64))
+        for i in range(n)]
+
+
+class _GatedBackend:
+    """Stand-in for farm._run_backend: records every call in the order
+    the backend took it, holds the first ``gate_first[kind]`` calls of a
+    kind until ``gate`` is set (a flight on the device), sleeps
+    ``sleep_s`` and answers True for every item."""
+
+    def __init__(self, farm, gate_first=None, sleep_s=0.0):
+        self.gate = threading.Event()
+        self.gate_left = dict(gate_first or {})
+        self.sleep_s = sleep_s
+        self.lock = threading.Lock()
+        self.calls = []
+        self.active = collections.Counter()
+        self.peak = collections.Counter()
+        farm._run_backend = self  # type: ignore[method-assign]
+
+    def __call__(self, kind, reqs):
+        with self.lock:
+            self.calls.append((kind, list(reqs)))
+            self.active[kind] += 1
+            self.peak[kind] = max(self.peak[kind], self.active[kind])
+            gated = self.gate_left.get(kind, 0) > 0
+            if gated:
+                self.gate_left[kind] -= 1
+        try:
+            if gated:
+                assert self.gate.wait(30), "test gate never released"
+            if self.sleep_s:
+                time.sleep(self.sleep_s)
+            return [True] * len(reqs)
+        finally:
+            with self.lock:
+                self.active[kind] -= 1
+
+    def of(self, kind):
+        with self.lock:
+            return [reqs for k, reqs in self.calls if k == kind]
+
+
+class _FixedTuner:
+    """The tuner's four hooks with verifyd's answers in the synced cell:
+    a batch is full at ``target`` and anything smaller goes at once."""
+
+    def __init__(self, target):
+        self.target = target
+
+    def note_arrival(self, kind, now):
+        pass
+
+    def observe(self, kind, batch, seconds):
+        pass
+
+    def target_batch(self, kind):
+        return self.target
+
+    def dispatch_now(self, kind, n, oldest_age_s):
+        return True
+
+
+def _submit_all(farm, reqs, lane=Lane.GOSSIP):
+    return [asyncio.ensure_future(farm.submit(r, lane=lane)) for r in reqs]
+
+
+@pytest.mark.parametrize("tuner", [None, _FixedTuner(4)],
+                         ids=["static", "tuned"])
+def test_post_gathers_behind_a_flight_and_leaves_as_one_batch(tuner):
+    """With one POST batch on the device, three further groups stay in
+    the lanes whatever full / deadline / tuner say, and leave together
+    when the flight returns: whole, in lane order, as ONE batch."""
+
+    async def main():
+        farm = VerificationFarm(tuner=tuner)
+        be = _GatedBackend(farm, gate_first={KIND_POST: 1})
+        first = _submit_all(farm, _post_reqs(4, b"g0"))
+        await asyncio.sleep(0.05)  # the first batch is on the device
+        groups = [(_post_reqs(4, b"g1"), Lane.GOSSIP),
+                  (_post_reqs(2, b"g2"), Lane.SYNC),
+                  (_post_reqs(2, b"g3"), Lane.GOSSIP)]
+        tasks = []
+        for reqs, lane in groups:
+            tasks += _submit_all(farm, reqs, lane)
+            await asyncio.sleep(0.03)  # past every lane's deadline
+        assert len(be.of(KIND_POST)) == 1 and not any(
+            t.done() for t in tasks)
+        be.gate.set()
+        got = await asyncio.wait_for(asyncio.gather(*first, *tasks), 10)
+        await farm.aclose()
+        return got, be, groups
+
+    got, be, groups = asyncio.run(main())
+    assert got == [True] * 12
+    calls = be.of(KIND_POST)
+    assert [len(c) for c in calls] == [4, 8] and be.peak[KIND_POST] == 1
+    (g1, _), (g2, _), (g3, _) = groups
+    assert calls[1] == g1 + g3 + g2  # GOSSIP drains before SYNC, FIFO
+
+
+def test_a_gathered_post_batch_is_a_whole_power_of_two():
+    """The backend pads a POST batch to a power of two of items, so the
+    farm takes a whole one and leaves the rest for the next flight: 11
+    gathered go as 8, 2 and 1, in lane order, one at a time. It is what
+    makes a closed loop of four settle at two even batches a round."""
+
+    async def main():
+        farm = VerificationFarm()
+        be = _GatedBackend(farm, gate_first={KIND_POST: 1})
+        first = _submit_all(farm, _post_reqs(4, b"w0"))
+        await asyncio.sleep(0.03)
+        held = _post_reqs(11, b"w1")
+        tasks = _submit_all(farm, held, Lane.SYNC)
+        await asyncio.sleep(0.03)
+        be.gate.set()
+        got = await asyncio.wait_for(asyncio.gather(*first, *tasks), 10)
+        await farm.aclose()
+        return got, be, held
+
+    got, be, held = asyncio.run(main())
+    assert got == [True] * 15 and be.peak[KIND_POST] == 1
+    calls = be.of(KIND_POST)
+    assert [len(c) for c in calls] == [4, 8, 2, 1]
+    assert calls[1] + calls[2] + calls[3] == held
+
+
+def test_sig_batches_still_overlap_beside_a_post_flight():
+    """The cap of one is the device kind's: host kinds run side by side
+    up to max_inflight while a POST batch is out and another is held."""
+
+    async def main():
+        farm = VerificationFarm(max_inflight=3)
+        be = _GatedBackend(farm, gate_first={KIND_POST: 9, KIND_SIG: 9})
+        tasks = _submit_all(farm, _post_reqs(2, b"p0"))
+        await asyncio.sleep(0.03)
+        tasks += _submit_all(farm, _post_reqs(2, b"p1"))  # held
+        for r in _sig_reqs(5, salt=b"ov"):
+            tasks += _submit_all(farm, [r])
+            await asyncio.sleep(0.02)  # each its own deadline batch
+        active = dict(be.active)
+        be.gate.set()
+        got = await asyncio.wait_for(asyncio.gather(*tasks), 10)
+        await farm.aclose()
+        return got, active, be
+
+    got, active, be = asyncio.run(main())
+    assert got == [True] * 9
+    assert active == {KIND_POST: 1, KIND_SIG: 3}
+    assert be.peak[KIND_POST] == 1 and be.peak[KIND_SIG] == 3
+    assert [len(c) for c in be.of(KIND_POST)] == [2, 2]
+
+
+def test_block_lane_post_request_passes_the_device_cap():
+    """The lane contract outranks the merge: a pending BLOCK request
+    takes the queue with it past a flight that has not returned."""
+
+    async def main():
+        farm = VerificationFarm()
+        be = _GatedBackend(farm, gate_first={KIND_POST: 1})
+        first = _submit_all(farm, _post_reqs(1, b"b0"))
+        await asyncio.sleep(0.03)
+        held = _post_reqs(3, b"b1")
+        held_tasks = _submit_all(farm, held, Lane.SYNC)
+        await asyncio.sleep(0.03)
+        assert len(be.of(KIND_POST)) == 1
+        [blk] = _post_reqs(1, b"b2")
+        ok = await asyncio.wait_for(farm.submit(blk, lane=Lane.BLOCK), 5)
+        assert not first[0].done()  # the flight ahead is still out
+        be.gate.set()
+        got = await asyncio.gather(*first, *held_tasks)
+        await farm.aclose()
+        return ok, got, be.of(KIND_POST), blk, held
+
+    ok, got, calls, blk, held = asyncio.run(main())
+    assert ok is True and got == [True] * 4
+    assert calls[1] == [blk] + held
+
+
+@pytest.mark.parametrize("how", ["aclose", "shutdown", "reset_lanes"])
+def test_held_post_requests_fail_typed_like_queued_ones(how):
+    async def main():
+        farm = VerificationFarm()
+        be = _GatedBackend(farm, gate_first={KIND_POST: 1})
+        inflight = _submit_all(farm, _post_reqs(2, b"c0"))
+        await asyncio.sleep(0.03)
+        held_reqs = _post_reqs(3, b"c1")
+        held = _submit_all(farm, held_reqs, Lane.SYNC)
+        await asyncio.sleep(0.03)  # ready (deadline passed), and held
+        closer = None
+        if how == "aclose":
+            closer = asyncio.ensure_future(farm.aclose())
+            await asyncio.sleep(0.02)
+        else:
+            getattr(farm, how)()
+        for t in held:
+            with pytest.raises(FarmClosed):
+                await asyncio.wait_for(t, 5)
+        be.gate.set()  # the flight itself still lands
+        assert await asyncio.gather(*inflight) == [True, True]
+        if closer is not None:
+            await closer
+        if how == "reset_lanes":  # ...and the farm keeps serving
+            assert farm._group.total() == 0
+            assert await asyncio.wait_for(
+                farm.submit(_post_reqs(1, b"c2")[0]), 5) is True
+        await farm.aclose()
+        return be.of(KIND_POST), held_reqs
+
+    calls, held_reqs = asyncio.run(main())
+    assert [len(c) for c in calls][:1] == [2]
+    went = [r for c in calls for r in c]
+    assert not any(r in went for r in held_reqs)  # the held never went
+
+
+@pytest.mark.parametrize("tuner", [None, _FixedTuner(8)],
+                         ids=["static", "tuned"])
+def test_closed_loop_of_four_settles_at_two_batches_a_round(tuner):
+    """Four clients, each sending its next request of 8 proofs when the
+    last has answered, over a backend that takes 60 ms whatever the
+    width: the first to arrive goes alone, of the three that gather
+    behind it two leave together (a power of two) and the third waits
+    for the first one's next request, and from then on one pair's
+    requests gather while the other's program runs, so a round of four
+    requests is two even batches (it was four, one after another on the
+    device)."""
+    rounds, per_request, clients = 6, 8, 4
+
+    async def main():
+        farm = VerificationFarm(tuner=tuner)
+        be = _GatedBackend(farm, sleep_s=0.06)
+
+        async def client(c):
+            await asyncio.sleep(0.01 * c)  # as over HTTP: never one tick
+            for i in range(rounds):
+                got = await asyncio.gather(*(
+                    farm.submit(r, lane=Lane.SYNC) for r in
+                    _post_reqs(per_request, b"cl%d-%d" % (c, i))))
+                assert got == [True] * per_request
+
+        t0 = time.perf_counter()
+        await asyncio.wait_for(
+            asyncio.gather(*(client(c) for c in range(clients))), 30)
+        wall = time.perf_counter() - t0
+        await farm.aclose()
+        return be, wall
+
+    be, wall = asyncio.run(main())
+    sizes = [len(c) for c in be.of(KIND_POST)]
+    assert sum(sizes) == rounds * clients * per_request
+    assert all(n % per_request == 0 for n in sizes)  # whole requests
+    assert be.peak[KIND_POST] == 1
+    # two a round and the odd one at the start (slack: a stalled loop
+    # can split a group once or twice); one batch a request was 24
+    assert 2 * rounds <= len(sizes) <= 2 * rounds + 3, sizes
+    assert 3 * per_request not in sizes, sizes
+    assert sizes.count(2 * per_request) >= len(sizes) - 4, sizes
+    assert wall < 0.06 * (2 * rounds + 4) + 1.0, wall
+
+
+def test_held_ms_and_the_held_counter_read_what_happened():
+    def held_count(kind):
+        return metrics.verify_farm_batches_held.sample().get(
+            (("kind", kind),), 0.0)
+
+    async def main():
+        farm = VerificationFarm()
+        be = _GatedBackend(farm, gate_first={KIND_POST: 1})
+        tasks = _submit_all(farm, _post_reqs(2, b"h0"))
+        await asyncio.sleep(0.03)
+        tasks += _submit_all(farm, _post_reqs(4, b"h1"))
+        tasks += _submit_all(farm, _sig_reqs(2, salt=b"h"))
+        await asyncio.sleep(0.08)  # ready after 5 ms, held ~75 more
+        be.gate.set()
+        await asyncio.wait_for(asyncio.gather(*tasks), 10)
+        [blk] = _post_reqs(1, b"h2")  # nothing in flight: goes at once
+        await farm.submit(blk, lane=Lane.BLOCK)
+        await farm.aclose()
+
+    before = {k: held_count(k) for k in (KIND_POST, KIND_SIG)}
+    tracing.stop()
+    tracing.start(capacity=1 << 12, jax_bridge=False)
+    try:
+        asyncio.run(main())
+    finally:
+        tracing.stop()
+    assert held_count(KIND_POST) == before[KIND_POST] + 1
+    assert held_count(KIND_SIG) == before[KIND_SIG]
+    batches = sorted((e for e in tracing.export()["traceEvents"]
+                      if e["ph"] == "X" and e["name"] == "farm.batch"),
+                     key=lambda e: e["ts"])
+    post = [b["args"] for b in batches if b["args"]["kind"] == KIND_POST]
+    assert [a["n"] for a in post] == [2, 4, 1]
+    assert [a["inflight"] for a in post] == [0, 0, 0]
+    assert post[0]["held_ms"] == 0 and post[2]["held_ms"] == 0
+    assert 40 <= post[1]["held_ms"] <= 5000, post[1]
+    assert post[1]["reason"] == "idle"  # the clause when it was let go
+    (sig,) = [b["args"] for b in batches if b["args"]["kind"] == KIND_SIG]
+    assert sig["held_ms"] == 0 and sig["n"] == 2
 
 
 # --- handler integration: farm path == inline path ------------------------
