@@ -87,6 +87,48 @@ def shape_bucket(b: int) -> int:
     return 1 << (b - 1).bit_length()
 
 
+# ROMix's V may take this share of a device's memory; the rest is for a
+# program's arguments, outputs and other temporaries (under 1% of V) and
+# for whatever else the process keeps there (k2pow batches, the next
+# tile's inputs)
+V_MEMORY_SHARE = 0.75
+# what a platform that reports no memory (the CPU) is taken to have: a
+# v5e chip's 16 GiB
+UNREPORTED_DEVICE_BYTES = 16 << 30
+
+
+@functools.lru_cache(maxsize=None)
+def _device_bytes(device) -> int:
+    stats = device.memory_stats() or {}     # None on the CPU
+    return int(stats.get("bytes_limit") or UNREPORTED_DEVICE_BYTES)
+
+
+def lane_ceiling(n: int, devices=None) -> int:
+    """The widest label program ONE device runs, in lanes: the largest
+    power of two whose ROMix scratch (``128 * r * N`` bytes a lane, r=1)
+    fits in :data:`V_MEMORY_SHARE` of the device's memory, as its
+    ``memory_stats()`` reports it (the smallest of ``devices``; default:
+    the default device). A lane-sharded batch holds that many lanes on
+    EACH chip of its mesh. At N=8192 on a 16 GB v5e chip: 8,192 lanes,
+    8 GiB of V. Nothing sets it: a batch wider than this runs as lane
+    tiles (post/verifier.py).
+
+    What the rule does not see: it is static. It reads the device's
+    LIMIT, not what is free (``bytes_in_use``), so a process that holds
+    gigabytes there already (an init running beside the farm) can still
+    be refused a full tile; the share was checked on one kind of chip
+    (any limit from 10.7 to 21.3 GiB gives 8,192 lanes at N=8192); and
+    a platform that reports nothing counts as a v5e, so a CPU run tiles
+    where a v5e would, whatever the host's memory."""
+    devices = list(devices) if devices is not None else jax.devices()[:1]
+    room = V_MEMORY_SHARE * min(_device_bytes(d) for d in devices)
+    lanes = int(room // (128 * n))
+    if lanes < 1:
+        raise ValueError(f"scrypt n={n}: one lane's V ({128 * n} bytes) "
+                         "does not fit the device")
+    return 1 << (lanes.bit_length() - 1)
+
+
 def _rotl(x, n: int):
     return (x << jnp.uint32(n)) | (x >> jnp.uint32(32 - n))
 

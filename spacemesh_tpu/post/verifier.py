@@ -3,9 +3,12 @@
 The PostVerifier equivalent (reference activation/post_verifier.go:122-405
 runs a CGo worker pool; validation semantics activation/validation.go:182).
 TPU-first design: verification of MANY proofs is one batched label
-recompute — all (proof, index) pairs are flattened into a single scrypt
-batch, then a single proving-hash batch — instead of a per-proof worker
-pool. The K3 spot-check subset (reference validation.go:206 PostSubset)
+recompute — all (proof, index) pairs are flattened into one lane batch
+that goes to the device as ONE flight (one upload, one blocking fetch):
+a label program and a proving-hash program, or, for a batch wider than
+the device's lane ceiling (ops/scrypt.lane_ceiling: 8,192 lanes of
+N=8192 on a 16 GB chip), one such pair per lane tile, enqueued back to
+back — instead of a per-proof worker pool. The K3 spot-check subset (reference validation.go:206 PostSubset)
 subsamples each proof's indices deterministically from a verifier seed.
 
 Also verifies the k2pow witness (ops/pow.py replaces RandomX behind the
@@ -25,7 +28,7 @@ from ..ops import pow as k2pow
 from ..ops import proving, scrypt
 from ..parallel import mesh as pmesh
 from ..parallel import topology
-from ..utils import tracing
+from ..utils import metrics, tracing
 from .prover import Proof, ProofParams
 
 
@@ -62,9 +65,13 @@ def verify_many(items: list[VerifyItem], params: ProofParams | None = None,
                 seed: bytes | None = None) -> list[bool]:
     """Verify a batch of proofs; returns per-proof validity.
 
-    One scrypt recompute + one proving-hash pass over the union of all
+    One device flight per ``scrypt_n`` group over the union of all
     spot-checked indices — the TPU replacement for the reference's
-    worker-pool verify (proofs are lanes, not queue items).
+    worker-pool verify (proofs are lanes, not queue items). A flight is
+    one label program and one proving-hash program; a group with more
+    lanes than the device's ceiling (ops/scrypt.lane_ceiling) runs as
+    several, one per lane tile, in the same flight: a batch of any size
+    runs on the chip.
 
     ``seed`` keys the K3 spot-check subset; by default a fresh random seed
     is drawn per call so provers cannot predict which indices get checked.
@@ -76,8 +83,8 @@ def verify_many(items: list[VerifyItem], params: ProofParams | None = None,
     (a proof object repeated in ``items`` is the farm's power-of-two
     padding, not a proof), those ``host_rejected``, the ``lanes_valid``
     K3 indices they send to the device, the ``lanes`` dispatched after
-    both paddings, blocking device->host ``syncs``, ``h2d_bytes`` and
-    ``d2h_bytes``.
+    both paddings, the lane ``tiles`` (label programs) they went as,
+    blocking device->host ``syncs``, ``h2d_bytes`` and ``d2h_bytes``.
     """
     import os
 
@@ -86,10 +93,29 @@ def verify_many(items: list[VerifyItem], params: ProofParams | None = None,
         seed = os.urandom(32)
     # the span holds this dict: filled in as the call goes
     tr = ({"proofs": 0, "host_rejected": 0, "lanes_valid": 0, "lanes": 0,
-           "syncs": 0, "h2d_bytes": 0, "d2h_bytes": 0}
+           "tiles": 0, "syncs": 0, "h2d_bytes": 0, "d2h_bytes": 0}
           if tracing.is_enabled() else None)
     with tracing.span("post.verify", tr):
         return _verify_many(items, p, seed, tr)
+
+
+def _lane_tiles(b: int, n: int) -> list[tuple[int, int]]:
+    """``(first lane, width)`` of the label programs a group of ``b``
+    lanes at scrypt ``n`` runs as: full tiles at the lane ceiling of
+    where such a batch runs (ops/scrypt.lane_ceiling lanes on each chip
+    of its mesh), then what remains in its power-of-two shape bucket. So
+    the executable population stays the buckets up to the ceiling, and
+    at ``b`` up to the ceiling there is one tile: the group's bucket."""
+    mesh = pmesh.auto_mesh(scrypt.shape_bucket(b))
+    if mesh is None:
+        ceiling = scrypt.lane_ceiling(n)
+    else:
+        ceiling = mesh.size * scrypt.lane_ceiling(n, mesh.devices.flat)
+    tiles = [(at, ceiling) for at in range(0, b - ceiling + 1, ceiling)]
+    rest = b % ceiling
+    if rest:
+        tiles.append((b - rest, scrypt.shape_bucket(rest)))
+    return tiles
 
 
 def _verify_many(items: list[VerifyItem], p: ProofParams, seed: bytes,
@@ -127,7 +153,7 @@ def _verify_many(items: list[VerifyItem], p: ProofParams, seed: bytes,
     if not flat_idx:
         return results
 
-    # 2) one batched label recompute + proving-hash pass over ALL proofs.
+    # 2) one flight of label recompute + proving hash over ALL proofs.
     # scrypt_n must be uniform per compiled program; group by n (usually 1).
     with tracing.span("post.verify.pack"):
         owners = np.array(flat_owner)
@@ -144,47 +170,62 @@ def _verify_many(items: list[VerifyItem], p: ProofParams, seed: bytes,
     for n in sorted({items[o].scrypt_n for o in flat_owner}):
         with tracing.span("post.verify.pack"):
             sel = np.array([items[o].scrypt_n == n for o in flat_owner])
-            # pad the flat batch to its power-of-two shape bucket HERE, in
-            # numpy (pad lanes repeat the last one, trimmed after the
-            # fetch): the device gets one bucket-sized batch, so one
-            # executable of each program serves every occupancy of the
-            # bucket and no eager device op pads or trims
             b = int(sel.sum())
-            bb = scrypt.shape_bucket(b)
             lo, hi = scrypt.split_indices(idx[sel])
             cw8 = commits[sel].view(">u4").astype(np.uint32).T  # (8, b)
-            cw8, chal_b, nonce_b, lo, hi = (
-                scrypt.pad_lanes(a, bb - b)
-                for a in (cw8, chals[:, sel], nonces[sel], lo, hi))
-            # the verify farm's batch recompute is a label batch like any
-            # other, so it shards like one (parallel/mesh.py auto_mesh).
-            # Placement is the only thing a mesh changes.
-            mesh, where = pmesh.auto_mesh(bb), None
-            if mesh is not None:
-                lay = topology.get().layouts_for(mesh)
-                where = [lay.lane, lay.lane, lay.batch, lay.batch, lay.batch]
-            host = [cw8, chal_b, nonce_b, lo, hi]
-            h2d = sum(a.nbytes for a in host)
+            group = (cw8, chals[:, sel], nonces[sel], lo, hi)
+            # cut the flat batch into lane tiles and pad each to its
+            # width HERE, in numpy (pad lanes repeat the last one,
+            # trimmed after the fetch): the device gets bucket-sized
+            # batches, so one executable of each program serves every
+            # occupancy of a bucket and no eager device op pads or trims
+            tiles = _lane_tiles(b, n)
+            host, where = [], []
+            for at, width in tiles:
+                take = min(width, b - at)
+                host.append([scrypt.pad_lanes(a[..., at:at + take],
+                                              width - take) for a in group])
+                # a tile is a label batch like any other, so it shards
+                # like one (parallel/mesh.py auto_mesh). Placement is
+                # the only thing a mesh changes.
+                mesh = pmesh.auto_mesh(width)
+                if mesh is None:
+                    where.append(None)
+                else:
+                    lay = topology.get().layouts_for(mesh)
+                    where.append([lay.lane, lay.lane, lay.batch, lay.batch,
+                                  lay.batch])
+            h2d = sum(a.nbytes for tile in host for a in tile)
+            bb = sum(width for _at, width in tiles)
         with tracing.span("romix.upload",
                           {"bytes": h2d} if tr is not None else None):
-            cw8, chal_b, nonce_b, lo, hi = jax.device_put(host, where)
-        # one flight: label program, endianness flip and proving hash are
-        # enqueued back to back and only the (bb,) hash values come back.
-        # The label pipeline emits BE word groups, the proving hash eats
-        # LE; the words never leave the device in between.
+            dev = jax.device_put(host, where)
+        # one flight: per tile the label program, the endianness flip
+        # and the proving hash are enqueued back to back, tile after
+        # tile, and only the hash values come back, in one fetch after
+        # the last enqueue. The label pipeline emits BE word groups, the
+        # proving hash eats LE; the words never leave the device in
+        # between.
         t0 = time.perf_counter_ns()
-        vals = np.asarray(proving.proving_hash_jit(
-            chal_b, nonce_b, lo, hi, scrypt.words_to_le(
-                scrypt.scrypt_labels_jit(cw8, lo, hi, n=n))))
+        out = []
+        for (cw8, chal_b, nonce_b, lo, hi), (_at, width) in zip(dev, tiles):
+            out.append(proving.proving_hash_jit(
+                chal_b, nonce_b, lo, hi, scrypt.words_to_le(
+                    scrypt.scrypt_labels_jit(cw8, lo, hi, n=n))))
+            metrics.post_verify_label_programs.inc(lanes=width)
+        vals = jax.device_get(out)
         if tr is not None:
             tracing.interval("device.flight", t0,
                              {"program": "labels_proving", "lanes": bb,
-                              "d2h_bytes": vals.nbytes})
+                              "tiles": len(out), "d2h_bytes": 4 * bb})
             tr["lanes"] += bb
+            tr["tiles"] += len(out)
             tr["syncs"] += 1
             tr["h2d_bytes"] += h2d
-            tr["d2h_bytes"] += vals.nbytes
-        values[sel] = vals[:b]
+            tr["d2h_bytes"] += 4 * bb
+        # each tile's own lanes, its padding dropped
+        values[sel] = np.concatenate(
+            [v[:b - at] for v, (at, _width) in zip(vals, tiles)])
 
     # 3) threshold check per item
     with tracing.span("post.verify.threshold"):

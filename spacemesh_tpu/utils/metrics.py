@@ -288,6 +288,15 @@ tortoise_mode_gauge = REGISTRY.gauge(
     "tortoise_mode", "0 verifying, 1 full (reference tortoise/metrics.go)")
 applied_gauge = REGISTRY.gauge("mesh_last_applied_layer", "applied frontier")
 
+# POST verification (post/verifier.py): label programs enqueued by
+# verify_many, one per lane tile of a batch (label: lanes, the program's
+# width over ALL the chips it is sharded across: a power of two up to
+# ops/scrypt.lane_ceiling on one chip, up to mesh size x that on a mesh)
+post_verify_label_programs = REGISTRY.counter(
+    "post_verify_label_programs_total",
+    "label programs enqueued by POST verification (label: lanes, the "
+    "program's whole width, mesh-wide where it is sharded)")
+
 # POST init streaming pipeline (post/initializer.py). Stage seconds carry a
 # stage label (dispatch/fetch/write/stall) so an operator can see where a
 # slow init is actually spending its time without a full profile.

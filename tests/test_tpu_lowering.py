@@ -73,6 +73,16 @@ for b in (32, 64, 128, 256):
 b = 8192
 build(f"labels_{b}", scrypt._labels_min_fused, sds((8,)), sds((b,)),
       sds((b,)), sds((scrypt.VRF_CARRY_WORDS,)), n=N)
+# the verifier's widest program: a full lane tile (ops/scrypt.lane_ceiling
+# at N=8192 on a v5e), per-lane commitments; and the doubled bucket a
+# 256-proof K3=37 batch would ask for untiled, which the chip cannot hold
+build(f"labels_verify_{b}", scrypt._labels_fused, sds((8, b)), sds((b,)),
+      sds((b,)), n=N)
+try:
+    build(f"labels_verify_{2 * b}", scrypt._labels_fused, sds((8, 2 * b)),
+          sds((2 * b,)), sds((2 * b,)), n=N)
+except Exception as e:
+    out[f"labels_verify_{2 * b}"] = {"refused": str(e)[:300]}
 b = 32768
 build(f"labels_{b}x4", scrypt._labels_min_fused,
       sds((8,), sharding=everywhere), sds((b,), sharding=lanes),
@@ -179,3 +189,15 @@ def test_label_program_keeps_v_for_the_whole_batch(lowered, lanes, chips):
     temp = lowered[f"labels_{lanes}" + (f"x{chips}" if chips > 1 else "")
                    ]["temp"]
     assert 128 * 8192 * lanes // chips <= temp < 16e9, temp
+
+
+def test_the_verifiers_full_lane_tile_compiles_and_its_double_does_not(
+        lowered):
+    """``_labels_fused`` at 8,192 lanes of N=8192 (the lane ceiling on a
+    v5e: 8 GiB of V) compiles for the chip; at 16,384 lanes (what 256
+    K3=37 proofs pad to as ONE program) the chip's compiler runs out of
+    HBM, which is why post/verifier.py tiles."""
+    temp = lowered["labels_verify_8192"]["temp"]
+    assert 8 << 30 <= temp < 16e9, temp
+    refused = lowered["labels_verify_16384"].get("refused", "")
+    assert "hbm" in refused.lower(), lowered["labels_verify_16384"]
