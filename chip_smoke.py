@@ -235,12 +235,14 @@ def run_init(dep: Deployment, data_dir: Path, node_id: bytes,
                       "hi_word_indices": list(HI_WORD_INDICES)}
 
 
-def _check_scan_step(prover, step, challenge: bytes) -> None:
+def _check_scan_step(prover, step, mesh, challenge: bytes) -> None:
     """``step`` (the window step the Prover binds by default: one
-    program over every nonce group of a pass, its lane indices made on
-    the device) against ``proving.prove_scan_step_jit`` group by group
-    on one real batch of the store (a ragged tail and an index carry
-    past 2^32 included)."""
+    program over every nonce group of a pass and every batch of a
+    flight, its lane indices made on the device) against
+    ``proving.prove_scan_step_jit`` sub-batch by sub-batch and group by
+    group on one real flight of the store: the last sub-batch empty and
+    the one before it ragged where a flight has more than one, and the
+    index carry past 2^32 falling inside the flight."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -248,37 +250,46 @@ def _check_scan_step(prover, step, challenge: bytes) -> None:
 
     b, ng, cap = prover.batch_labels, prover.nonce_group, prover.params.k2
     groups = prover.window_groups
-    count = min(b, prover.meta.total_labels) - 5
-    labels = np.zeros((b, scrypt.LABEL_BYTES), np.uint8)
+    fb = prover.flight_batches(mesh)
+    f = fb * b
+    count = min(f, prover.meta.total_labels) - 5 - (b if fb > 1 else 0)
+    labels = np.zeros((f, scrypt.LABEL_BYTES), np.uint8)
     labels[:count] = np.frombuffer(
         prover.store.read_labels(0, count), np.uint8).reshape(count, -1)
     prover.store.close()
-    start = 2**32 - 7
-    lo, hi = scrypt.split_indices(np.arange(start, start + b,
-                                            dtype=np.uint64))
+    start = 2**32 - f // 2 - 7
     cw = jnp.asarray(proving.challenge_words(challenge))
-    lw = jnp.asarray(scrypt.labels_to_words(labels))
-    # ~64x the proof threshold so every nonce row carries hits
-    thr = jnp.uint32(proving.threshold_u32(prover.params.k1 * 64,
+    lw = scrypt.labels_to_words(labels)
+    # ~64x the proof threshold over a flight, so every nonce row carries
+    # hits and the K2 slots fill over several sub-batches
+    thr = jnp.uint32(proving.threshold_u32(prover.params.k1 * 64 // fb,
                                            prover.meta.total_labels))
-    words = [count, start & 0xFFFFFFFF, start >> 32]
     bases = 16 + ng * np.arange(groups)
     got = [np.asarray(x) for x in step(
-        cw, jnp.asarray(bases, jnp.uint32), lw,
-        jnp.asarray(words, jnp.uint32), thr,
-        *proving.init_hit_state(groups * ng, cap))]
-    per_group = [proving.prove_scan_step_jit(
-        cw, jnp.uint32(base), jnp.asarray(lo), jnp.asarray(hi), lw, thr,
-        *proving.init_hit_state(ng, cap), *map(jnp.uint32, words),
-        n_nonces=ng, max_hits=cap) for base in bases]
-    # counts and batch counts stack by row; the carry is (2, rows, cap)
-    want = [np.concatenate([np.asarray(o[i]) for o in per_group], axis=ax)
-            for i, ax in enumerate((0, 0, 1))]
+        cw, jnp.asarray(bases, jnp.uint32), jnp.asarray(lw),
+        jnp.asarray([count, start & 0xFFFFFFFF, start >> 32], jnp.uint32),
+        thr, *proving.init_hit_state(groups * ng, cap))]
+    state = [proving.init_hit_state(ng, cap) for _ in bases]
+    flight_counts = np.zeros(groups * ng, np.int64)
+    for g in range(-(-count // b)):
+        at = start + g * b
+        lo, hi = scrypt.split_indices(np.arange(at, at + b, dtype=np.uint64))
+        outs = [proving.prove_scan_step_jit(
+            cw, jnp.uint32(base), jnp.asarray(lo), jnp.asarray(hi),
+            jnp.asarray(lw[:, g * b:(g + 1) * b]), thr, *st,
+            jnp.uint32(min(b, count - g * b)), jnp.uint32(at & 0xFFFFFFFF),
+            jnp.uint32(at >> 32), n_nonces=ng, max_hits=cap)
+            for base, st in zip(bases, state)]
+        state = [(o[0], o[2]) for o in outs]
+        flight_counts += np.concatenate([np.asarray(o[1]) for o in outs])
+    # counts stack by row; the carry is (2, rows, cap)
+    want = [np.concatenate([np.asarray(c) for c, _ in state]), flight_counts,
+            np.concatenate([np.asarray(c) for _, c in state], axis=1)]
     check(int(want[1].min()) > 0, "scan-step check saw an empty row")
     for g, w in zip(got, want):
         check(np.array_equal(g, w),
               "default window step != proving.prove_scan_step_jit "
-              "group by group")
+              "sub-batch by sub-batch, group by group")
 
 
 def run_prove(dep: Deployment, data_dir: Path, challenge: bytes, params,
@@ -303,13 +314,14 @@ def run_prove(dep: Deployment, data_dir: Path, challenge: bytes, params,
     if impl == "pallas":
         # on a mesh the default step IS the XLA one, sharded: there is
         # nothing to compare, and the report carries no such key
-        _check_scan_step(prover, step, challenge)
+        _check_scan_step(prover, step, mesh, challenge)
         checked["scan_step_vs_xla"] = True
     doc.update(nonce=proof.nonce, pow_nonce=proof.pow_nonce,
                stats=stats,
                decision={"impl": impl,
                          "devices": mesh.size if mesh is not None else 1,
                          "batch": prover.batch_labels,
+                         "flight_batches": prover.flight_batches(mesh),
                          "nonce_group": prover.nonce_group,
                          "window_groups": prover.window_groups,
                          "source": "platform"},

@@ -227,51 +227,106 @@ def lane_indices(b: int, start_lo, start_hi, sharding=None):
 
 
 def scan_window(group_step, challenge_words, bases, label_words, meta,
-                threshold, hit_counts, hit_carry, *, lane_sharding=None):
-    """The window step both backends share: ONE program per label batch
-    that runs ``group_step`` (a per-group scan step, its ``n_nonces`` and
-    ``max_hits`` bound) once per base in ``bases`` over the same batch.
+                threshold, hit_counts, hit_carry, *, batch: int | None = None,
+                lane_sharding=None):
+    """The window step both backends share: ONE program per FLIGHT of
+    label batches that runs ``group_step`` (a per-group scan step, its
+    ``n_nonces`` and ``max_hits`` bound) once per base in ``bases`` over
+    each batch of the flight.
 
-    ``meta`` is the batch's three u32 words ``[valid, start_lo,
-    start_hi]``, uploaded beside ``label_words`` (4, B); the lane indices
-    are made here (:func:`lane_indices`), not sent. The hit state is one
-    pair for the whole window, group-major: ``(groups * n_nonces,)``
-    counts and ``(2, groups * n_nonces, cap)`` carry; row ``g * n_nonces
-    + k`` is nonce ``bases[g] + k``. ``group_step`` is the cached inner
-    jit, so its body is traced and lowered once however many groups the
-    window has. Returns (hit_counts', batch_counts, hit_carry') like the
-    per-group step, each over all the window's nonces."""
+    ``meta`` is the flight's three u32 words ``[valid, start_lo,
+    start_hi]``, uploaded beside ``label_words`` (4, lanes); the lane
+    indices are made here (:func:`lane_indices`), not sent. ``batch`` is
+    the static width of ONE scan step (kernel + compaction epilogue).
+    Label words no wider than it (or ``batch`` None) are one step.
+    Wider ones are a flight: the same step
+    runs inside one ROLLED ``lax.fori_loop`` over sub-batches ``g = 0 ..
+    ceil(valid / batch) - 1`` (a dynamic trip count: a ragged last flight
+    runs only the sub-batches that hold labels), sub-batch ``g`` taking
+    lanes ``[g * batch, (g + 1) * batch)`` by a dynamic slice, ``valid_g =
+    clip(valid - g * batch, 0, batch)`` and ``start_g = start + g *
+    batch`` with the carry into the hi word. Rolled, so ``group_step``
+    is lowered once per group whatever the flight holds; it is traced
+    once too, before the loop, in the program's own trace.
+
+    The hit state is one pair for the whole window, group-major:
+    ``(groups * n_nonces,)`` counts and ``(2, groups * n_nonces, cap)``
+    carry; row ``g * n_nonces + k`` is nonce ``bases[g] + k``.
+    ``group_step`` is the cached inner jit, so its body is traced and
+    lowered once however many groups the window has. Returns
+    (hit_counts', batch_counts, hit_carry') like the per-group step, each
+    over all the window's nonces, ``batch_counts`` summed over the
+    flight's sub-batches."""
     groups = bases.shape[0]
     ng = hit_counts.shape[0] // groups
+    lanes = label_words.shape[1]
     valid, start_lo, start_hi = meta[0], meta[1], meta[2]
-    idx_lo, idx_hi = lane_indices(label_words.shape[1], start_lo, start_hi,
-                                  lane_sharding)
-    outs = [group_step(challenge_words, bases[g], idx_lo, idx_hi,
-                       label_words, threshold,
-                       hit_counts[g * ng:(g + 1) * ng],
-                       hit_carry[:, g * ng:(g + 1) * ng],
-                       valid, start_lo, start_hi)
-            for g in range(groups)]
-    counts, batch_counts, carry = zip(*outs)
-    return (jnp.concatenate(counts), jnp.concatenate(batch_counts),
-            jnp.concatenate(carry, axis=1))
+
+    def one_batch(words, valid, start_lo, start_hi, hit_counts, hit_carry):
+        idx_lo, idx_hi = lane_indices(words.shape[1], start_lo, start_hi,
+                                      lane_sharding)
+        outs = [group_step(challenge_words, bases[g], idx_lo, idx_hi,
+                           words, threshold,
+                           hit_counts[g * ng:(g + 1) * ng],
+                           hit_carry[:, g * ng:(g + 1) * ng],
+                           valid, start_lo, start_hi)
+                for g in range(groups)]
+        counts, batch_counts, carry = zip(*outs)
+        return (jnp.concatenate(counts), jnp.concatenate(batch_counts),
+                jnp.concatenate(carry, axis=1))
+
+    if batch is None or lanes <= batch:
+        return one_batch(label_words, valid, start_lo, start_hi, hit_counts,
+                         hit_carry)
+    if lanes % batch:
+        raise ValueError(f"flight of {lanes} lanes is not a whole number "
+                         f"of {batch}-lane scan steps")
+
+    def sub_batch(g, state):
+        hit_counts, flight_counts, hit_carry = state
+        off = (g * batch).astype(jnp.uint32)
+        lo = start_lo + off
+        hit_counts, batch_counts, hit_carry = one_batch(
+            jax.lax.dynamic_slice_in_dim(label_words, g * batch, batch, 1),
+            jnp.minimum(valid - off, jnp.uint32(batch)),
+            lo, start_hi + (lo < off).astype(jnp.uint32),
+            hit_counts, hit_carry)
+        return hit_counts, flight_counts + batch_counts, hit_carry
+
+    # Trace ``group_step`` HERE, in the program's own trace, and drop the
+    # result (dead code: the compiler removes it): the loop body's calls
+    # then find it traced. Traced for the first time inside the body's
+    # nested trace, the Pallas kernel's ~10k jnp ops cost 10-49 s of
+    # Python on a v5e's host where they cost 2.9 s here (PERF.md
+    # section 6, PR 32: measured, not explained).
+    one_batch(label_words[:, :batch], jnp.minimum(valid, jnp.uint32(batch)),
+              start_lo, start_hi, hit_counts, hit_carry)
+
+    # g < steps implies g * batch < valid, so valid - off never wraps
+    steps = (valid + jnp.uint32(batch - 1)) // jnp.uint32(batch)
+    return jax.lax.fori_loop(
+        jnp.int32(0), steps.astype(jnp.int32), sub_batch,
+        (hit_counts, jnp.zeros_like(hit_counts), hit_carry))
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("n_nonces", "max_hits", "lane_sharding"),
+                   static_argnames=("n_nonces", "max_hits", "batch",
+                                    "lane_sharding"),
                    donate_argnums=(5, 6))
 def prove_scan_step_window(challenge_words, bases, label_words, meta,
                            threshold, hit_counts, hit_carry, *,
-                           n_nonces: int, max_hits: int, lane_sharding=None):
+                           n_nonces: int, max_hits: int,
+                           batch: int | None = None, lane_sharding=None):
     """One pipelined prove step over a whole nonce window: every group of
     ``bases`` through :func:`prove_scan_step_jit`'s body in ONE program
-    (:func:`scan_window`), so a batch is one upload, one program call and
-    one ``(groups * n_nonces,)`` count vector back."""
+    (:func:`scan_window`) over a flight of ``batch``-lane scan steps (one
+    step where ``label_words`` is no wider), so a flight is one upload,
+    one program call and one ``(groups * n_nonces,)`` count vector back."""
     return scan_window(
         functools.partial(prove_scan_step_jit, n_nonces=n_nonces,
                           max_hits=max_hits),
         challenge_words, bases, label_words, meta, threshold, hit_counts,
-        hit_carry, lane_sharding=lane_sharding)
+        hit_carry, batch=batch, lane_sharding=lane_sharding)
 
 
 def init_hit_state(n_nonces: int, cap: int):

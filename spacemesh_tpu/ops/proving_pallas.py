@@ -25,8 +25,10 @@ mask would be 4-16x the bytes); the surrounding jit unpacks it and
 ``prove_scan_step_pallas`` runs the same compaction epilogue as the XLA
 step (ops/proving.py compact_and_merge), so the mask never crosses
 PCIe. A prove session runs ``prove_scan_step_window_pallas``: that step
-once per nonce group of the pass, in one program over one uploaded batch,
-and the only per-batch D2H is its one count vector.
+once per nonce group of the pass over each batch of one uploaded FLIGHT
+(up to eight batches, post/prover.py FLIGHT_BATCHES), in one program
+whose loop over the flight's batches is rolled, and the only D2H of a
+flight is its one count vector.
 
 Grid: lane tiles of LANE_TILE. ``interpret=True`` runs the kernel on CPU
 (the test path); on TPU the same call compiles via Mosaic.
@@ -166,20 +168,24 @@ def prove_scan_step_pallas(challenge_words, nonce_base, idx_lo, idx_hi,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("n_nonces", "max_hits", "interpret"),
+                   static_argnames=("n_nonces", "max_hits", "batch",
+                                    "interpret"),
                    donate_argnums=(5, 6))
 def prove_scan_step_window_pallas(challenge_words, bases, label_words, meta,
                                   threshold, hit_counts, hit_carry, *,
                                   n_nonces: int, max_hits: int,
+                                  batch: int | None = None,
                                   interpret: bool = False):
     """Pallas-backed twin of ops.proving.prove_scan_step_window: the
-    kernel runs once per group of ``bases`` (``n_nonces`` each), all in
-    one program over one uploaded batch."""
+    kernel runs once per group of ``bases`` (``n_nonces`` each) over each
+    ``batch``-lane scan step of the uploaded flight, all in one program
+    (the steps of a flight in one rolled loop: four kernel custom-calls
+    whatever it holds)."""
     return proving.scan_window(
         functools.partial(prove_scan_step_pallas, n_nonces=n_nonces,
                           max_hits=max_hits, interpret=interpret),
         challenge_words, bases, label_words, meta, threshold, hit_counts,
-        hit_carry)
+        hit_carry, batch=batch)
 
 
 def proving_scan(challenge: bytes, nonce_base: int, indices, labels: np.ndarray,

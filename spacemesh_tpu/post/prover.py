@@ -9,21 +9,29 @@ the init side's (post/initializer.py):
 
   read      — a bounded background reader pool (post/data.py LabelReader)
               prefetches label batches while the device scans;
-  dispatch  — up to K batches in flight; a batch crosses to the device
-              ONCE: one ``jax.device_put`` of its label words and its
-              three start/count words (16 B a label: the program makes
-              its own lane indices), then one compiled program, the
-              window step (``prove_scan_step_window`` /
-              ``prove_scan_step_window_pallas``), that scans every nonce
-              group of the pass, compacts hits on device and merges them
-              into ONE *donated* running hit state — ragged tails are
-              padded to the full batch shape so one shape compiles per
-              pass;
-  retire    — the only per-batch D2H is ONE (window_groups * nonce_group,)
-              count vector, its copy started right after the enqueue
-              (``copy_to_host_async``) so that it has landed by the time
-              the batch retires, ``inflight - 1`` batches later; the
-              packed (nonce, index) hit pairs are fetched once per pass.
+  dispatch  — the unit that crosses the host-device boundary is a
+              FLIGHT of up to ``FLIGHT_BATCHES`` = 8 consecutive batches
+              (131,072 labels at the default batch), up to K flights in
+              flight; a flight crosses to the device ONCE: one
+              ``jax.device_put`` of its label words and its three
+              start/count words (16 B a label: the program makes its own
+              lane indices), then one compiled program, the window step
+              (``prove_scan_step_window`` /
+              ``prove_scan_step_window_pallas``), whose ROLLED loop runs
+              the flight's scan steps one ``batch_labels`` wide each:
+              every nonce group of the pass, hits compacted on device
+              and merged into ONE *donated* running hit state. A ragged
+              last flight is padded to the flight shape and runs only
+              the steps that hold labels, so one shape compiles per
+              pass. A store smaller than a flight gets the power of two
+              of batches that covers it, so a one-batch store runs the
+              one-batch program; on a mesh a flight is one batch;
+  retire    — the only D2H of a flight is ONE (window_groups *
+              nonce_group,) count vector (the flight's per-nonce hits),
+              its copy started right after the enqueue
+              (``copy_to_host_async``) and read when the flight retires,
+              ``inflight - 1`` flights later; the packed (nonce, index)
+              hit pairs are fetched once per pass.
 
 One disk pass covers a whole nonce *window* (``window_groups`` groups per
 read — on TPU disk bytes are the scarce resource and device FLOPs nearly
@@ -35,7 +43,7 @@ scan's (kept as ``prove_serial`` — the bench baseline and fallback).
 
 On multi-device the label lanes are sharded over the mesh per batch
 (parallel/mesh.py prove_window_step_sharded), the way init shards its
-batches.
+batches, and a flight is one batch.
 
 A proof for challenge ``ch`` is:
     nonce     — the winning proving nonce
@@ -62,9 +70,19 @@ from ..utils import accel, metrics, tracing
 from .data import LabelStore, PostMetadata
 
 DEFAULT_NONCE_GROUP = 16
-DEFAULT_INFLIGHT = 3      # device batches in flight before the oldest retires
+DEFAULT_INFLIGHT = 3      # device flights in flight before the oldest retires
 DEFAULT_READERS = 2       # background reader threads
-DEFAULT_READER_QUEUE = 4  # prefetched batches before reader backpressure
+DEFAULT_READER_QUEUE = 4  # prefetched flights before reader backpressure
+# Batches (scan steps) one host call carries. What the host pays per
+# CALL on a v5e's host (PERF.md section 5): device_put ~0.25 ms fixed +
+# 0.1 ms of lay-out copy a batch + 2 MiB over PCIe ~0.2, the program
+# call 0.32, the async copy, the engine, the retire and the reader's
+# get ~0.35: ~2.0 ms a flight of eight = 0.25 ms a scan step, against
+# 8 x 0.785 = 6.28 ms of device work (3.4 once one epilogue serves all
+# 64 rows). Four would leave 1.7 of 3.1 ms and no room for that; sixteen
+# buys nothing more and doubles the overshoot after a decided winner
+# (inflight flights) and the reader's buffers (reader_queue flights).
+FLIGHT_BATCHES = 8
 MAX_GROUPS = 1025         # nonce search gives up past this many groups
 
 
@@ -91,12 +109,27 @@ def bucket_batch(batch_labels: int, use_pallas: bool) -> int:
     return scrypt.shape_bucket(-(-max(batch_labels, tile) // tile) * tile)
 
 
-def window_step(nonce_group: int, max_hits: int, *, use_pallas: bool,
-                mesh=None):
+def flight_batches(total_labels: int, batch_labels: int, mesh=None) -> int:
+    """Batches a flight of this store carries: ``FLIGHT_BATCHES``, or for
+    a store smaller than that the power of two of batches that covers it
+    (1, 2, 4: at most four compiled shapes, and nobody scans 131,072
+    padded lanes for a 4,096-label store). On a mesh a flight is ONE
+    batch: lane-sharding a flight would cut its sub-batches across
+    devices, no cell and no chip has run the sharded prover (ROADMAP
+    M4), and its flight shape belongs to the PR that brings that cell."""
+    if mesh is not None:
+        return 1
+    batches = -(-total_labels // batch_labels)
+    return min(FLIGHT_BATCHES, 1 << (batches - 1).bit_length())
+
+
+def window_step(nonce_group: int, max_hits: int, batch: int, *,
+                use_pallas: bool, mesh=None):
     """The window step of one backend with its static arguments bound
     (``Prover.scan_step`` for a store's prover; the warmers for the
     program a default prover will run): sharded XLA on a mesh, else the
-    Pallas step or the XLA step on one device."""
+    Pallas step or the XLA step on one device, each a rolled loop of
+    ``batch``-lane scan steps over the flight it is handed."""
     if mesh is not None:
         from ..parallel import mesh as pmesh
         return functools.partial(pmesh.prove_window_step_sharded, mesh,
@@ -104,10 +137,11 @@ def window_step(nonce_group: int, max_hits: int, *, use_pallas: bool,
     if use_pallas:
         return functools.partial(
             proving_pallas.prove_scan_step_window_pallas,
-            n_nonces=nonce_group, max_hits=max_hits,
+            n_nonces=nonce_group, max_hits=max_hits, batch=batch,
             interpret=accel.pallas_interpret())
     return functools.partial(proving.prove_scan_step_window,
-                             n_nonces=nonce_group, max_hits=max_hits)
+                             n_nonces=nonce_group, max_hits=max_hits,
+                             batch=batch)
 
 
 @dataclasses.dataclass
@@ -141,8 +175,9 @@ class ProverStats:
     """Per-prove pipeline accounting (tools/profiler.py --prove)."""
 
     windows: int = 0          # nonce windows swept
-    batches: int = 0          # label batches dispatched
-    retire_ready: int = 0     # of them: count vector landed before retire
+    batches: int = 0          # label batches (scan steps) dispatched
+    flights: int = 0          # device calls that carried them
+    retire_ready: int = 0     # flights whose counts landed before retire
     labels_swept: int = 0     # labels covered across all passes
     read_wait_s: float = 0.0  # blocked on the reader pool
     read_io_s: float = 0.0    # filesystem time inside the reader pool
@@ -354,16 +389,25 @@ class Prover:
         ``(step, mesh, impl)`` — the callable, the mesh it shards over
         (None on one device) and which backend it is (``xla-sharded``,
         ``pallas`` or ``xla``). All three take ``(challenge_words, bases,
-        label_words, meta, threshold, hit_counts, hit_carry)`` and return
-        ``(hit_counts, batch_counts, hit_carry)`` over every nonce of the
-        window (ops/proving.py scan_window). ProveSession runs exactly
-        this, and chip_smoke.py reports and checks it."""
+        label_words, meta, threshold, hit_counts, hit_carry)``, the label
+        words and ``meta`` those of one flight (:meth:`flight_batches`
+        batches wide), and return ``(hit_counts, batch_counts,
+        hit_carry)`` over every nonce of the window (ops/proving.py
+        scan_window). ProveSession runs exactly this, and chip_smoke.py
+        reports and checks it."""
         mesh = self._resolve_mesh()
         impl = "xla-sharded" if mesh is not None else (
             "pallas" if self.use_pallas else "xla")
         step = window_step(self.nonce_group, max(self.params.k2, 1),
-                           use_pallas=self.use_pallas, mesh=mesh)
+                           self.batch_labels, use_pallas=self.use_pallas,
+                           mesh=mesh)
         return step, mesh, impl
+
+    def flight_batches(self, mesh) -> int:
+        """Batches one host call carries over this store where its
+        batches run on ``mesh`` (module :func:`flight_batches`)."""
+        return flight_batches(self.meta.total_labels, self.batch_labels,
+                              mesh)
 
     def _scan_window(self, cw, thr, nonce_base, groups, step, mesh, stats,
                      tenant: str = "-"):
@@ -372,16 +416,18 @@ class Prover:
 
         The bounded read->dispatch->retire window is the shared runtime
         engine's (runtime/engine.py); this method supplies the prove
-        callbacks. A batch crosses the host-device boundary once in each
+        callbacks. Its items are FLIGHTS of ``flight_batches`` batches,
+        and a flight crosses the host-device boundary once in each
         direction: one upload, one program, one count vector back. Under
         a trace capture the pass is one ``prove.window`` span and every
-        per-batch read/dispatch/retire span carries the SAME ``window``
+        per-flight read/dispatch/retire span carries the SAME ``window``
         attribute (the pass's base nonce), so a timeline groups a
-        window's whole ladder even when batches from two windows
+        window's whole ladder even when flights from two windows
         interleave."""
         meta, p = self.meta, self.params
         total = meta.total_labels
         b = self.batch_labels
+        f = self.flight_batches(mesh) * b
         ng = self.nonce_group
         cap = max(p.k2, 1)
         wsp = tracing.span("prove.window",
@@ -391,7 +437,9 @@ class Prover:
         wsp.__enter__()
         reader = None
         try:
-            ranges = [(s, min(b, total - s)) for s in range(0, total, b)]
+            # flight-sized ranges: one reader.get() is a flight's bytes
+            # in one object, and the host concatenates nothing
+            ranges = [(s, min(f, total - s)) for s in range(0, total, f)]
             # ONE donated hit state for the whole window, group-major;
             # a mesh changes placement and nothing else
             state = list(proving.init_hit_state(groups * ng, cap))
@@ -401,7 +449,7 @@ class Prover:
                 state = [pmesh.replicate(mesh, x) for x in state]
                 where = pmesh.prove_batch_shardings(mesh)
             host_counts = np.zeros(ng * groups, dtype=np.int64)
-            # the groups' base nonces go up once a pass; a batch's count
+            # the groups' base nonces go up once a pass; a flight's count
             # and start travel with its labels (a device scalar made
             # here would be a host->device transfer of its own: 0.5 ms
             # each on a v5e's host, PERF.md section 6)
@@ -415,7 +463,7 @@ class Prover:
 
             def dispatch(item):
                 start, count = item
-                # asked per batch: a capture may start in mid-pass
+                # asked per flight: a capture may start in mid-pass
                 traced = tracing.is_enabled()
                 tr = time.perf_counter()
                 with tracing.span("prove.read_wait",
@@ -427,11 +475,11 @@ class Prover:
                                   if traced else None):
                     # a view of the bytes as read, one row of four LE
                     # words a label; its transpose is the program's
-                    # word-major (4, B) and is laid out by the upload
+                    # word-major (4, F) and is laid out by the upload
                     words = np.frombuffer(raw, dtype="<u4").reshape(count, 4)
-                    if count < b:  # pad-and-trim: one shape per pass
+                    if count < f:  # the pass's last flight: one shape
                         words = np.concatenate([
-                            words, np.zeros((b - count, 4), words.dtype)])
+                            words, np.zeros((f - count, 4), words.dtype)])
                     host = [words.T.astype(np.uint32, copy=False),
                             np.array([count, start & 0xFFFFFFFF,
                                       start >> 32], dtype=np.uint32)]
@@ -442,25 +490,31 @@ class Prover:
                                   if traced else None):
                     lw, words = jax.device_put(host, where)
                 metrics.post_prove_h2d_bytes.inc(h2d)
-                # the batch's device.flight runs from here to the read
+                # the scan steps this program runs: the sub-batches of
+                # the flight that hold labels
+                steps = -(-count // b)
+                # the flight's device.flight runs from here to the read
                 # of its count vector (_retire)
                 t_flight = time.perf_counter_ns() if traced else 0
                 with tracing.span("prove.enqueue",
                                   {"window": nonce_base, "groups": groups,
-                                   "batch": b, "nonces": groups * ng,
-                                   "programs": 1}
+                                   "batch": steps * b, "batches": steps,
+                                   "nonces": groups * ng, "programs": 1}
                                   if traced else None):
                     state[0], bc, state[1] = step(cw, bases, lw, words, thr,
                                                   *state)
-                    # the copy starts now and has landed when the batch
-                    # retires, inflight - 1 batches from here
+                    # the copy starts now; the flight retires
+                    # inflight - 1 flights from here
                     bc.copy_to_host_async()
-                # progress must advance PER BATCH, here in the callback
+                # progress must advance PER FLIGHT, here in the callback
                 # — folding the engine's count in after the pass would
                 # freeze the liveness watchdog for the whole disk pass
-                # (ProveSession registers it on stats.batches)
-                stats.batches += 1
-                metrics.post_prove_batches.inc()
+                # (ProveSession registers it on stats.batches, which
+                # keeps counting batch_labels-wide scan steps)
+                stats.batches += steps
+                stats.flights += 1
+                metrics.post_prove_batches.inc(steps)
+                metrics.post_prove_flights.inc()
                 return start + count, bc, count, t_flight
 
             def retire(ticket):
@@ -506,8 +560,9 @@ class Prover:
 
     def _retire(self, item, host_counts, total, stats,
                 nonce_base: int = 0) -> bool:
-        """Read one batch's count vector (one per-nonce count for every
-        nonce of the window); True on sound early exit: some nonce has k2
+        """Read one flight's count vector (one per-nonce count for every
+        nonce of the window, summed over the flight's batches); True on
+        sound early exit: some nonce has k2
         hits and every lower nonce in the window provably cannot reach k2
         with the labels left in this pass (lower windows already failed
         their full pass, so the winner is final and identical to the
@@ -516,8 +571,10 @@ class Prover:
         p = self.params
         tr = time.perf_counter()
         # the engine's prove.retire span (runtime/engine.py) is open here
-        ready = bc.is_ready()   # the prefetch (dispatch) hid the fetch
-        vec = np.asarray(bc)    # the batch's one blocking sync
+        # ready: the flight's program had ended and its counts landed
+        # before anyone asked; false where the device sets the pace
+        ready = bc.is_ready()
+        vec = np.asarray(bc)    # the flight's one blocking sync
         host_counts += vec
         stats.retire_ready += ready
         stats.d2h_bytes += vec.nbytes

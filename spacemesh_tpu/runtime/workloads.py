@@ -149,19 +149,23 @@ def _warm_prove_scan(n: int, batch: int) -> dict:
 
     # the window step a default Prover binds on this platform, at its
     # shapes (post/prover.py): the Pallas step wherever it runs
-    # compiled, every nonce group of a pass in the one program
+    # compiled, every nonce group of a pass over a whole flight of
+    # FLIGHT_BATCHES batches in the one program (what any store of
+    # eight batches or more runs; a smaller store's narrower flight
+    # compiles in its own first proof)
     use_pallas = not accel.pallas_interpret()
     b = prover.bucket_batch(batch, use_pallas)
+    f = prover.FLIGHT_BATCHES * b
     ng, cap = prover.DEFAULT_NONCE_GROUP, prover.ProofParams().k2
     groups = prover.default_window_groups(jax.devices()[0].platform)
-    step = prover.window_step(ng, cap, use_pallas=use_pallas)
+    step = prover.window_step(ng, cap, b, use_pallas=use_pallas)
     cw = jnp.asarray(proving.challenge_words(bytes(32)))
     bases = jnp.asarray(ng * np.arange(groups), jnp.uint32)
-    lw, words = jax.device_put([np.zeros((4, b), np.uint32),
-                                np.array([b, 0, 0], np.uint32)])
+    lw, words = jax.device_put([np.zeros((4, f), np.uint32),
+                                np.array([f, 0, 0], np.uint32)])
     counts, carry = proving.init_hit_state(groups * ng, cap)
-    doc: dict = {"batch": b, "nonce_group": ng, "groups": groups,
-                 "pallas": use_pallas}
+    doc: dict = {"batch": b, "flight_batches": prover.FLIGHT_BATCHES,
+                 "nonce_group": ng, "groups": groups, "pallas": use_pallas}
     _timed(doc, "prove_scan_step_window",
            lambda: step(cw, bases, lw, words, jnp.uint32(1 << 30), counts,
                         carry))

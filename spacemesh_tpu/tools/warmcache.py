@@ -96,12 +96,14 @@ def _warm_shape(n: int, batch: int, mesh_ok: bool) -> dict:
 
 def _warm_prove(batch: int) -> dict:
     """Compile the window step a default streaming prover runs here, at
-    its bucketed batch (the runtime's ``prove_scan`` recipe)."""
+    its bucketed batch and a full flight of batches (the runtime's
+    ``prove_scan`` recipe)."""
     from ..runtime import workloads
 
     doc = workloads.get("prove_scan").warm(0, batch)
     _log(f"  prove_scan_step_window b={doc['batch']} "
-         f"groups={doc['groups']}: {doc['prove_scan_step_window']}s")
+         f"x{doc['flight_batches']} groups={doc['groups']}: "
+         f"{doc['prove_scan_step_window']}s")
     return doc
 
 

@@ -375,7 +375,12 @@ post_store_enospc_waits = REGISTRY.counter(
 post_prove_windows = REGISTRY.counter(
     "post_prove_windows_total", "nonce windows swept over the label store")
 post_prove_batches = REGISTRY.counter(
-    "post_prove_batches_total", "label batches dispatched by the prover")
+    "post_prove_batches_total",
+    "label batches (scan steps) dispatched by the prover")
+post_prove_flights = REGISTRY.counter(
+    "post_prove_flights_total",
+    "device calls that carried them: batches / flights is the mean fill "
+    "of a flight (post/prover.py FLIGHT_BATCHES)")
 post_prove_early_exits = REGISTRY.counter(
     "post_prove_early_exits_total",
     "prove passes cut short once the winning nonce was decided")

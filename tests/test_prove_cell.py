@@ -43,9 +43,10 @@ def test_last_line_is_the_contracts_object(trace):
         # the span readers report; the device-trace readers find no
         # device plane on the CPU and stay silent
         assert PER_LAYER <= set(metrics), sorted(metrics)
-        # 16 of label words and the batch's three start/count words
+        # 16 of label words and the flight's three start/count words;
+        # the rehearsal's store of two batches is one flight of two
         assert metrics["prove_h2d_bytes_per_label"]["value"] \
-            == 16.0 + 12 / 16384
+            == 16.0 + 12 / (2 * 16384)
         assert 0 <= line["device"]["busy_s"] <= line["device"]["window_s"]
     else:
         assert sorted(metrics) == ["p50_ms", "setup_s"]

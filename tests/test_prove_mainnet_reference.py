@@ -39,8 +39,9 @@ def _challenge(i: int) -> bytes:
 
 @pytest.fixture(scope="module")
 def stores(tmp_path_factory):
-    """A: 2^14 labels, whole batches. B: 20,000 labels, so the last of
-    its ten batches of 2,048 holds 1,568."""
+    """A: 2^14 labels, whole batches: one flight of eight. B: 20,000
+    labels: a flight of eight batches of 2,048 and a ragged one of two,
+    whose second holds 1,568."""
     out = {}
     for name, per_unit in (("A", 4096), ("B", 5000)):
         d = tmp_path_factory.mktemp(f"prove-ref-{name}")
@@ -55,18 +56,19 @@ def stores(tmp_path_factory):
 
 
 # store, challenge, nonce_group, window_groups -> winner, passes, and the
-# labels swept where the pass ended early at a batch's edge
+# label whose flight's edge ends the pass early (512-label batches there:
+# a flight is 4,096 labels under XLA; the Pallas lane tile makes it 8,192)
 CASES = {
     # nonce 9 of the first 16: one pass, decided at the store's end
     "winner_in_first_window": ("A", 5, 16, 1, 9, 1, None),
     # nonce 21: the first 16 nonces fail their whole pass, a second runs
     "second_pass": ("A", 2, 16, 1, 21, 2, None),
     # nonce 62 = 2 x 31 is the LOWEST nonce of the third window, so
-    # nothing below it has to be ruled out: the pass ends with the batch
-    # that holds its 37th hit (label 11,923), 4,096 labels early
-    "early_exit_lowest_of_window": ("A", 14, 31, 1, 62, 3,
-                                    2 * 16384 + 12288),
-    # the last batch holds 1,568 labels and is padded to 2,048
+    # nothing below it has to be ruled out: the pass ends with the
+    # FLIGHT that holds its 37th hit (label 11,923): 4,096 labels early
+    # where a flight is 4,096 labels, at the store's end where it is 8,192
+    "early_exit_lowest_of_window": ("A", 14, 31, 1, 62, 3, 11923),
+    # the last batch holds 1,568 labels; its flight is padded to 16,384
     "ragged_last_batch": ("B", 3, 16, 1, 13, 1, None),
 }
 
@@ -74,14 +76,15 @@ CASES = {
 @pytest.mark.parametrize("step", ["xla", "pallas"])
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_prover_returns_the_reference_proof(stores, case, step):
-    store, i, ng, wg, winner, passes, swept = CASES[case]
+    store, i, ng, wg, winner, passes, decided = CASES[case]
     data_dir, files = stores[store]
     challenge = _challenge(i)
     want = _ref().prove(files, challenge, K1, K2)
     assert want[0] == winner                     # what the case was picked for
     prover = Prover(data_dir, ProofParams(k1=K1, k2=K2, k3=K2,
                                           pow_difficulty=DIFFICULTY),
-                    batch_labels=BATCH, nonce_group=ng, window_groups=wg,
+                    batch_labels=BATCH if decided is None else 512,
+                    nonce_group=ng, window_groups=wg,
                     use_pallas=step == "pallas", mesh=None)
     assert prover.scan_step()[2] == step
     proof = prover.prove(challenge)
@@ -89,8 +92,12 @@ def test_prover_returns_the_reference_proof(stores, case, step):
     st = prover.last_stats
     assert st.windows == passes
     total = prover.meta.total_labels
-    if swept is not None:
-        assert st.early_exited and st.labels_swept == swept < passes * total
+    if decided is not None:
+        flight = prover.flight_batches(None) * prover.batch_labels
+        assert flight == {"xla": 4096, "pallas": 8192}[step]
+        swept = (passes - 1) * total + -(-(decided + 1) // flight) * flight
+        assert st.early_exited and st.labels_swept == swept
+        assert swept == {"xla": 2 * 16384 + 12288, "pallas": 3 * 16384}[step]
     else:
         assert st.labels_swept == passes * total
     ok = _ref().check(files, challenge, NODE, nonce=proof.nonce,

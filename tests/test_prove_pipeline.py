@@ -15,8 +15,9 @@ import time
 import numpy as np
 import pytest
 
-from spacemesh_tpu.ops import proving, scrypt
+from spacemesh_tpu.ops import proving, proving_pallas, scrypt
 from spacemesh_tpu.post import initializer
+from spacemesh_tpu.post import prover as prover_mod
 from spacemesh_tpu.post.data import LabelReader, LabelStore, PostMetadata
 from spacemesh_tpu.post.prover import ProofParams, Prover
 from spacemesh_tpu.utils import metrics
@@ -43,6 +44,21 @@ def unit(tmp_path_factory):
 def serial_proof(unit):
     d, _ = unit
     return Prover(d, PARAMS, batch_labels=512).prove_serial(CH)
+
+
+def _little_store(tmp_path_factory, labels):
+    d = tmp_path_factory.mktemp(f"prove-{labels}")
+    meta, _ = initializer.initialize(
+        d, node_id=NODE, commitment=COMMIT, num_units=1,
+        labels_per_unit=labels, scrypt_n=2, max_file_size=8192,
+        batch_size=256)
+    return d, meta
+
+
+@pytest.fixture(scope="module")
+def long_unit(tmp_path_factory):
+    # 128 batches of 64 labels = 16 flights of eight
+    return _little_store(tmp_path_factory, 8192)
 
 
 # -- proof identity across backends -----------------------------------------
@@ -107,25 +123,101 @@ def test_wide_window_backends_match_serial(unit, serial_proof, monkeypatch,
     assert prover.prove(CH) == serial_proof
 
 
-def test_one_count_vector_a_batch_and_its_prefetch_is_counted(unit):
-    # k1 < k2 here: no early exit, every dispatched batch retires
+@pytest.mark.parametrize("batch, flight", [(512, 4), (128, 8)])
+def test_one_count_vector_a_batch_and_its_prefetch_is_counted(unit, batch,
+                                                              flight):
+    # k1 < k2 here: no early exit, every dispatched flight retires. The
+    # 2,048-label store is ONE flight of its four 512-label batches, or
+    # two flights of eight 128-label ones
     d, meta = unit
     params = ProofParams(k1=8, k2=12, k3=8, pow_difficulty=bytes([255]) * 32)
-    prover = Prover(d, params, batch_labels=512, window_groups=4)
+    prover = Prover(d, params, batch_labels=batch, window_groups=4)
+    assert prover.flight_batches(None) == flight
     before = metrics.post_prove_d2h_bytes.sample().get((), 0.0)
+    flights0 = metrics.post_prove_flights.sample().get((), 0.0)
+    batches0 = metrics.post_prove_batches.sample().get((), 0.0)
     proof = prover.prove(CH)
     stats = prover.last_stats
     assert proof == Prover(d, params, batch_labels=512).prove_serial(CH)
     ng, groups = prover.nonce_group, prover.window_groups
     per_pass = meta.total_labels // prover.batch_labels
+    # batches keep counting batch_labels-wide scan steps (what the
+    # liveness watchdog watches); flights count device calls
     assert stats.batches == stats.windows * per_pass
-    # per retired batch ONE (groups * ng,) i32 vector; per deciding pass
+    assert stats.flights == stats.windows * per_pass // flight
+    assert stats.batches / stats.flights == flight    # the mean fill
+    assert metrics.post_prove_flights.sample()[()] - flights0 \
+        == stats.flights
+    assert metrics.post_prove_batches.sample()[()] - batches0 \
+        == stats.batches
+    # per retired FLIGHT one (groups * ng,) i32 vector; per deciding pass
     # the one state pair (counts + lo/hi carry of k2 slots a nonce)
     state = groups * ng * 4 + 2 * groups * ng * params.k2 * 4
-    assert stats.d2h_bytes == stats.batches * groups * ng * 4 + state
+    assert stats.d2h_bytes == stats.flights * groups * ng * 4 + state
     assert metrics.post_prove_d2h_bytes.sample().get((), 0.0) - before \
         == stats.d2h_bytes
-    assert 0 <= stats.retire_ready <= stats.batches
+    assert 0 <= stats.retire_ready <= stats.flights
+
+
+@pytest.mark.parametrize("labels, batches, flight, flights", [
+    (64, 1, 1, 1),          # a one-batch store runs the one-batch program
+    (3 * 64 - 24, 3, 4, 1),  # ragged: three of the flight's four steps
+    (8 * 64, 8, 8, 1),
+    (11 * 64 - 24, 11, 8, 2),   # a full flight and a ragged one
+])
+def test_flights_of_every_fill_match_serial(tmp_path_factory, labels,
+                                            batches, flight, flights):
+    # k1 < k2: a nonce wins rarely, so passes run to the store's end
+    d, _ = _little_store(tmp_path_factory, labels)
+    params = ProofParams(k1=10, k2=12, k3=8,
+                         pow_difficulty=bytes([255]) * 32)
+    prover = Prover(d, params, batch_labels=64, nonce_group=8,
+                    use_pallas=False, mesh=None)
+    assert prover.flight_batches(None) == flight
+    proof = prover.prove(CH)
+    assert proof == Prover(d, params, batch_labels=64, nonce_group=8,
+                           use_pallas=False).prove_serial(CH)
+    stats = prover.last_stats
+    if not stats.early_exited:
+        assert stats.batches == stats.windows * batches
+        assert stats.flights == stats.windows * flights
+        assert stats.labels_swept == stats.windows * labels
+
+
+def test_the_warmer_warms_the_flight_program_a_prover_runs(long_unit):
+    # a warmer that warms another shape costs a compile inside somebody's
+    # first proof: after runtime/workloads' prove_scan recipe (what
+    # tools/warmcache runs) a default-shaped prover over a store of
+    # eight batches or more compiles no window step of its own
+    from spacemesh_tpu.runtime import workloads
+
+    d, _ = long_unit
+    doc = workloads.get("prove_scan").warm(0, 64)
+    assert (doc["batch"], doc["flight_batches"], doc["pallas"]) \
+        == (64, prover_mod.FLIGHT_BATCHES, False)
+    warmed = proving.prove_scan_step_window._cache_size()
+    params = ProofParams(k1=60, pow_difficulty=bytes([255]) * 32)
+    prover = Prover(d, params, batch_labels=64, mesh=None)
+    assert (prover.nonce_group, prover.window_groups, params.k2) \
+        == (doc["nonce_group"], doc["groups"], 37)
+    assert prover.flight_batches(None) == doc["flight_batches"]
+    assert prover.prove(CH).k2 == 37
+    assert proving.prove_scan_step_window._cache_size() == warmed
+
+
+def test_a_mesh_flight_is_one_batch(unit, serial_proof, monkeypatch):
+    # lane-sharding a flight would cut its sub-batches across devices:
+    # on a mesh the unit that crosses stays one batch
+    d, _ = unit
+    monkeypatch.setenv("SPACEMESH_MESH", "1")
+    prover = Prover(d, PARAMS, batch_labels=256)
+    _step, mesh, impl = prover.scan_step()
+    assert impl == "xla-sharded" and mesh is not None
+    assert prover.flight_batches(mesh) == 1
+    assert prover.flight_batches(None) == 8
+    assert prover.prove(CH) == serial_proof
+    stats = prover.last_stats
+    assert stats.flights == stats.batches > 0
 
 
 def test_ragged_tail_single_shape(unit, serial_proof):
@@ -159,11 +251,12 @@ def test_one_disk_pass_per_window(unit):
     assert stats.windows >= 1
 
 
-def test_early_exit_reads_less_than_store(unit):
+def test_early_exit_reads_less_than_store(long_unit):
     # k1=64 >> k2=16: nonce 0 qualifies after a fraction of the store, so
     # the sound early exit fires and the pass never reads the whole store
-    d, meta = unit
-    prover = Prover(d, PARAMS, batch_labels=256, inflight=1,
+    # (16 flights of 8 x 64 labels)
+    d, meta = long_unit
+    prover = Prover(d, PARAMS, batch_labels=64, inflight=1,
                     reader_queue=1)
     before = _read_bytes()
     proof = prover.prove(CH)
@@ -171,6 +264,27 @@ def test_early_exit_reads_less_than_store(unit):
     assert prover.last_stats.early_exited
     assert proof.nonce == 0
     assert read < meta.total_labels * scrypt.LABEL_BYTES
+
+
+def test_early_exit_decided_in_mid_flight(long_unit):
+    # the winner's K2-th hit lies in a middle scan step of its flight:
+    # the rule is asked once a flight, with scanned_end the flight's
+    # end, and the proof is the serial scan's all the same (the winner's
+    # indices are the first K2 slots of the carry wherever the pass
+    # stopped)
+    d, meta = long_unit
+    b, f = 64, 8 * 64
+    prover = Prover(d, PARAMS, batch_labels=b)
+    proof = prover.prove(CH)
+    assert proof == Prover(d, PARAMS, batch_labels=b).prove_serial(CH)
+    stats = prover.last_stats
+    assert stats.early_exited and stats.windows == 1
+    decided = proof.indices[-1]
+    assert (decided % f) // b < 7, "pick a challenge that decides mid-flight"
+    flight_end = (decided // f + 1) * f
+    assert flight_end <= stats.labels_swept < meta.total_labels
+    assert stats.labels_swept % f == 0
+    assert stats.batches == 8 * stats.flights
 
 
 def test_d2h_is_compacted_hits_not_masks(unit):
@@ -239,6 +353,82 @@ def test_prove_step_high_index_batches():
         vals = proving.proving_hashes(CH, k, idx, labels)
         want = [int(start + i) for i in np.nonzero(vals < t)[0][:cap]]
         assert proving.decode_hits(counts, carry, k, cap) == want
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+@pytest.mark.parametrize("case", ["full", "ragged", "carry"])
+def test_flight_step_is_the_batch_step_sub_batch_by_sub_batch(backend, case):
+    # the flight program (one rolled loop over the flight's scan steps)
+    # against the one-batch window step applied sub-batch by sub-batch:
+    # counts, the flight's summed batch counts and the carry, bit for bit
+    import jax.numpy as jnp
+
+    b, fb, ng, groups, cap = 1024, 8, 4, 2, 40
+    f = fb * b
+    count, start = {"full": (f, 5 * f),
+                    # five steps of eight, the fifth partly valid
+                    "ragged": (4 * b + 100, 3 * b),
+                    # the carry into start_hi falls in the fourth step
+                    "carry": (f, (1 << 32) - 3 * b - 17)}[case]
+    if backend == "pallas":
+        step = proving_pallas.prove_scan_step_window_pallas
+        kw = {"n_nonces": ng, "max_hits": cap, "interpret": True}
+    else:
+        step = proving.prove_scan_step_window
+        kw = {"n_nonces": ng, "max_hits": cap}
+    rng = np.random.default_rng(32)
+    words = rng.integers(0, 1 << 32, size=(4, f), dtype=np.uint32)
+    words[:, count:] = 0
+    cw = jnp.asarray(proving.challenge_words(CH))
+    bases = jnp.asarray(7 + ng * np.arange(groups), jnp.uint32)
+    # ~6 hits a row a scan step: the slots fill over the whole flight
+    thr = jnp.uint32(proving.threshold_u32(48, f))
+
+    def meta(n, at):
+        return jnp.asarray([n, at & 0xFFFFFFFF, at >> 32], jnp.uint32)
+
+    got = step(cw, bases, jnp.asarray(words), meta(count, start), thr,
+               *proving.init_hit_state(groups * ng, cap), batch=b, **kw)
+    counts, carry = proving.init_hit_state(groups * ng, cap)
+    summed = np.zeros(groups * ng, np.int64)
+    steps = -(-count // b)
+    for g in range(steps):
+        counts, bc, carry = step(
+            cw, bases, jnp.asarray(words[:, g * b:(g + 1) * b]),
+            meta(min(b, count - g * b), start + g * b), thr, counts, carry,
+            **kw)
+        summed += np.asarray(bc)
+    assert steps == {"full": 8, "ragged": 5, "carry": 8}[case]
+    assert summed.min() > 0
+    if case == "full":      # some row overflows its slots: hits drop
+        assert int(np.asarray(counts).max()) > cap
+    assert np.array_equal(got[0], counts)
+    assert np.array_equal(got[1], summed)
+    assert np.array_equal(got[2], carry)
+    if case == "carry":     # hits on both sides of 2^32
+        his = np.asarray(got[2])[1]
+        assert (his == 0).any() and (his == 1).any()
+
+
+def test_a_flight_is_a_whole_number_of_scan_steps():
+    import jax.numpy as jnp
+
+    with pytest.raises(ValueError, match="whole number"):
+        proving.prove_scan_step_window(
+            jnp.zeros(8, jnp.uint32), jnp.zeros(1, jnp.uint32),
+            jnp.zeros((4, 192), jnp.uint32), jnp.zeros(3, jnp.uint32),
+            jnp.uint32(1), *proving.init_hit_state(4, 8), n_nonces=4,
+            max_hits=8, batch=128)
+
+
+@pytest.mark.parametrize("total, batch, mesh, want", [
+    (4096, 16384, None, 1), (2 * 16384, 16384, None, 2),
+    (3 * 16384, 16384, None, 4), (5 * 16384 - 1, 16384, None, 8),
+    (1 << 23, 16384, None, 8), (1 << 34, 16384, None, 8),
+    (1 << 23, 16384, object(), 1)])
+def test_flight_batches_adapts_to_the_store(total, batch, mesh, want):
+    assert prover_mod.FLIGHT_BATCHES == 8
+    assert prover_mod.flight_batches(total, batch, mesh) == want
 
 
 # -- LabelReader pool --------------------------------------------------------

@@ -180,3 +180,33 @@ def test_lane_indices_carry_into_the_hi_word():
     assert np.array_equal(np.asarray(lo), want_lo)
     assert np.array_equal(np.asarray(hi), want_hi)
     assert lo.dtype == hi.dtype == jnp.uint32
+
+
+def test_flight_traces_the_kernel_once_and_outside_the_loop_body(monkeypatch):
+    # the rolled loop's body calls the per-group step four times; the
+    # step (and with it the Pallas kernel's ~10k jnp ops) is traced ONCE,
+    # in the window program's own trace before the loop, and the body's
+    # calls find it traced: first traced inside the body's nested trace
+    # it cost 10-49 s of Python on a v5e's host against 2.9 s (PERF.md
+    # section 6, PR 32). Fresh shapes, so nothing here is cached
+    import traceback
+
+    import jax.numpy as jnp
+
+    seen = []
+    kernel = proving_pallas._kernel
+
+    def watched(*a, **kw):
+        seen.append(any(f.name == "sub_batch"
+                        for f in traceback.extract_stack()))
+        return kernel(*a, **kw)
+
+    monkeypatch.setattr(proving_pallas, "_kernel", watched)
+    b, fb, ng, groups, cap = 2048, 4, 3, 4, 5
+    lowered = proving_pallas.prove_scan_step_window_pallas.lower(
+        jnp.zeros(8, jnp.uint32), jnp.zeros(groups, jnp.uint32),
+        jnp.zeros((4, fb * b), jnp.uint32), jnp.zeros(3, jnp.uint32),
+        jnp.uint32(1), *proving.init_hit_state(groups * ng, cap),
+        n_nonces=ng, max_hits=cap, batch=b, interpret=True)
+    assert seen == [False]
+    assert "while" in lowered.as_text()

@@ -115,6 +115,32 @@ c = build("prove_window_pallas", proving_pallas.prove_scan_step_window_pallas,
 out["prove_window_pallas"]["kernel_calls"] = sum(
     "custom-call" in line and "_scan_pallas" in line
     for line in c.as_text().splitlines())
+# ... over a FLIGHT of eight batches (post/prover.py FLIGHT_BATCHES): what
+# a default Prover runs on any store of eight batches or more. The loop
+# over the flight's scan steps is rolled: the kernel's four custom-calls
+# sit in ONE while body, whatever the flight holds
+flight_args = (window_args[:2] + (sds((4, prover.FLIGHT_BATCHES * b)),)
+               + window_args[3:])
+c = build("prove_flight_pallas", proving_pallas.prove_scan_step_window_pallas,
+          *flight_args, n_nonces=ng, max_hits=cap, batch=b, interpret=False)
+text = c.as_text()
+computations, name = {}, None
+for line in text.splitlines():
+    if line and not line[0].isspace() and line.rstrip().endswith("{"):
+        name = line.split()[1 if line.startswith("ENTRY") else 0].lstrip("%")
+        computations[name] = []
+    elif name is not None:
+        computations[name].append(line)
+holders = [n for n, body in computations.items()
+           if any("custom-call" in ln and "_scan_pallas" in ln for ln in body)]
+out["prove_flight_pallas"].update(
+    module=text.splitlines()[0].split()[1].rstrip(","),
+    kernel_calls=sum("custom-call" in ln and "_scan_pallas" in ln
+                     for ln in text.splitlines()),
+    kernel_computations=len(holders),
+    while_bodies_holding_the_kernel=sum(
+        f"body=%{n}," in ln or ln.rstrip().endswith(f"body=%{n}")
+        for n in holders for body in computations.values() for ln in body))
 
 # k2pow: search (one 2^16-nonce batch) and batched witness verification
 b = 1 << 16
@@ -158,8 +184,8 @@ def test_tpu_default_programs_compile_for_v5e(lowered):
     # use_pallas=True), so Mosaic must keep compiling it
     for name in ("labels_8192", "prove_step_xla", "prove_step_pallas",
                  "prove_mask_pallas", "prove_window_xla",
-                 "prove_window_pallas", "pow_hash", "pow_below_target",
-                 "pow_verify"):
+                 "prove_window_pallas", "prove_flight_pallas", "pow_hash",
+                 "pow_below_target", "pow_verify"):
         assert name in lowered, name
 
 
@@ -167,6 +193,18 @@ def test_window_step_keeps_the_kernel_ops_the_trace_metrics_match(lowered):
     # scan_roofline / scan_kernel_share sum the device time of op events
     # named ``_scan_pallas ... custom-call``: one per nonce group
     assert lowered["prove_window_pallas"]["kernel_calls"] == 4
+
+
+def test_flight_program_is_one_rolled_loop_around_the_four_kernels(lowered):
+    # a flight of eight batches is ONE program whose loop over the scan
+    # steps is rolled: the kernel is lowered once a nonce group (four
+    # custom-calls, not thirty-two), all in one computation that is the
+    # body of one while op; and scan_step_ms finds the program by name
+    flight = lowered["prove_flight_pallas"]
+    assert flight["kernel_calls"] == 4
+    assert flight["kernel_computations"] == 1
+    assert flight["while_bodies_holding_the_kernel"] == 1
+    assert "prove_scan_step" in flight["module"]
 
 
 def test_labels_shard_over_four_chips_without_collectives(lowered):

@@ -1,7 +1,7 @@
 """One proof, one tree: ``Prover.prove`` with the span tracer on closes
 into a single tree from ``prove.proof`` down to one ``device.flight``
-per batch, and the spans carry the counts the benchmark's per-layer
-readers take (docs/OBSERVABILITY.md, docs/POST_PROVING.md)."""
+per flight of batches, and the spans carry the counts the benchmark's
+per-layer readers take (docs/OBSERVABILITY.md, docs/POST_PROVING.md)."""
 
 import hashlib
 
@@ -12,7 +12,10 @@ from spacemesh_tpu.post.prover import ProofParams, Prover
 from spacemesh_tpu.utils import metrics, tracing
 
 K2 = 37
-NG, GROUPS, BATCH = 16, 2, 2048
+NG, GROUPS, BATCH = 16, 2, 512
+# 10,000 labels are two full flights of eight batches and a ragged one
+# of 1,808 labels: four of its eight scan steps, the fourth partly valid
+TOTAL, FLIGHT = 10_000, 8 * BATCH
 STAGES = ("prove.read_wait", "prove.convert", "prove.upload",
           "prove.enqueue")
 
@@ -89,7 +92,7 @@ def test_every_batch_has_its_stages_and_its_flight(capture):
     _proof, stats, evs, _h2d = capture
     named = _named(evs)
     dispatches = {e["args"]["id"]: e for e in named["prove.dispatch"]}
-    assert len(dispatches) == stats.batches
+    assert len(dispatches) == stats.flights
     for name in STAGES:     # one of each, inside its batch's dispatch
         assert sorted(e["args"]["parent"] for e in named[name]) \
             == sorted(dispatches), name
@@ -98,8 +101,16 @@ def test_every_batch_has_its_stages_and_its_flight(capture):
             assert d["ts"] <= e["ts"] and \
                 e["ts"] + e["dur"] <= d["ts"] + d["dur"] + 2
     for e in named["prove.enqueue"]:
-        assert (e["args"]["groups"], e["args"]["batch"],
-                e["args"]["nonces"]) == (GROUPS, BATCH, GROUPS * NG)
+        # ``batch`` is the lanes the program scans: its scan steps' width
+        count = dispatches[e["args"]["parent"]]["args"]["count"]
+        steps = -(-count // BATCH)
+        assert (e["args"]["groups"], e["args"]["batches"],
+                e["args"]["batch"], e["args"]["nonces"]) \
+            == (GROUPS, steps, steps * BATCH, GROUPS * NG)
+    assert sorted({e["args"]["batches"] for e in named["prove.enqueue"]}) \
+        == [4, 8]
+    assert sum(e["args"]["batches"] for e in named["prove.enqueue"]) \
+        == stats.batches
     # a flight per retired batch: from its enqueue to its counts fetched
     retires = {e["args"]["id"]: e for e in named["prove.retire"]}
     flights = named["device.flight"]
@@ -108,7 +119,7 @@ def test_every_batch_has_its_stages_and_its_flight(capture):
     for f in flights:
         r = retires[f["args"]["parent"]]
         assert f["args"]["program"] == "prove_scan"
-        assert f["args"]["labels"] == r["args"]["count"] <= BATCH
+        assert f["args"]["labels"] == r["args"]["count"] <= FLIGHT
         assert f["args"]["d2h_bytes"] == GROUPS * NG * 4
         assert f["ts"] + f["dur"] <= r["ts"] + r["dur"] + 2
         # it starts where the batch's enqueue does (the clock is read
@@ -123,18 +134,19 @@ def test_every_batch_has_its_stages_and_its_flight(capture):
 
 def test_upload_bytes_are_16_a_label_dispatched(capture):
     # label words only: the program makes its own lane indices; the
-    # batch's count and start ride along as three u32 words
-    _proof, _stats, evs, h2d = capture
+    # flight's count and start ride along as three u32 words. A ragged
+    # last flight is padded to the flight's shape
+    _proof, stats, evs, h2d = capture
     named = _named(evs)
     sent = sum(e["args"]["h2d_bytes"] for e in named["prove.upload"])
-    assert sent == sum(16 * e["args"]["batch"] + 12
-                       for e in named["prove.enqueue"])
+    assert sent == (16 * FLIGHT + 12) * stats.flights
     assert h2d == sent      # the counter counts what the spans say
 
 
 def test_a_batch_crosses_the_boundary_once_each_way(capture):
-    # the mechanism of ISSUE 28, pinned as PR 24's was for post.verify:
-    # per batch ONE upload call, ONE program, ONE count vector back
+    # the mechanism of ISSUE 28 at ISSUE 32's unit, pinned as PR 24's was
+    # for post.verify: per FLIGHT of up to eight batches ONE upload call,
+    # ONE program, ONE count vector back
     _proof, stats, evs, _h2d = capture
     named = _named(evs)
     by_parent = {}
@@ -145,11 +157,18 @@ def test_a_batch_crosses_the_boundary_once_each_way(capture):
         (up,) = by_parent[d["args"]["id"], "prove.upload"]
         (enq,) = by_parent[d["args"]["id"], "prove.enqueue"]
         assert up["args"]["arrays"] == 2    # both in one device_put
-        assert up["args"]["h2d_bytes"] == 16 * BATCH + 12
+        assert up["args"]["h2d_bytes"] == 16 * FLIGHT + 12
         assert enq["args"]["programs"] == 1
+        assert enq["args"]["batches"] == -(-d["args"]["count"] // BATCH)
         assert enq["args"]["groups"] == GROUPS
     flights = named["device.flight"]
     assert len(flights) == len(named["prove.retire"])
+    # a pass of the store is three flights of 8 + 8 + 4 scan steps
+    per_pass = -(-TOTAL // FLIGHT)
+    assert stats.flights == len(named["prove.dispatch"]) \
+        == stats.windows * per_pass
+    assert stats.batches == stats.windows * -(-TOTAL // BATCH)
+    assert stats.batches / stats.flights == 20 / 3      # the mean fill
     for f in flights:
         assert f["args"]["syncs"] == 1
         assert f["args"]["groups"] == GROUPS
@@ -157,8 +176,8 @@ def test_a_batch_crosses_the_boundary_once_each_way(capture):
     # the prefetch's hit count is in the stats and equals the trace's
     assert stats.retire_ready == sum(
         bool(f["args"]["ready"]) for f in flights)
-    assert 0 <= stats.retire_ready <= stats.batches
-    assert "retire_ready" in stats.as_dict()
+    assert 0 <= stats.retire_ready <= stats.flights
+    assert {"retire_ready", "flights"} <= set(stats.as_dict())
 
 
 def test_spans_are_free_when_the_tracer_is_off():
