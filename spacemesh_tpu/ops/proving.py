@@ -84,32 +84,32 @@ def proving_hash_jit(challenge_words, nonce, idx_lo, idx_hi, label_words):
         return salsa20_8(state)[0]
 
 
-def _scan_mask(challenge_words, nonce_base, idx_lo, idx_hi, label_words,
-               threshold, *, n_nonces: int):
-    """(n_nonces, B) bool qualification mask, traced per-nonce.
+@functools.partial(jax.jit, static_argnames=("n_nonces",))
+def proving_scan_jit(challenge_words, nonce_base, idx_lo, idx_hi, label_words,
+                     threshold, valid=None, *, n_nonces: int):
+    """Evaluate ``n_nonces`` consecutive nonces over one label batch: one
+    nonce group's scan KERNEL in plain XLA, the twin of
+    ops/proving_pallas.py ``_scan_pallas``.
 
-    The per-nonce stacking (rather than one fused (16, n*B) state) keeps
-    each Salsa20/8 working set L2-resident — measured ~2x faster on CPU
-    and neutral on TPU, where the Pallas kernel is the fast path anyway.
+    Returns (n_nonces, B) bool qualification mask; n_nonces is static so
+    the whole sweep is one compiled program. Traced per nonce: the
+    per-nonce stacking (rather than one fused (16, n*B) state) keeps each
+    Salsa20/8 working set L2-resident — measured ~2x faster on CPU and
+    neutral on TPU, where the Pallas kernel is the fast path anyway.
+    ``valid`` cuts the pad lanes of a ragged tail batch (lane >= valid
+    never qualifies), so every batch of a pass shares one compiled
+    shape; per nonce and before the stack: cut after it, the masks of
+    several groups concatenated compile 10x slower on the CPU.
     """
+    alive = None if valid is None else (
+        jnp.arange(idx_lo.shape[0], dtype=jnp.uint32) < valid)
+
     def one(k):
         vals = proving_hash_jit(challenge_words, nonce_base + jnp.uint32(k),
                                 idx_lo, idx_hi, label_words)
-        return vals < threshold.astype(jnp.uint32)
+        hit = vals < threshold.astype(jnp.uint32)
+        return hit if alive is None else hit & alive
     return jnp.stack([one(k) for k in range(n_nonces)])
-
-
-@functools.partial(jax.jit, static_argnames=("n_nonces",))
-def proving_scan_jit(challenge_words, nonce_base, idx_lo, idx_hi, label_words,
-                     threshold, *, n_nonces: int):
-    """Evaluate ``n_nonces`` consecutive nonces over one label batch.
-
-    Returns (n_nonces, B) bool qualification mask. The host accumulates
-    per-nonce hit counts/indices across label batches; n_nonces is static
-    so the whole sweep is one compiled program.
-    """
-    return _scan_mask(challenge_words, nonce_base, idx_lo, idx_hi,
-                      label_words, threshold, n_nonces=n_nonces)
 
 
 # --- on-device hit compaction ----------------------------------------------
@@ -204,12 +204,10 @@ def prove_scan_step_jit(challenge_words, nonce_base, idx_lo, idx_hi,
     donated and cycle device-side across the pass — the only per-batch
     host fetch is ``batch_counts``.
     """
-    b = idx_lo.shape[0]
     with jax.named_scope("scan_kernel"):
-        mask = _scan_mask(challenge_words, nonce_base, idx_lo, idx_hi,
-                          label_words, threshold, n_nonces=n_nonces)
-        lane = jnp.arange(b, dtype=jnp.uint32)
-        mask = mask & (lane[None, :] < valid)
+        mask = proving_scan_jit(challenge_words, nonce_base, idx_lo, idx_hi,
+                                label_words, threshold, valid,
+                                n_nonces=n_nonces)
     return compact_and_merge(mask, hit_counts, hit_carry, start_lo,
                              start_hi, max_hits=max_hits)
 
@@ -226,18 +224,24 @@ def lane_indices(b: int, start_lo, start_hi, sharding=None):
     return lo, start_hi + (lo < lane).astype(jnp.uint32)
 
 
-def scan_window(group_step, challenge_words, bases, label_words, meta,
-                threshold, hit_counts, hit_carry, *, batch: int | None = None,
-                lane_sharding=None):
+def scan_window(group_mask, challenge_words, bases, label_words, meta,
+                threshold, hit_counts, hit_carry, *, max_hits: int,
+                batch: int | None = None, lane_sharding=None):
     """The window step both backends share: ONE program per FLIGHT of
-    label batches that runs ``group_step`` (a per-group scan step, its
-    ``n_nonces`` and ``max_hits`` bound) once per base in ``bases`` over
-    each batch of the flight.
+    label batches. A scan step of it is ``group_mask`` (a nonce group's
+    scan kernel, its ``n_nonces`` bound: :func:`proving_scan_jit` or the
+    Pallas ``_scan_pallas``) once per base in ``bases`` over one batch,
+    the groups' masks stacked group-major, and then ONE compaction
+    epilogue (:func:`compact_and_merge`) over every nonce row of the
+    window: what an epilogue costs is mostly fixed (its cumsum, gathers
+    and two scatters, and the slices and concatenates four per-group
+    epilogues needed), so one over 64 rows took a scan step from 0.80 to
+    0.43 ms on a v5e (PERF.md section 6, PR 35).
 
     ``meta`` is the flight's three u32 words ``[valid, start_lo,
     start_hi]``, uploaded beside ``label_words`` (4, lanes); the lane
     indices are made here (:func:`lane_indices`), not sent. ``batch`` is
-    the static width of ONE scan step (kernel + compaction epilogue).
+    the static width of ONE scan step (kernels + compaction epilogue).
     Label words no wider than it (or ``batch`` None) are one step.
     Wider ones are a flight: the same step
     runs inside one ROLLED ``lax.fori_loop`` over sub-batches ``g = 0 ..
@@ -245,35 +249,35 @@ def scan_window(group_step, challenge_words, bases, label_words, meta,
     runs only the sub-batches that hold labels), sub-batch ``g`` taking
     lanes ``[g * batch, (g + 1) * batch)`` by a dynamic slice, ``valid_g =
     clip(valid - g * batch, 0, batch)`` and ``start_g = start + g *
-    batch`` with the carry into the hi word. Rolled, so ``group_step``
+    batch`` with the carry into the hi word. Rolled, so ``group_mask``
     is lowered once per group whatever the flight holds; it is traced
     once too, before the loop, in the program's own trace.
 
     The hit state is one pair for the whole window, group-major:
     ``(groups * n_nonces,)`` counts and ``(2, groups * n_nonces, cap)``
-    carry; row ``g * n_nonces + k`` is nonce ``bases[g] + k``.
-    ``group_step`` is the cached inner jit, so its body is traced and
-    lowered once however many groups the window has. Returns
-    (hit_counts', batch_counts, hit_carry') like the per-group step, each
-    over all the window's nonces, ``batch_counts`` summed over the
-    flight's sub-batches."""
-    groups = bases.shape[0]
-    ng = hit_counts.shape[0] // groups
+    carry; row ``g * n_nonces + k`` is nonce ``bases[g] + k``, the order
+    the stacked mask has. Rows are independent in every op of the
+    epilogue, so the result is the per-group step's
+    (:func:`prove_scan_step_jit`) group by group, bit for bit. Returns
+    (hit_counts', batch_counts, hit_carry') like that step, each over
+    all the window's nonces, ``batch_counts`` summed over the flight's
+    sub-batches."""
     lanes = label_words.shape[1]
     valid, start_lo, start_hi = meta[0], meta[1], meta[2]
 
-    def one_batch(words, valid, start_lo, start_hi, hit_counts, hit_carry):
+    def window_mask(words, valid, start_lo, start_hi):
         idx_lo, idx_hi = lane_indices(words.shape[1], start_lo, start_hi,
                                       lane_sharding)
-        outs = [group_step(challenge_words, bases[g], idx_lo, idx_hi,
-                           words, threshold,
-                           hit_counts[g * ng:(g + 1) * ng],
-                           hit_carry[:, g * ng:(g + 1) * ng],
-                           valid, start_lo, start_hi)
-                for g in range(groups)]
-        counts, batch_counts, carry = zip(*outs)
-        return (jnp.concatenate(counts), jnp.concatenate(batch_counts),
-                jnp.concatenate(carry, axis=1))
+        with jax.named_scope("scan_kernel"):
+            return jnp.concatenate([
+                group_mask(challenge_words, bases[g], idx_lo, idx_hi, words,
+                           threshold, valid)
+                for g in range(bases.shape[0])])
+
+    def one_batch(words, valid, start_lo, start_hi, hit_counts, hit_carry):
+        return compact_and_merge(
+            window_mask(words, valid, start_lo, start_hi), hit_counts,
+            hit_carry, start_lo, start_hi, max_hits=max_hits)
 
     if batch is None or lanes <= batch:
         return one_batch(label_words, valid, start_lo, start_hi, hit_counts,
@@ -293,14 +297,14 @@ def scan_window(group_step, challenge_words, bases, label_words, meta,
             hit_counts, hit_carry)
         return hit_counts, flight_counts + batch_counts, hit_carry
 
-    # Trace ``group_step`` HERE, in the program's own trace, and drop the
+    # Trace the kernel HERE, in the program's own trace, and drop the
     # result (dead code: the compiler removes it): the loop body's calls
-    # then find it traced. Traced for the first time inside the body's
-    # nested trace, the Pallas kernel's ~10k jnp ops cost 10-49 s of
-    # Python on a v5e's host where they cost 2.9 s here (PERF.md
-    # section 6, PR 32: measured, not explained).
-    one_batch(label_words[:, :batch], jnp.minimum(valid, jnp.uint32(batch)),
-              start_lo, start_hi, hit_counts, hit_carry)
+    # then find ``group_mask`` traced. Traced for the first time inside
+    # the body's nested trace, the Pallas kernel's ~10k jnp ops cost
+    # 10-49 s of Python on a v5e's host where they cost 2.9 s here
+    # (PERF.md section 6, PR 32: measured, not explained).
+    window_mask(label_words[:, :batch], jnp.minimum(valid, jnp.uint32(batch)),
+                start_lo, start_hi)
 
     # g < steps implies g * batch < valid, so valid - off never wraps
     steps = (valid + jnp.uint32(batch - 1)) // jnp.uint32(batch)
@@ -317,16 +321,17 @@ def prove_scan_step_window(challenge_words, bases, label_words, meta,
                            threshold, hit_counts, hit_carry, *,
                            n_nonces: int, max_hits: int,
                            batch: int | None = None, lane_sharding=None):
-    """One pipelined prove step over a whole nonce window: every group of
-    ``bases`` through :func:`prove_scan_step_jit`'s body in ONE program
-    (:func:`scan_window`) over a flight of ``batch``-lane scan steps (one
-    step where ``label_words`` is no wider), so a flight is one upload,
-    one program call and one ``(groups * n_nonces,)`` count vector back."""
+    """One pipelined prove step over a whole nonce window in ONE program
+    (:func:`scan_window`): every group of ``bases`` through the XLA scan
+    kernel, one compaction epilogue over all their rows, over a flight
+    of ``batch``-lane scan steps (one step where ``label_words`` is no
+    wider), so a flight is one upload, one program call and one
+    ``(groups * n_nonces,)`` count vector back."""
     return scan_window(
-        functools.partial(prove_scan_step_jit, n_nonces=n_nonces,
-                          max_hits=max_hits),
+        functools.partial(proving_scan_jit, n_nonces=n_nonces),
         challenge_words, bases, label_words, meta, threshold, hit_counts,
-        hit_carry, batch=batch, lane_sharding=lane_sharding)
+        hit_carry, max_hits=max_hits, batch=batch,
+        lane_sharding=lane_sharding)
 
 
 def init_hit_state(n_nonces: int, cap: int):

@@ -21,14 +21,14 @@ Output:
         ragged tail batch shares the full-batch compiled shape.
 
 One u32 of hit bits per lane is the whole kernel output (the (n_nonces, B)
-mask would be 4-16x the bytes); the surrounding jit unpacks it and
-``prove_scan_step_pallas`` runs the same compaction epilogue as the XLA
-step (ops/proving.py compact_and_merge), so the mask never crosses
-PCIe. A prove session runs ``prove_scan_step_window_pallas``: that step
-once per nonce group of the pass over each batch of one uploaded FLIGHT
-(up to eight batches, post/prover.py FLIGHT_BATCHES), in one program
-whose loop over the flight's batches is rolled, and the only D2H of a
-flight is its one count vector.
+mask would be 4-16x the bytes); the surrounding jit unpacks it, and the
+mask never crosses PCIe. A prove session runs
+``prove_scan_step_window_pallas``: over each batch of one uploaded FLIGHT
+(up to eight batches, post/prover.py FLIGHT_BATCHES) the kernel once per
+nonce group of the pass, then ONE compaction epilogue over all the
+groups' rows (ops/proving.py scan_window / compact_and_merge, the XLA
+window step's), in one program whose loop over the flight's batches is
+rolled, and the only D2H of a flight is its one count vector.
 
 Grid: lane tiles of LANE_TILE. ``interpret=True`` runs the kernel on CPU
 (the test path); on TPU the same call compiles via Mosaic.
@@ -148,26 +148,6 @@ def proving_scan_pallas(challenge_words, nonce_base, idx_lo, idx_hi,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("n_nonces", "max_hits", "interpret"),
-                   donate_argnums=(6, 7))
-def prove_scan_step_pallas(challenge_words, nonce_base, idx_lo, idx_hi,
-                           label_words, threshold, hit_counts, hit_carry,
-                           valid, start_lo, start_hi, *, n_nonces: int,
-                           max_hits: int, interpret: bool = False):
-    """Pallas-backed twin of ops.proving.prove_scan_step_jit.
-
-    Same contract: donated (hit_counts, hit_carry) device state, per-batch
-    D2H limited to the (n_nonces,) batch count vector.
-    """
-    with jax.named_scope("scan_kernel"):
-        mask = _scan_pallas(challenge_words, nonce_base, idx_lo, idx_hi,
-                            label_words, threshold, valid,
-                            n_nonces=n_nonces, interpret=interpret)
-    return proving.compact_and_merge(mask, hit_counts, hit_carry, start_lo,
-                                     start_hi, max_hits=max_hits)
-
-
-@functools.partial(jax.jit,
                    static_argnames=("n_nonces", "max_hits", "batch",
                                     "interpret"),
                    donate_argnums=(5, 6))
@@ -178,14 +158,16 @@ def prove_scan_step_window_pallas(challenge_words, bases, label_words, meta,
                                   interpret: bool = False):
     """Pallas-backed twin of ops.proving.prove_scan_step_window: the
     kernel runs once per group of ``bases`` (``n_nonces`` each) over each
-    ``batch``-lane scan step of the uploaded flight, all in one program
-    (the steps of a flight in one rolled loop: four kernel custom-calls
-    whatever it holds)."""
+    ``batch``-lane scan step of the uploaded flight, then one compaction
+    epilogue over all the groups' rows, all in one program (the steps of
+    a flight in one rolled loop: four kernel custom-calls and one
+    epilogue whatever it holds). Same contract: donated (hit_counts,
+    hit_carry) device state, the one D2H a flight its count vector."""
     return proving.scan_window(
-        functools.partial(prove_scan_step_pallas, n_nonces=n_nonces,
-                          max_hits=max_hits, interpret=interpret),
+        functools.partial(_scan_pallas, n_nonces=n_nonces,
+                          interpret=interpret),
         challenge_words, bases, label_words, meta, threshold, hit_counts,
-        hit_carry, batch=batch)
+        hit_carry, max_hits=max_hits, batch=batch)
 
 
 def proving_scan(challenge: bytes, nonce_base: int, indices, labels: np.ndarray,

@@ -165,9 +165,11 @@ def prove_window_step_sharded(mesh: Mesh, challenge_words, bases,
     batches. The program makes its lane indices under the same sharding;
     the Salsa20/8 sweep is embarrassingly parallel per lane, and GSPMD
     lowers the compaction epilogue's small reductions/gathers to ICI
-    collectives. The donated (hit_counts, hit_carry) state stays
-    replicated (see ops/proving.py merge_hits); the prover replicates it
-    via ``replicate()`` before the first batch of a pass. Batch size must
+    collectives (one epilogue a scan step over all the window's rows, so
+    a quarter of the collectives four per-group epilogues made). The
+    donated (hit_counts, hit_carry) state stays replicated (see
+    ops/proving.py merge_hits); the prover replicates it via
+    ``replicate()`` before the first batch of a pass. Batch size must
     divide by the mesh size — the prover's pad-and-trim already makes
     every batch the full ``batch_labels``.
     """

@@ -19,13 +19,15 @@ the init side's (post/initializer.py):
               (``prove_scan_step_window`` /
               ``prove_scan_step_window_pallas``), whose ROLLED loop runs
               the flight's scan steps one ``batch_labels`` wide each:
-              every nonce group of the pass, hits compacted on device
-              and merged into ONE *donated* running hit state. A ragged
-              last flight is padded to the flight shape and runs only
-              the steps that hold labels, so one shape compiles per
-              pass. A store smaller than a flight gets the power of two
-              of batches that covers it, so a one-batch store runs the
-              one-batch program; on a mesh a flight is one batch;
+              the scan kernel once per nonce group of the pass, then
+              ONE compaction epilogue over all the groups' nonce rows:
+              hits compacted on device and merged into ONE *donated*
+              running hit state. A ragged last flight is padded to the
+              flight shape and runs only the steps that hold labels, so
+              one shape compiles per pass. A store smaller than a flight
+              gets the power of two of batches that covers it, so a
+              one-batch store runs the one-batch program; on a mesh a
+              flight is one batch;
   retire    — the only D2H of a flight is ONE (window_groups *
               nonce_group,) count vector (the flight's per-nonce hits),
               its copy started right after the enqueue
@@ -78,8 +80,9 @@ DEFAULT_READER_QUEUE = 4  # prefetched flights before reader backpressure
 # 0.1 ms of lay-out copy a batch + 2 MiB over PCIe ~0.2, the program
 # call 0.32, the async copy, the engine, the retire and the reader's
 # get ~0.35: ~2.0 ms a flight of eight = 0.25 ms a scan step, against
-# 8 x 0.785 = 6.28 ms of device work (3.4 once one epilogue serves all
-# 64 rows). Four would leave 1.7 of 3.1 ms and no room for that; sixteen
+# 8 x 0.785 = 6.28 ms of device work when PR 32 chose it (about half
+# that since one epilogue serves all 64 rows, PR 35). Four would leave
+# 1.7 of 3.1 ms then and no room under the device now; sixteen
 # buys nothing more and doubles the overshoot after a decided winner
 # (inflight flights) and the reader's buffers (reader_queue flights).
 FLIGHT_BATCHES = 8
@@ -499,7 +502,10 @@ class Prover:
                 with tracing.span("prove.enqueue",
                                   {"window": nonce_base, "groups": groups,
                                    "batch": steps * b, "batches": steps,
-                                   "nonces": groups * ng, "programs": 1}
+                                   "nonces": groups * ng, "programs": 1,
+                                   # one compaction epilogue a scan step,
+                                   # over all the groups' rows
+                                   "epilogues": steps}
                                   if traced else None):
                     state[0], bc, state[1] = step(cw, bases, lw, words, thr,
                                                   *state)

@@ -15,7 +15,7 @@ import time
 import numpy as np
 import pytest
 
-from spacemesh_tpu.ops import proving, proving_pallas, scrypt
+from spacemesh_tpu.ops import proving, scrypt
 from spacemesh_tpu.post import initializer
 from spacemesh_tpu.post import prover as prover_mod
 from spacemesh_tpu.post.data import LabelReader, LabelStore, PostMetadata
@@ -355,59 +355,9 @@ def test_prove_step_high_index_batches():
         assert proving.decode_hits(counts, carry, k, cap) == want
 
 
-@pytest.mark.parametrize("backend", ["xla", "pallas"])
-@pytest.mark.parametrize("case", ["full", "ragged", "carry"])
-def test_flight_step_is_the_batch_step_sub_batch_by_sub_batch(backend, case):
-    # the flight program (one rolled loop over the flight's scan steps)
-    # against the one-batch window step applied sub-batch by sub-batch:
-    # counts, the flight's summed batch counts and the carry, bit for bit
-    import jax.numpy as jnp
-
-    b, fb, ng, groups, cap = 1024, 8, 4, 2, 40
-    f = fb * b
-    count, start = {"full": (f, 5 * f),
-                    # five steps of eight, the fifth partly valid
-                    "ragged": (4 * b + 100, 3 * b),
-                    # the carry into start_hi falls in the fourth step
-                    "carry": (f, (1 << 32) - 3 * b - 17)}[case]
-    if backend == "pallas":
-        step = proving_pallas.prove_scan_step_window_pallas
-        kw = {"n_nonces": ng, "max_hits": cap, "interpret": True}
-    else:
-        step = proving.prove_scan_step_window
-        kw = {"n_nonces": ng, "max_hits": cap}
-    rng = np.random.default_rng(32)
-    words = rng.integers(0, 1 << 32, size=(4, f), dtype=np.uint32)
-    words[:, count:] = 0
-    cw = jnp.asarray(proving.challenge_words(CH))
-    bases = jnp.asarray(7 + ng * np.arange(groups), jnp.uint32)
-    # ~6 hits a row a scan step: the slots fill over the whole flight
-    thr = jnp.uint32(proving.threshold_u32(48, f))
-
-    def meta(n, at):
-        return jnp.asarray([n, at & 0xFFFFFFFF, at >> 32], jnp.uint32)
-
-    got = step(cw, bases, jnp.asarray(words), meta(count, start), thr,
-               *proving.init_hit_state(groups * ng, cap), batch=b, **kw)
-    counts, carry = proving.init_hit_state(groups * ng, cap)
-    summed = np.zeros(groups * ng, np.int64)
-    steps = -(-count // b)
-    for g in range(steps):
-        counts, bc, carry = step(
-            cw, bases, jnp.asarray(words[:, g * b:(g + 1) * b]),
-            meta(min(b, count - g * b), start + g * b), thr, counts, carry,
-            **kw)
-        summed += np.asarray(bc)
-    assert steps == {"full": 8, "ragged": 5, "carry": 8}[case]
-    assert summed.min() > 0
-    if case == "full":      # some row overflows its slots: hits drop
-        assert int(np.asarray(counts).max()) > cap
-    assert np.array_equal(got[0], counts)
-    assert np.array_equal(got[1], summed)
-    assert np.array_equal(got[2], carry)
-    if case == "carry":     # hits on both sides of 2^32
-        his = np.asarray(got[2])[1]
-        assert (his == 0).any() and (his == 1).any()
+# (the flight program against the per-group reference step, sub-batch by
+# sub-batch, ragged, full and across 2^32, on both backends:
+# tests/test_proving_pallas.py test_window_step_equals_per_group_steps)
 
 
 def test_a_flight_is_a_whole_number_of_scan_steps():

@@ -37,8 +37,10 @@ def test_pallas_scan_padding():
 
 
 def _step_both(count, batch, nonce_base, n_nonces, start=0, max_hits=8):
-    """Run the compacted prove step through Pallas (interpret) and XLA on
-    the same padded batch; return both (counts, decoded hits) sets."""
+    """Run the compacted prove step through XLA (the per-group reference
+    step) and Pallas (interpret; the window step over ONE group: the
+    same code as over four, with one mask) on the same padded batch;
+    return both (counts, decoded hits) sets."""
     import jax.numpy as jnp
 
     idx = np.arange(start, start + batch, dtype=np.uint64)
@@ -52,10 +54,14 @@ def _step_both(count, batch, nonce_base, n_nonces, start=0, max_hits=8):
             jnp.asarray(scrypt.labels_to_words(padded)), jnp.uint32(t))
     tail = (jnp.uint32(count), jnp.uint32(start & 0xFFFFFFFF),
             jnp.uint32(start >> 32))
+    def window_of_one_group(cw, base, _lo, _hi, lw, thr, counts, carry,
+                            *tail, **kw):
+        return proving_pallas.prove_scan_step_window_pallas(
+            cw, base[None], lw, jnp.stack(tail), thr, counts, carry,
+            interpret=True, **kw)
+
     out = []
-    for step in (proving.prove_scan_step_jit,
-                 lambda *a, **kw: proving_pallas.prove_scan_step_pallas(
-                     *a, interpret=True, **kw)):
+    for step in (proving.prove_scan_step_jit, window_of_one_group):
         counts, carry = proving.init_hit_state(n_nonces, max_hits)
         counts, bc, carry = step(*args, counts, carry, *tail,
                                  n_nonces=n_nonces, max_hits=max_hits)
@@ -116,57 +122,109 @@ def _window_backends():
             "mesh": sharded}
 
 
-@pytest.mark.parametrize("backend", ["xla", "pallas", "mesh"])
-def test_window_step_equals_per_group_steps(backend):
-    # one window-step program a batch == ``groups`` per-group steps a
-    # batch, bit for bit (counts, batch counts, carry), over two batches:
-    # the second a ragged tail whose lanes cross 2^32, so the device-made
-    # indices carry into the hi word where the host-made ones did
+_WINDOW_CASES = [(backend, shape, groups)
+                 for backend in ("xla", "pallas", "mesh")
+                 # a mesh's flight is one batch (post/prover.flight_batches)
+                 for shape in (("one",) if backend == "mesh"
+                               else ("one", "flight_ragged", "flight_full"))
+                 for groups in (1, 4)]
+
+
+@pytest.mark.parametrize("backend, shape, groups", _WINDOW_CASES)
+def test_window_step_equals_per_group_steps(backend, shape, groups):
+    # ONE compaction epilogue a scan step over all the window's rows ==
+    # the plain per-group step (its own epilogue over its own rows),
+    # group by group and sub-batch by sub-batch, bit for bit: counts,
+    # the flight's summed batch counts, the carry. "one" is the program
+    # with no loop over four consecutive batches (the last ragged);
+    # "flight_ragged" one rolled program of eight scan steps whose
+    # seventh is ragged and whose eighth is empty; "flight_full" all
+    # eight. The lanes cross 2^32 half way through the FIRST scan step,
+    # while slots are free: the hits' lo words wrap inside the step, and
+    # start_lo carries into start_hi from the flight's second step on
     import jax.numpy as jnp
 
-    groups, ng, cap, b = 3, 4, 8, 1024
-    first = (1 << 32) - b - 100
-    batches = [(first, b), (first + b, 700)]
-    bases = 5 + ng * np.arange(groups)
-    cw = jnp.asarray(proving.challenge_words(CH))
-    # ~4 hits a nonce a batch: some rows fill partly, some overflow ``cap``
-    thr = jnp.uint32(proving.threshold_u32(4, b))
+    ng, cap, b, fb = 8, 4, 1024, 8
+    rows = groups * ng
+    first = (1 << 32) - b // 2 - 17
+    if shape == "one":
+        width, kw = b, {}
+        calls = [(first + i * b, b) for i in range(3)] + [(first + 3 * b, 700)]
+    else:
+        width, kw = fb * b, {"batch": b}
+        calls = [(first, width if shape == "flight_full" else 6 * b + 100)]
     step = _window_backends()[backend]
-    state = proving.init_hit_state(groups * ng, cap)
-    ref = [proving.init_hit_state(ng, cap) for _ in range(groups)]
-    for start, count in batches:
-        idx = np.arange(start, start + b, dtype=np.uint64)
-        labels = np.zeros((b, scrypt.LABEL_BYTES), np.uint8)
-        labels[:count] = scrypt.scrypt_labels(COMMIT, idx[:count], n=2)
-        lw = scrypt.labels_to_words(labels)
-        words = [count, start & 0xFFFFFFFF, start >> 32]
+    rng = np.random.default_rng(35)
+    cw = jnp.asarray(proving.challenge_words(CH))
+    bases = 5 + ng * np.arange(groups)
+    # ~2.5 hits a row a scan step against 4 slots: rows with none and
+    # rows with more than max_hits in ONE step both occur
+    thr = jnp.uint32(proving.threshold_u32(5, 2 * b))
+    # rows that start at 0, at cap, past cap, and just under it; the
+    # slots already filled hold marks the merge must leave alone
+    counts0 = np.resize([0, cap, cap - 1, 0, cap + 5, 1, 0, cap - 2],
+                        rows).astype(np.int32)
+    carry0 = np.full((2, rows, cap), 0xFFFFFFFF, np.uint32)
+    for r in range(rows):
+        filled = min(counts0[r], cap)
+        carry0[0, r, :filled] = 1000 * r + np.arange(filled)
+        carry0[1, r, :filled] = 0
+    state = (jnp.asarray(counts0), jnp.asarray(carry0))
+    ref = [(jnp.asarray(counts0[g * ng:(g + 1) * ng]),
+            jnp.asarray(carry0[:, g * ng:(g + 1) * ng]))
+           for g in range(groups)]
+    seen = []       # (counts before the scan step, its batch counts)
+    for start, count in calls:
+        lw = rng.integers(0, 1 << 32, size=(4, width), dtype=np.uint32)
+        lw[:, count:] = 0
         counts, bc, carry = step(
             cw, jnp.asarray(bases, jnp.uint32), jnp.asarray(lw),
-            jnp.asarray(words, jnp.uint32), thr, *state,
-            n_nonces=ng, max_hits=cap)
+            jnp.asarray([count, start & 0xFFFFFFFF, start >> 32],
+                        jnp.uint32),
+            thr, *state, n_nonces=ng, max_hits=cap, **kw)
         state = (counts, carry)
-        lo, hi = scrypt.split_indices(idx)
-        want_bc = []
-        for g in range(groups):
-            c, gbc, h = proving.prove_scan_step_jit(
+        want_bc = np.zeros(rows, np.int64)
+        for sub in range(-(-count // b)):
+            at = start + sub * b
+            lo, hi = scrypt.split_indices(
+                np.arange(at, at + b, dtype=np.uint64))
+            before = np.concatenate([np.asarray(c) for c, _ in ref])
+            outs = [proving.prove_scan_step_jit(
                 cw, jnp.uint32(bases[g]), jnp.asarray(lo), jnp.asarray(hi),
-                jnp.asarray(lw), thr, *ref[g], *map(jnp.uint32, words),
-                n_nonces=ng, max_hits=cap)
-            ref[g] = (c, h)
-            want_bc.append(np.asarray(gbc))
-        assert np.array_equal(np.asarray(bc), np.concatenate(want_bc))
+                jnp.asarray(lw[:, sub * b:(sub + 1) * b]), thr, *ref[g],
+                jnp.uint32(min(b, count - sub * b)),
+                jnp.uint32(at & 0xFFFFFFFF), jnp.uint32(at >> 32),
+                n_nonces=ng, max_hits=cap) for g in range(groups)]
+            ref = [(o[0], o[2]) for o in outs]
+            sub_bc = np.concatenate([np.asarray(o[1]) for o in outs])
+            seen.append((before, sub_bc))
+            want_bc += sub_bc
+        assert np.array_equal(np.asarray(bc), want_bc)
         assert np.array_equal(np.asarray(counts), np.concatenate(
             [np.asarray(c) for c, _ in ref]))
         assert np.array_equal(np.asarray(carry), np.concatenate(
             [np.asarray(h) for _, h in ref], axis=1))
-    assert np.asarray(bc).shape == (groups * ng,)
-    got = np.asarray(state[0])
-    assert got.min() < cap < got.max(), "want rows under AND over cap"
-    # the carried indices are global, and past 2^32 where they should be
-    rows = [proving.decode_hits(*state, k, cap) for k in range(groups * ng)]
-    assert all(r == sorted(r) and first <= r[0] and r[-1] < first + b + 700
-               for r in rows)
-    assert any(r[-1] >= 1 << 32 for r in rows)
+    assert len(seen) == {"one": 4, "flight_ragged": 7, "flight_full": 8}[shape]
+    # what the cases are here to meet, met in the reference's own figures
+    before, sub_bc = (np.stack(x) for x in zip(*seen))
+    assert (sub_bc > cap).any(), "no row with more than max_hits in a step"
+    assert (sub_bc == 0).any(), "no row with no hit in a step"
+    assert ((before == 0) & (sub_bc > 0)).any()
+    assert ((before >= cap) & (sub_bc > 0)).any(), "hits past cap drop"
+    assert ((before < cap) & (before + sub_bc > cap)).any(), \
+        "no row crossed cap inside a step"
+    got_counts, got_carry = (np.asarray(x) for x in state)
+    # the marks stand, and what was merged is global and past 2^32
+    # where it should be
+    for r in range(rows):
+        filled = min(counts0[r], cap)
+        assert np.array_equal(got_carry[0, r, :filled],
+                              1000 * r + np.arange(filled))
+        new = proving.decode_hits(got_counts, got_carry, r, cap)[filled:]
+        assert new == sorted(new)
+        assert all(first <= i < calls[-1][0] + calls[-1][1] for i in new)
+    his = got_carry[1][got_carry[0] != 0xFFFFFFFF]
+    assert (his == 0).any() and (his == 1).any()
 
 
 def test_lane_indices_carry_into_the_hi_word():

@@ -98,23 +98,47 @@ step_args = (sds((8,)), sds(()), sds((b,)), sds((b,)), sds((4, b)),
              sds(()), sds(()))
 build("prove_step_xla", proving.prove_scan_step_jit, *step_args,
       n_nonces=ng, max_hits=cap)
-build("prove_step_pallas", proving_pallas.prove_scan_step_pallas,
-      *step_args, n_nonces=ng, max_hits=cap, interpret=False)
 build("prove_mask_pallas", proving_pallas.proving_scan_pallas,
       *step_args[:6], n_nonces=ng, interpret=False)
+
+
+def computations_of(text):
+    # {computation name: its lines} of a compiled module's text
+    found, name = {}, None
+    for line in text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            name = line.split()[1 if line.startswith("ENTRY") else 0]
+            found[name.lstrip("%")] = []
+        elif name is not None:
+            found[name.lstrip("%")].append(line)
+    return found
+
+
+def is_kernel(line):
+    return "custom-call" in line and "_scan_pallas" in line
+
+
+def is_while(line):     # the op, not a computation's or a value's name
+    return " while(" in line
+
+
 # ... and the window steps a session runs: every nonce group of a pass
 # (4 on tpu) in one program over one uploaded batch, indices made on
-# the device
+# the device. The compaction epilogue's searchsorted is the one loop
+# such a program holds: ONE since a scan step runs one epilogue over
+# all the groups' rows (four at bdb9bbf, one a group)
 groups = 4
 window_args = (sds((8,)), sds((groups,)), sds((4, b)), sds((3,)), sds(()),
                sds((groups * ng,), jnp.int32), sds((2, groups * ng, cap)))
-build("prove_window_xla", proving.prove_scan_step_window, *window_args,
-      n_nonces=ng, max_hits=cap)
+c = build("prove_window_xla", proving.prove_scan_step_window, *window_args,
+          n_nonces=ng, max_hits=cap)
+out["prove_window_xla"]["whiles"] = sum(
+    map(is_while, c.as_text().splitlines()))
 c = build("prove_window_pallas", proving_pallas.prove_scan_step_window_pallas,
           *window_args, n_nonces=ng, max_hits=cap, interpret=False)
-out["prove_window_pallas"]["kernel_calls"] = sum(
-    "custom-call" in line and "_scan_pallas" in line
-    for line in c.as_text().splitlines())
+lines = c.as_text().splitlines()
+out["prove_window_pallas"].update(kernel_calls=sum(map(is_kernel, lines)),
+                                  whiles=sum(map(is_while, lines)))
 # ... over a FLIGHT of eight batches (post/prover.py FLIGHT_BATCHES): what
 # a default Prover runs on any store of eight batches or more. The loop
 # over the flight's scan steps is rolled: the kernel's four custom-calls
@@ -124,23 +148,21 @@ flight_args = (window_args[:2] + (sds((4, prover.FLIGHT_BATCHES * b)),)
 c = build("prove_flight_pallas", proving_pallas.prove_scan_step_window_pallas,
           *flight_args, n_nonces=ng, max_hits=cap, batch=b, interpret=False)
 text = c.as_text()
-computations, name = {}, None
-for line in text.splitlines():
-    if line and not line[0].isspace() and line.rstrip().endswith("{"):
-        name = line.split()[1 if line.startswith("ENTRY") else 0].lstrip("%")
-        computations[name] = []
-    elif name is not None:
-        computations[name].append(line)
-holders = [n for n, body in computations.items()
-           if any("custom-call" in ln and "_scan_pallas" in ln for ln in body)]
+lines = text.splitlines()
+computations = computations_of(text)
+holders = [n for n, body in computations.items() if any(map(is_kernel, body))]
 out["prove_flight_pallas"].update(
-    module=text.splitlines()[0].split()[1].rstrip(","),
-    kernel_calls=sum("custom-call" in ln and "_scan_pallas" in ln
-                     for ln in text.splitlines()),
+    module=lines[0].split()[1].rstrip(","),
+    kernel_calls=sum(map(is_kernel, lines)),
     kernel_computations=len(holders),
     while_bodies_holding_the_kernel=sum(
         f"body=%{n}," in ln or ln.rstrip().endswith(f"body=%{n}")
-        for n in holders for body in computations.values() for ln in body))
+        for n in holders for body in computations.values() for ln in body),
+    # the epilogue's searchsorted loops a scan step: the while ops in the
+    # computation that holds the kernels (the rolled loop's body)
+    whiles_beside_the_kernel=sum(
+        map(is_while, (ln for n in holders for ln in computations[n]))),
+    whiles=sum(map(is_while, lines)))
 
 # k2pow: search (one 2^16-nonce batch) and batched witness verification
 b = 1 << 16
@@ -182,7 +204,7 @@ def test_tpu_default_programs_compile_for_v5e(lowered):
     assert lowered["devices"] == 4
     # the Pallas scan step is the tpu default (and stays reachable via
     # use_pallas=True), so Mosaic must keep compiling it
-    for name in ("labels_8192", "prove_step_xla", "prove_step_pallas",
+    for name in ("labels_8192", "prove_step_xla",
                  "prove_mask_pallas", "prove_window_xla",
                  "prove_window_pallas", "prove_flight_pallas", "pow_hash",
                  "pow_below_target", "pow_verify"):
@@ -205,6 +227,21 @@ def test_flight_program_is_one_rolled_loop_around_the_four_kernels(lowered):
     assert flight["kernel_computations"] == 1
     assert flight["while_bodies_holding_the_kernel"] == 1
     assert "prove_scan_step" in flight["module"]
+
+
+@pytest.mark.parametrize("program, whiles", [
+    ("prove_window_xla", 1), ("prove_window_pallas", 1),
+    # the rolled loop itself, and the one nested in its body
+    ("prove_flight_pallas", 2)])
+def test_a_scan_step_holds_one_compaction_epilogue(lowered, program, whiles):
+    # the epilogue's vmap(searchsorted) lowers to a while loop of 8-9
+    # dependent steps, the one loop a scan step holds: ONE over all 64
+    # nonce rows where bdb9bbf compiled four, one a 16-row nonce group
+    # (4 / 4 / 5 while ops in these three programs there), with the four
+    # kernel custom-calls beside it in the flight's rolled body
+    assert lowered[program]["whiles"] == whiles
+    if program == "prove_flight_pallas":
+        assert lowered[program]["whiles_beside_the_kernel"] == 1
 
 
 def test_labels_shard_over_four_chips_without_collectives(lowered):

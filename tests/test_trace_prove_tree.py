@@ -161,6 +161,10 @@ def test_a_batch_crosses_the_boundary_once_each_way(capture):
         assert enq["args"]["programs"] == 1
         assert enq["args"]["batches"] == -(-d["args"]["count"] // BATCH)
         assert enq["args"]["groups"] == GROUPS
+        assert enq["args"]["nonces"] == GROUPS * NG
+        # ONE compaction epilogue a scan step over all the groups' rows
+        # (ISSUE 35; it would read batches x groups at bdb9bbf)
+        assert enq["args"]["epilogues"] == enq["args"]["batches"]
     flights = named["device.flight"]
     assert len(flights) == len(named["prove.retire"])
     # a pass of the store is three flights of 8 + 8 + 4 scan steps
