@@ -181,6 +181,7 @@ class ProverStats:
     batches: int = 0          # label batches (scan steps) dispatched
     flights: int = 0          # device calls that carried them
     retire_ready: int = 0     # flights whose counts landed before retire
+    flights_abandoned: int = 0  # dispatched, dropped by an early exit
     labels_swept: int = 0     # labels covered across all passes
     read_wait_s: float = 0.0  # blocked on the reader pool
     read_io_s: float = 0.0    # filesystem time inside the reader pool
@@ -207,52 +208,58 @@ class Prover:
                  mesh="auto",
                  stall_deadline_s: float = 30.0,
                  fs=None):
-        # load() raises typed PostMetaCorrupt on a torn/truncated
-        # metadata file and clears crash-leftover staging tmps; label
-        # reads below get bounded EIO retry (LabelStore._pread_retry),
-        # so one transient medium error cannot abort a multi-window
-        # disk pass
-        self.meta = PostMetadata.load(data_dir, fs=fs)
-        if self.meta.labels_written < self.meta.total_labels:
-            raise ValueError("POST data is not fully initialized")
-        self.store = LabelStore(data_dir, self.meta, fs=fs)
-        self.params = params or ProofParams()
-        self.nonce_group = nonce_group
-        self._platform = jax.devices()[0].platform
-        if use_pallas is None:
-            # the Pallas scan step (ops/proving_pallas.py) is the default
-            # wherever it runs compiled: under Mosaic it matched
-            # prove_scan_step_jit bit for bit on a v5e (PERF.md Bring-up);
-            # anywhere else it would only interpret
-            use_pallas = not accel.pallas_interpret()
-        self.use_pallas = use_pallas
-        # pipelined batches share one compiled shape: round the batch up to
-        # the compaction segment (and the Pallas lane tile on that path),
-        # then to its power-of-two shape bucket, so two Provers configured
-        # with nearby batch sizes (grpc worker tenants, test fixtures)
-        # land on ONE prove_scan_step executable instead of minting one
-        # each (ops/scrypt.py shape_bucket; both tiles are powers of two,
-        # so bucketing preserves the tile multiple)
-        self.batch_labels = bucket_batch(batch_labels, use_pallas)
-        if pipelined is None:
-            pipelined = os.environ.get(
-                "SPACEMESH_PROVE_PIPELINE", "1") not in ("0", "off")
-        self.pipelined = pipelined
-        self.window_groups = max(window_groups if window_groups is not None
-                                 else default_window_groups(self._platform),
-                                 1)
-        self.inflight = max(inflight if inflight is not None
-                            else _env_int("SPACEMESH_PROVE_INFLIGHT",
-                                          DEFAULT_INFLIGHT), 1)
-        self.readers = max(readers if readers is not None
-                           else _env_int("SPACEMESH_PROVE_READERS",
-                                         DEFAULT_READERS), 1)
-        self.reader_queue = max(reader_queue if reader_queue is not None
-                                else _env_int("SPACEMESH_PROVE_QUEUE",
-                                              DEFAULT_READER_QUEUE), 1)
-        self._mesh_arg = mesh
-        self.stall_deadline_s = stall_deadline_s
-        self.last_stats: ProverStats | None = None
+        # a PostClient builds one per challenge: under a capture this is
+        # the proof's first span (metadata, store, the device query)
+        with tracing.span("prove.open"):
+            # load() raises typed PostMetaCorrupt on a torn/truncated
+            # metadata file and clears crash-leftover staging tmps; label
+            # reads below get bounded EIO retry (LabelStore._pread_retry),
+            # so one transient medium error cannot abort a multi-window
+            # disk pass
+            self.meta = PostMetadata.load(data_dir, fs=fs)
+            if self.meta.labels_written < self.meta.total_labels:
+                raise ValueError("POST data is not fully initialized")
+            self.store = LabelStore(data_dir, self.meta, fs=fs)
+            self.params = params or ProofParams()
+            self.nonce_group = nonce_group
+            self._platform = jax.devices()[0].platform
+            if use_pallas is None:
+                # the Pallas scan step (ops/proving_pallas.py) is the
+                # default wherever it runs compiled: under Mosaic it
+                # matched prove_scan_step_jit bit for bit on a v5e
+                # (PERF.md Bring-up); anywhere else it would only
+                # interpret
+                use_pallas = not accel.pallas_interpret()
+            self.use_pallas = use_pallas
+            # pipelined batches share one compiled shape: round the batch
+            # up to the compaction segment (and the Pallas lane tile on
+            # that path), then to its power-of-two shape bucket, so two
+            # Provers configured with nearby batch sizes (grpc worker
+            # tenants, test fixtures) land on ONE prove_scan_step
+            # executable instead of minting one each (ops/scrypt.py
+            # shape_bucket; both tiles are powers of two, so bucketing
+            # preserves the tile multiple)
+            self.batch_labels = bucket_batch(batch_labels, use_pallas)
+            if pipelined is None:
+                pipelined = os.environ.get(
+                    "SPACEMESH_PROVE_PIPELINE", "1") not in ("0", "off")
+            self.pipelined = pipelined
+            self.window_groups = max(
+                window_groups if window_groups is not None
+                else default_window_groups(self._platform), 1)
+            self.inflight = max(inflight if inflight is not None
+                                else _env_int("SPACEMESH_PROVE_INFLIGHT",
+                                              DEFAULT_INFLIGHT), 1)
+            self.readers = max(readers if readers is not None
+                               else _env_int("SPACEMESH_PROVE_READERS",
+                                             DEFAULT_READERS), 1)
+            self.reader_queue = max(
+                reader_queue if reader_queue is not None
+                else _env_int("SPACEMESH_PROVE_QUEUE",
+                              DEFAULT_READER_QUEUE), 1)
+            self._mesh_arg = mesh
+            self.stall_deadline_s = stall_deadline_s
+            self.last_stats: ProverStats | None = None
 
     # -- mesh routing (mirrors post/initializer.py) -------------------------
 
@@ -438,28 +445,33 @@ class Prover:
                             "labels": total}
                            if tracing.is_enabled() else None)
         wsp.__enter__()
-        reader = None
+        reader = pipe = None
         try:
-            # flight-sized ranges: one reader.get() is a flight's bytes
-            # in one object, and the host concatenates nothing
-            ranges = [(s, min(f, total - s)) for s in range(0, total, f)]
-            # ONE donated hit state for the whole window, group-major;
-            # a mesh changes placement and nothing else
-            state = list(proving.init_hit_state(groups * ng, cap))
-            where = None
-            if mesh is not None:
-                from ..parallel import mesh as pmesh
-                state = [pmesh.replicate(mesh, x) for x in state]
-                where = pmesh.prove_batch_shardings(mesh)
-            host_counts = np.zeros(ng * groups, dtype=np.int64)
-            # the groups' base nonces go up once a pass; a flight's count
-            # and start travel with its labels (a device scalar made
-            # here would be a host->device transfer of its own: 0.5 ms
-            # each on a v5e's host, PERF.md section 6)
-            bases = jnp.asarray(
-                nonce_base + ng * np.arange(groups), dtype=jnp.uint32)
-            reader = self.store.start_reader(ranges, self.readers,
-                                             self.reader_queue)
+            with tracing.span("prove.prepare",
+                              {"what": "pass", "window": nonce_base}
+                              if tracing.is_enabled() else None):
+                # flight-sized ranges: one reader.get() is a flight's
+                # bytes in one object, and the host concatenates nothing
+                ranges = [(s, min(f, total - s))
+                          for s in range(0, total, f)]
+                # ONE donated hit state for the whole window,
+                # group-major; a mesh changes placement and nothing else
+                state = list(proving.init_hit_state(groups * ng, cap))
+                where = None
+                if mesh is not None:
+                    from ..parallel import mesh as pmesh
+                    state = [pmesh.replicate(mesh, x) for x in state]
+                    where = pmesh.prove_batch_shardings(mesh)
+                host_counts = np.zeros(ng * groups, dtype=np.int64)
+                # the groups' base nonces go up once a pass; a flight's
+                # count and start travel with its labels (a device
+                # scalar made here would be a host->device transfer of
+                # its own: 0.5 ms each on a v5e's host, PERF.md
+                # section 6)
+                bases = jnp.asarray(
+                    nonce_base + ng * np.arange(groups), dtype=jnp.uint32)
+                reader = self.store.start_reader(ranges, self.readers,
+                                                 self.reader_queue)
             metrics.post_prove_windows.inc()
             stats.windows += 1
             retired_end = [0]
@@ -546,9 +558,18 @@ class Prover:
                 pipe.stats.dispatch_s - (stats.read_wait_s - rw0), 0.0)
             scanned = retired_end[0] if exited else total
         finally:
-            if reader is not None:
-                reader.close()
-                stats.read_io_s += reader.read_seconds
+            with tracing.span("prove.drain", {"window": nonce_base}
+                              if tracing.is_enabled() else None):
+                if reader is not None:
+                    reader.close()
+                    stats.read_io_s += reader.read_seconds
+            if pipe is not None:
+                # flights an early exit dropped: the device still runs
+                # them, and the decode below waits for the last one
+                abandoned = pipe.stats.abandoned
+                stats.flights_abandoned += abandoned
+                metrics.post_prove_flights_abandoned.inc(abandoned)
+                wsp.set(flights=pipe.stats.batches, abandoned=abandoned)
             wsp.__exit__(None, None, None)
         if exited:
             metrics.post_prove_early_exits.inc()
@@ -559,9 +580,15 @@ class Prover:
             return None, None
         w = int(qualified[0])
         counts, carry = state
-        indices = proving.decode_hits(counts, carry, w, p.k2)
-        stats.d2h_bytes += carry.nbytes + counts.nbytes
-        metrics.post_prove_d2h_bytes.inc(carry.nbytes + counts.nbytes)
+        d2h = carry.nbytes + counts.nbytes
+        with tracing.span("prove.decode",
+                          {"window": nonce_base, "d2h_bytes": d2h}
+                          if tracing.is_enabled() else None):
+            # its two fetches block until the LAST enqueued flight has
+            # run, abandoned ones included
+            indices = proving.decode_hits(counts, carry, w, p.k2)
+        stats.d2h_bytes += d2h
+        metrics.post_prove_d2h_bytes.inc(d2h)
         return nonce_base + w, indices
 
     def _retire(self, item, host_counts, total, stats,
@@ -673,11 +700,13 @@ class ProveSession:
             self.pow_nonce = p._pow(self.challenge)
             return None
         if self._prep is None:
-            thr = jnp.uint32(proving.threshold_u32(
-                p.params.k1, p.meta.total_labels))
-            cw = jnp.asarray(proving.challenge_words(self.challenge))
-            stepfn, mesh, _ = p.scan_step()
-            self._prep = (cw, thr, mesh, stepfn)
+            with tracing.span("prove.prepare", {"what": "session"}
+                              if tracing.is_enabled() else None):
+                thr = jnp.uint32(proving.threshold_u32(
+                    p.params.k1, p.meta.total_labels))
+                cw = jnp.asarray(proving.challenge_words(self.challenge))
+                stepfn, mesh, _ = p.scan_step()
+                self._prep = (cw, thr, mesh, stepfn)
         cw, thr, mesh, stepfn = self._prep
         if self._base >= self._max_nonce:
             raise RuntimeError("no winning nonce found (k1/k2 mismatch?)")
@@ -710,13 +739,16 @@ class ProveSession:
 
         health_mod.HEALTH.unregister("post.prove", self._wd.check)
         self._span.__exit__(None, None, None)
-        stats = self.stats
-        stats.elapsed_s = time.monotonic() - self._t0
-        if stats.elapsed_s > 0:
-            metrics.post_prove_labels_per_sec.set(
-                stats.labels_swept / stats.elapsed_s)
-        for stage, secs in (("read", stats.read_wait_s),
-                            ("dispatch", stats.dispatch_s),
-                            ("retire", stats.retire_s)):
-            metrics.post_prove_stage_seconds.inc(secs, stage=stage)
-        self.prover.store.close()
+        # after the session's span: prove.close nests in what encloses
+        # the session (prove.proof) instead of straddling prove.run's end
+        with tracing.span("prove.close"):
+            stats = self.stats
+            stats.elapsed_s = time.monotonic() - self._t0
+            if stats.elapsed_s > 0:
+                metrics.post_prove_labels_per_sec.set(
+                    stats.labels_swept / stats.elapsed_s)
+            for stage, secs in (("read", stats.read_wait_s),
+                                ("dispatch", stats.dispatch_s),
+                                ("retire", stats.retire_s)):
+                metrics.post_prove_stage_seconds.inc(secs, stage=stage)
+            self.prover.store.close()

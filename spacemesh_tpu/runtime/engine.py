@@ -82,6 +82,9 @@ class PipelineStats:
     dispatch_s: float = 0.0   # host time enqueueing device work
     retire_s: float = 0.0     # blocked consuming results
     fallbacks: int = 0        # dispatch exceptions absorbed by fallback
+    abandoned: int = 0        # dispatched, never retired: dropped on an
+    #                           early exit or a stop (the device may
+    #                           still run them)
     early_exited: bool = False
     stopped: bool = False
 
@@ -208,6 +211,11 @@ class Pipeline:
         metrics.runtime_dispatched.inc(kind=self.kind, tenant=self.tenant)
         return ticket
 
+    def _abandon(self) -> None:
+        """Drop the in-flight tickets unretired, counting them."""
+        self.stats.abandoned += len(self._pending)
+        self._pending.clear()
+
     def _retire_one(self, retire, ticket):
         t0 = time.perf_counter()
         try:
@@ -236,7 +244,7 @@ class Pipeline:
                     stats.stopped = True
                     # stop contract: discard in-flight device work, the
                     # caller persists whatever already retired
-                    pending.clear()
+                    self._abandon()
                     return None
                 if item is IDLE:
                     if pending:
@@ -244,7 +252,7 @@ class Pipeline:
                         self._set_inflight(len(pending))
                         if result is not None:
                             stats.early_exited = True
-                            pending.clear()
+                            self._abandon()
                             return result
                     continue
                 pending.append(self._dispatch_one(dispatch, item))
@@ -254,18 +262,18 @@ class Pipeline:
                     self._set_inflight(len(pending))
                     if result is not None:
                         stats.early_exited = True
-                        pending.clear()  # abandon: the result is final
+                        self._abandon()  # the result is final
                         return result
             while pending:
                 if self._stop is not None and self._stop():
                     stats.stopped = True
-                    pending.clear()
+                    self._abandon()
                     return None
                 result = self._retire_one(retire, pending.popleft())
                 self._set_inflight(len(pending))
                 if result is not None:
                     stats.early_exited = True
-                    pending.clear()
+                    self._abandon()
                     return result
             return None
         finally:

@@ -384,6 +384,10 @@ post_prove_flights = REGISTRY.counter(
 post_prove_early_exits = REGISTRY.counter(
     "post_prove_early_exits_total",
     "prove passes cut short once the winning nonce was decided")
+post_prove_flights_abandoned = REGISTRY.counter(
+    "post_prove_flights_abandoned_total",
+    "flights dispatched and never retired: dropped by an early exit "
+    "while the device still runs them (at most inflight - 1 a pass)")
 post_prove_stage_seconds = REGISTRY.counter(
     "post_prove_stage_seconds_total",
     "host seconds per prove pipeline stage (label=stage)")

@@ -41,6 +41,15 @@ Controls:
   ``jax.profiler.TraceAnnotation`` so host spans line up with XLA
   device traces inside a ``jax.profiler.trace()`` capture on TPU.
 
+A context-manager span also records the CPU time its thread used
+between enter and exit (``time.thread_time_ns``) as ``cpu_us`` in its
+``args``: the part of its wall time the thread computed rather than
+waited (on the GIL, a lock, a device, I/O). It is left out where it
+would mislead: a span exited on another thread than the one that
+entered it, and a span entered on a thread that runs an asyncio event
+loop (every task interleaved across the span's awaits would be counted
+as its own). ``interval()`` and ``instant()`` carry none.
+
 ``interval(name, t0_ns)`` records a completed span from a start the
 caller took earlier (a device program's flight: enqueued here, fetched
 there). From the first ``start()`` in a process that has imported JAX,
@@ -68,6 +77,7 @@ parent edges the single-process tracer could never draw.
 
 from __future__ import annotations
 
+import asyncio
 import contextvars
 import itertools
 import json
@@ -158,7 +168,7 @@ class _Span:
     """A live span: a context manager that records itself on exit."""
 
     __slots__ = ("_tracer", "name", "cat", "attrs", "parent", "id",
-                 "_t0", "_token", "_ann")
+                 "_t0", "_token", "_ann", "_cpu0", "_tid")
 
     def __init__(self, tracer: "Tracer", name: str, attrs, parent, cat):
         self._tracer = tracer
@@ -190,10 +200,24 @@ class _Span:
                 tracer.jax_bridge = False
                 self._ann = None
         self._t0 = time.perf_counter_ns()
+        # read inside the wall clock's two reads, so cpu_us <= dur
+        if asyncio._get_running_loop() is None:
+            self._tid = threading.get_ident()
+            self._cpu0 = time.thread_time_ns()
+        else:
+            self._cpu0 = None
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        cpu = None
+        if self._cpu0 is not None and threading.get_ident() == self._tid:
+            cpu = time.thread_time_ns() - self._cpu0
         t1 = time.perf_counter_ns()
+        if cpu is not None:
+            if self.attrs is None:
+                self.attrs = {"cpu_us": cpu // 1000}
+            else:
+                self.attrs["cpu_us"] = cpu // 1000
         if self._ann is not None:
             try:
                 self._ann.__exit__(exc_type, exc, tb)

@@ -14,7 +14,9 @@ import pytest
 ROOT = Path(__file__).resolve().parent.parent
 PER_LAYER = {"prove_dispatch_ms", "prove_upload_ms", "prove_retire_ms",
              "prove_read_wait_share", "prove_h2d_bytes_per_label",
-             "prove_fixed_ms", "scan_labels_per_s"}
+             "prove_fixed_ms", "scan_labels_per_s",
+             # a proof's edges (ISSUE 36)
+             "prove_edge_ms", "prove_host_cpu_ms", "prove_abandoned_share"}
 
 
 def _run(seconds, trace):
@@ -48,6 +50,9 @@ def test_last_line_is_the_contracts_object(trace):
         assert metrics["prove_h2d_bytes_per_label"]["value"] \
             == 16.0 + 12 / (2 * 16384)
         assert 0 <= line["device"]["busy_s"] <= line["device"]["window_s"]
+        # a thread's CPU time cannot pass its wall time
+        assert metrics["prove_host_cpu_ms"]["value"] \
+            <= metrics["prove_dispatch_ms"]["value"]
     else:
         assert sorted(metrics) == ["p50_ms", "setup_s"]
         assert metrics["p50_ms"]["value"] > 0

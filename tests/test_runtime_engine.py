@@ -74,6 +74,38 @@ def test_pipeline_stop_discards_pending():
     assert retired == []  # discarded, never retired
 
 
+@pytest.mark.parametrize("case", ["full", "exit_in_stream",
+                                  "exit_in_drain", "exit_on_idle",
+                                  "stop_in_stream", "stop_in_drain"])
+def test_pipeline_counts_the_tickets_it_abandons(case):
+    """``stats.abandoned`` is every ticket dispatched and never retired,
+    whichever of the five places dropped it (ISSUE 36)."""
+    dispatched, retired = [], []
+    stop = [False]
+    n = 6
+    items = list(range(n))
+    if case == "exit_on_idle":
+        items = [0, 1, engine.IDLE, 2]
+    winner = {"exit_in_stream": 1, "exit_in_drain": n - 2,
+              "exit_on_idle": 0}.get(case)
+
+    def dispatch(i):
+        dispatched.append(i)
+        if (case == "stop_in_stream" and i == 3) or (
+                case == "stop_in_drain" and i == n - 1):
+            stop[0] = True
+        return i
+
+    def retire(t):
+        retired.append(t)
+        return "won" if t == winner else None
+
+    pipe = engine.Pipeline(kind="t", inflight=3, stop=lambda: stop[0])
+    pipe.run(iter(items), dispatch, retire)
+    assert pipe.stats.abandoned == len(dispatched) - len(retired)
+    assert (pipe.stats.abandoned > 0) == (case != "full"), retired
+
+
 def test_pipeline_fallback_on_dispatch_failure():
     before = sum(metrics.runtime_fallbacks.sample().values())
 

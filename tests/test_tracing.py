@@ -158,6 +158,81 @@ def test_span_records_parenting_and_attrs():
     assert evs["outer"]["dur"] >= evs["inner"]["dur"] >= 0
 
 
+# --- thread CPU time on spans (ISSUE 36) --------------------------------
+
+
+def _by_name(doc):
+    return {e["name"]: e for e in doc["traceEvents"] if e["ph"] != "M"}
+
+
+def test_sync_span_carries_its_threads_cpu_time():
+    tracing.start(capacity=64)
+    with tracing.span("compute"):
+        t_end = time.thread_time() + 0.02
+        while time.thread_time() < t_end:
+            pass
+    with tracing.span("wait"):
+        time.sleep(0.05)
+    evs = _by_name(tracing.export())
+    compute, wait = evs["compute"], evs["wait"]
+    # what the thread computed is CPU time, and never more than the wall
+    assert 20_000 <= compute["args"]["cpu_us"] <= compute["dur"]
+    # a span that waits is long on the wall clock and short on the CPU
+    assert wait["dur"] >= 50_000
+    assert 0 <= wait["args"]["cpu_us"] < wait["dur"] // 5
+
+
+def test_span_entered_on_an_event_loop_thread_carries_no_cpu_time():
+    # on a loop's thread the tasks interleaved across a span's awaits
+    # would be counted as its own; a span in a worker thread the loop
+    # hands work to runs on no loop and keeps it
+    tracing.start(capacity=64)
+
+    def in_worker():
+        with tracing.span("worker"):
+            pass
+
+    async def main():
+        with tracing.span("sync_on_loop"):
+            await asyncio.sleep(0)
+        async with tracing.span("async_on_loop"):
+            await asyncio.to_thread(in_worker)
+
+    asyncio.run(main())
+    evs = _by_name(tracing.export())
+    assert "cpu_us" not in evs["sync_on_loop"]["args"]
+    assert "cpu_us" not in evs["async_on_loop"]["args"]
+    assert 0 <= evs["worker"]["args"]["cpu_us"] <= evs["worker"]["dur"]
+
+
+def test_span_exited_on_another_thread_carries_no_cpu_time():
+    import contextvars
+
+    tracing.start(capacity=64)
+    ctx = contextvars.copy_context()    # one context, two threads
+    sp = tracing.span("handed_over")
+    ctx.run(sp.__enter__)
+    t = threading.Thread(target=ctx.run,
+                         args=(sp.__exit__, None, None, None))
+    t.start()
+    t.join(timeout=10)
+    assert not t.is_alive()
+    ev = _by_name(tracing.export())["handed_over"]
+    assert "cpu_us" not in ev["args"]
+
+
+def test_interval_and_instant_carry_no_cpu_time():
+    tracing.start(capacity=64)
+    t0 = time.perf_counter_ns()
+    with tracing.span("outer"):
+        tracing.interval("device.flight", t0, {"program": "p"})
+        tracing.instant("mark")
+    evs = _by_name(tracing.export())
+    assert "cpu_us" in evs["outer"]["args"]
+    assert "cpu_us" not in evs["device.flight"]["args"]
+    assert "cpu_us" not in evs["mark"]["args"]
+
+
 def test_async_context_propagation():
     tracing.start(capacity=64)
 
