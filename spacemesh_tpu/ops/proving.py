@@ -140,14 +140,15 @@ def compact_hits(mask, *, max_hits: int):
     seg_csum = jnp.cumsum(seg_sum, axis=1)
     batch_counts = seg_csum[:, -1]
     targets = jnp.arange(1, max_hits + 1, dtype=jnp.int32)
-    # segment holding each nonce's j-th hit (binary search per row)
-    seg = jax.vmap(
-        lambda row: jnp.searchsorted(row, targets, side="left"))(seg_csum)
+    # segment holding each nonce's j-th hit: the number of segment
+    # cumsums below j (a row's cumsums never decrease, so this is
+    # searchsorted(row, j, side="left")), by compare-and-count: no loop
+    # of dependent steps, which a binary search lowers to; the hits
+    # before that segment are the largest cumsum below j (0 if none)
+    below = seg_csum[:, None, :] < targets[None, :, None]
+    seg = jnp.sum(below, axis=-1, dtype=jnp.int32)
+    prev = jnp.max(jnp.where(below, seg_csum[:, None, :], 0), axis=-1)
     segc = jnp.minimum(seg, nseg - 1)
-    prev = jnp.where(seg > 0,
-                     jnp.take_along_axis(seg_csum,
-                                         jnp.maximum(seg - 1, 0), axis=1),
-                     0)
     rank = targets[None, :] - prev             # 1-based rank within segment
     seg_lanes = jnp.take_along_axis(m3, segc[:, :, None], axis=1)
     within = jnp.cumsum(seg_lanes.astype(jnp.int32), axis=-1)
@@ -236,7 +237,10 @@ def scan_window(group_mask, challenge_words, bases, label_words, meta,
     window: what an epilogue costs is mostly fixed (its cumsum, gathers
     and two scatters, and the slices and concatenates four per-group
     epilogues needed), so one over 64 rows took a scan step from 0.80 to
-    0.43 ms on a v5e (PERF.md section 6, PR 35).
+    0.43 ms on a v5e; it holds no loop (:func:`compact_hits` finds each
+    hit's segment by compare-and-count, where a binary search of 8-9
+    dependent steps was half the step), which took the step to 0.20 ms
+    in a bare loop (PERF.md section 6).
 
     ``meta`` is the flight's three u32 words ``[valid, start_lo,
     start_hi]``, uploaded beside ``label_words`` (4, lanes); the lane

@@ -355,6 +355,68 @@ def test_prove_step_high_index_batches():
         assert proving.decode_hits(counts, carry, k, cap) == want
 
 
+def _masks():
+    """{case: (mask, max_hits)} for compact_hits against numpy: every
+    place a segment search can land, and the cell's own shape."""
+    rng = np.random.default_rng(37)
+    seg = proving.HIT_SEGMENT
+    some = rng.random((16, 1024)) < 0.02
+    exactly = np.zeros((16, 1024), bool)
+    for r in range(16):
+        exactly[r, rng.choice(1024, 8 + r % 2, replace=False)] = True
+    last = np.zeros((16, 1024), bool)
+    last[::3, -1] = True
+    straddle = np.zeros((16, 1024), bool)
+    for r in range(16):     # runs across each segment boundary
+        at = seg * (1 + r % 15)
+        straddle[r, at - 1 - r % 3:at + 2 + r % 4] = True
+    # one segment holding the whole batch
+    one_seg = rng.random((16, seg)) < 0.12
+    one_seg[0], one_seg[1] = False, True
+    # the cell's scan step: 64 nonce rows x 16,384 lanes at 26 hits a
+    # nonce over 2^23 labels, and at ~60 hits a row (past max_hits)
+    p_cell = proving.threshold_u32(26, 1 << 23) / 2.0 ** 32
+    cell = rng.random((64, 16384)) < p_cell
+    busy = rng.random((64, 16384)) < 60 / 16384
+    return {
+        "rows_with_no_hits": (some * (np.arange(16) % 2)[:, None] > 0, 8),
+        "no_hits_at_all": (np.zeros((16, 1024), bool), 8),
+        "max_hits_and_one_more": (exactly, 8),
+        "every_lane": (np.ones((16, 1024), bool), 8),
+        "last_lane_of_last_segment": (last, 8),
+        "straddling_segment_boundaries": (straddle, 4),
+        "one_segment": (one_seg, 8),
+        "cell_step_at_cell_rate": (cell, 37),
+        "cell_step_past_max_hits": (busy, 37),
+    }
+
+
+@pytest.mark.parametrize("case", list(_masks()))
+def test_compact_hits_matches_numpy(case):
+    # compact_hits alone against np.nonzero, row by row: every program
+    # equivalence test compares two programs that share it, so this is
+    # the test a wrong segment search would fail
+    import functools
+
+    import jax
+
+    mask, max_hits = _masks()[case]
+    counts, pos, ok = (np.asarray(x) for x in jax.jit(functools.partial(
+        proving.compact_hits, max_hits=max_hits))(mask))
+    assert counts.dtype == np.int32 and pos.dtype == np.uint32
+    assert np.array_equal(counts, mask.sum(axis=1))
+    for r, row in enumerate(mask):
+        want = np.nonzero(row)[0][:max_hits]
+        assert np.array_equal(pos[r][ok[r]], want), r
+        assert np.array_equal(ok[r], np.arange(max_hits) < len(want)), r
+    if case == "max_hits_and_one_more":
+        assert set(counts) == {max_hits, max_hits + 1}
+    if case == "cell_step_at_cell_rate":
+        assert 0 < counts.sum() and counts.max() < max_hits
+    if case == "cell_step_past_max_hits":
+        assert counts.max() > max_hits
+
+
 # (the flight program against the per-group reference step, sub-batch by
 # sub-batch, ragged, full and across 2^32, on both backends:
 # tests/test_proving_pallas.py test_window_step_equals_per_group_steps)

@@ -124,9 +124,10 @@ def is_while(line):     # the op, not a computation's or a value's name
 
 # ... and the window steps a session runs: every nonce group of a pass
 # (4 on tpu) in one program over one uploaded batch, indices made on
-# the device. The compaction epilogue's searchsorted is the one loop
-# such a program holds: ONE since a scan step runs one epilogue over
-# all the groups' rows (four at bdb9bbf, one a group)
+# the device. The compaction epilogue holds no loop: it finds each
+# nonce's j-th hit segment by compare-and-count, where a binary search
+# lowered to one `while` an epilogue (four a scan step at bdb9bbf, one
+# an epilogue a nonce group; one at 1de6202, one epilogue over all rows)
 groups = 4
 window_args = (sds((8,)), sds((groups,)), sds((4, b)), sds((3,)), sds(()),
                sds((groups * ng,), jnp.int32), sds((2, groups * ng, cap)))
@@ -158,8 +159,8 @@ out["prove_flight_pallas"].update(
     while_bodies_holding_the_kernel=sum(
         f"body=%{n}," in ln or ln.rstrip().endswith(f"body=%{n}")
         for n in holders for body in computations.values() for ln in body),
-    # the epilogue's searchsorted loops a scan step: the while ops in the
-    # computation that holds the kernels (the rolled loop's body)
+    # loops nested in a scan step: the while ops in the computation that
+    # holds the kernels (the rolled loop's body)
     whiles_beside_the_kernel=sum(
         map(is_while, (ln for n in holders for ln in computations[n]))),
     whiles=sum(map(is_while, lines)))
@@ -230,18 +231,20 @@ def test_flight_program_is_one_rolled_loop_around_the_four_kernels(lowered):
 
 
 @pytest.mark.parametrize("program, whiles", [
-    ("prove_window_xla", 1), ("prove_window_pallas", 1),
-    # the rolled loop itself, and the one nested in its body
-    ("prove_flight_pallas", 2)])
-def test_a_scan_step_holds_one_compaction_epilogue(lowered, program, whiles):
-    # the epilogue's vmap(searchsorted) lowers to a while loop of 8-9
-    # dependent steps, the one loop a scan step holds: ONE over all 64
-    # nonce rows where bdb9bbf compiled four, one a 16-row nonce group
-    # (4 / 4 / 5 while ops in these three programs there), with the four
-    # kernel custom-calls beside it in the flight's rolled body
+    ("prove_window_xla", 0), ("prove_window_pallas", 0),
+    # the flight's rolled loop over its scan steps, and nothing in its body
+    ("prove_flight_pallas", 1)])
+def test_the_compaction_epilogue_holds_no_loop(lowered, program, whiles):
+    # the epilogue counts, for each nonce row and each j <= max_hits, the
+    # segment cumsums below j: compares and a sum, no dependent steps.
+    # The vmap(searchsorted) it replaced lowered to a while loop of 8-9
+    # dependent steps, half the flight program's device time (1 / 1 / 2
+    # while ops in these three programs at 1de6202; 4 / 4 / 5 at bdb9bbf,
+    # one epilogue a 16-row nonce group); the four kernel custom-calls
+    # sit alone in the flight's rolled body
     assert lowered[program]["whiles"] == whiles
     if program == "prove_flight_pallas":
-        assert lowered[program]["whiles_beside_the_kernel"] == 1
+        assert lowered[program]["whiles_beside_the_kernel"] == 0
 
 
 def test_labels_shard_over_four_chips_without_collectives(lowered):
