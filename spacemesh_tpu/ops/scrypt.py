@@ -106,20 +106,20 @@ def _device_bytes(device) -> int:
 def lane_ceiling(n: int, devices=None) -> int:
     """The widest label program ONE device runs, in lanes: the largest
     power of two whose ROMix scratch (``128 * r * N`` bytes a lane, r=1)
-    fits in :data:`V_MEMORY_SHARE` of the device's memory, as its
-    ``memory_stats()`` reports it (the smallest of ``devices``; default:
-    the default device). A lane-sharded batch holds that many lanes on
-    EACH chip of its mesh. At N=8192 on a 16 GB v5e chip: 8,192 lanes,
-    8 GiB of V. Nothing sets it: a batch wider than this runs as lane
-    tiles (post/verifier.py).
-
-    What the rule does not see: it is static. It reads the device's
-    LIMIT, not what is free (``bytes_in_use``), so a process that holds
-    gigabytes there already (an init running beside the farm) can still
-    be refused a full tile; the share was checked on one kind of chip
-    (any limit from 10.7 to 21.3 GiB gives 8,192 lanes at N=8192); and
-    a platform that reports nothing counts as a v5e, so a CPU run tiles
-    where a v5e would, whatever the host's memory."""
+    fits in :data:`V_MEMORY_SHARE` of the memory ``memory_stats()``
+    reports (the smallest of ``devices``; default: the default device).
+    At N=8192 on a 16 GB v5e chip: 8,192 lanes, 8 GiB of V. Nothing sets
+    it: a wider batch runs as lane tiles (post/verifier.py). On a mesh
+    the verifier's ceiling is chips x this one, and a group up to it is
+    ONE tile in its whole-batch power-of-two bucket, in equal slices a
+    chip: the padding lands on the last chips (four v5e chips run a 256-
+    proof batch at K3 = 37 as one 16,384-lane program, the last chip all
+    padding). What the rule does not see: it is static. It reads the
+    device's LIMIT, not what is free (``bytes_in_use``), so a process
+    holding gigabytes there (an init beside the farm) can still be
+    refused a full tile; the share was checked on one kind of chip (any
+    limit from 10.7 to 21.3 GiB gives 8,192 lanes at N=8192); and a
+    platform reporting nothing counts as a v5e, whatever the host has."""
     devices = list(devices) if devices is not None else jax.devices()[:1]
     room = V_MEMORY_SHARE * min(_device_bytes(d) for d in devices)
     lanes = int(room // (128 * n))

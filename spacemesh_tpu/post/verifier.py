@@ -84,7 +84,12 @@ def verify_many(items: list[VerifyItem], params: ProofParams | None = None,
     padding, not a proof), those ``host_rejected``, the ``lanes_valid``
     K3 indices they send to the device, the ``lanes`` dispatched after
     both paddings, the lane ``tiles`` (label programs) they went as,
-    blocking device->host ``syncs``, ``h2d_bytes`` and ``d2h_bytes``.
+    blocking device->host ``syncs``, ``h2d_bytes`` and ``d2h_bytes``;
+    and where the lanes ran: ``chips``, the mesh size of the widest
+    tile (1 off a mesh), and per chip of it ``chip_lanes_valid`` and
+    ``chip_lanes``, the real and the dispatched lanes summed over the
+    tiles (a tile on k chips holds width/k consecutive lanes on each,
+    so a tile's padding, at its end, lands on its last chips).
     """
     import os
 
@@ -93,7 +98,8 @@ def verify_many(items: list[VerifyItem], params: ProofParams | None = None,
         seed = os.urandom(32)
     # the span holds this dict: filled in as the call goes
     tr = ({"proofs": 0, "host_rejected": 0, "lanes_valid": 0, "lanes": 0,
-           "tiles": 0, "syncs": 0, "h2d_bytes": 0, "d2h_bytes": 0}
+           "tiles": 0, "syncs": 0, "h2d_bytes": 0, "d2h_bytes": 0,
+           "chips": 1, "chip_lanes_valid": [], "chip_lanes": []}
           if tracing.is_enabled() else None)
     with tracing.span("post.verify", tr):
         return _verify_many(items, p, seed, tr)
@@ -105,7 +111,13 @@ def _lane_tiles(b: int, n: int) -> list[tuple[int, int]]:
     where such a batch runs (ops/scrypt.lane_ceiling lanes on each chip
     of its mesh), then what remains in its power-of-two shape bucket. So
     the executable population stays the buckets up to the ceiling, and
-    at ``b`` up to the ceiling there is one tile: the group's bucket."""
+    at ``b`` up to the ceiling there is one tile: the group's bucket.
+    On a mesh that ceiling is the mesh's (chips x the per-chip one), so
+    a group up to it is ONE tile in its whole-batch bucket, sharded in
+    equal slices: its padding, at the end, lands on the last chips (a
+    node's 256-proof batch at K3 = 37 on four v5e chips: 8,880-9,472
+    real lanes in 16,384, two chips full, one part full, one all
+    padding)."""
     mesh = pmesh.auto_mesh(scrypt.shape_bucket(b))
     if mesh is None:
         ceiling = scrypt.lane_ceiling(n)
@@ -116,6 +128,21 @@ def _lane_tiles(b: int, n: int) -> list[tuple[int, int]]:
     if rest:
         tiles.append((b - rest, scrypt.shape_bucket(rest)))
     return tiles
+
+
+def _count_chip_lanes(tr: dict, tiles: list, chips: list, b: int) -> None:
+    """Add one flight's lanes to the span's per-chip counts: a tile of
+    ``width`` lanes on k chips puts lanes ``[at + c*width/k, at +
+    (c+1)*width/k)`` on chip c (the lane axis shards in equal slices, in
+    order), of which those below ``b`` are real; a tile off a mesh is
+    chip 0's."""
+    for key in ("chip_lanes_valid", "chip_lanes"):
+        tr[key] += [0] * (max(chips) - len(tr[key]))
+    for (at, width), k in zip(tiles, chips):
+        per = width // k
+        for c in range(k):
+            tr["chip_lanes_valid"][c] += max(0, min(per, b - at - c * per))
+            tr["chip_lanes"][c] += per
 
 
 def _verify_many(items: list[VerifyItem], p: ProofParams, seed: bytes,
@@ -180,7 +207,7 @@ def _verify_many(items: list[VerifyItem], p: ProofParams, seed: bytes,
             # batches, so one executable of each program serves every
             # occupancy of a bucket and no eager device op pads or trims
             tiles = _lane_tiles(b, n)
-            host, where = [], []
+            host, where, chips = [], [], []
             for at, width in tiles:
                 take = min(width, b - at)
                 host.append([scrypt.pad_lanes(a[..., at:at + take],
@@ -189,6 +216,7 @@ def _verify_many(items: list[VerifyItem], p: ProofParams, seed: bytes,
                 # like one (parallel/mesh.py auto_mesh). Placement is
                 # the only thing a mesh changes.
                 mesh = pmesh.auto_mesh(width)
+                chips.append(1 if mesh is None else mesh.size)
                 if mesh is None:
                     where.append(None)
                 else:
@@ -214,15 +242,20 @@ def _verify_many(items: list[VerifyItem], p: ProofParams, seed: bytes,
                     scrypt.scrypt_labels_jit(cw8, lo, hi, n=n))))
             metrics.post_verify_label_programs.inc(lanes=width)
         vals = jax.device_get(out)
+        # tiles are powers of two: the widest one shards the widest
+        metrics.post_verify_mesh_devices.set(max(chips))
         if tr is not None:
             tracing.interval("device.flight", t0,
                              {"program": "labels_proving", "lanes": bb,
-                              "tiles": len(out), "d2h_bytes": 4 * bb})
+                              "tiles": len(out), "d2h_bytes": 4 * bb,
+                              "chips": max(chips)})
             tr["lanes"] += bb
             tr["tiles"] += len(out)
             tr["syncs"] += 1
             tr["h2d_bytes"] += h2d
             tr["d2h_bytes"] += 4 * bb
+            tr["chips"] = max(tr["chips"], *chips)
+            _count_chip_lanes(tr, tiles, chips, b)
         # each tile's own lanes, its padding dropped
         values[sel] = np.concatenate(
             [v[:b - at] for v, (at, _width) in zip(vals, tiles)])

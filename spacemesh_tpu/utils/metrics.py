@@ -296,6 +296,13 @@ post_verify_label_programs = REGISTRY.counter(
     "post_verify_label_programs_total",
     "label programs enqueued by POST verification (label: lanes, the "
     "program's whole width, mesh-wide where it is sharded)")
+# the chips the widest label program of the last verify flight ran on
+# (parallel/mesh.py auto_mesh; 1 = one device), as post_mesh_devices
+# is for init
+post_verify_mesh_devices = REGISTRY.gauge(
+    "post_verify_mesh_devices",
+    "device count the widest label program of the last POST verify "
+    "flight was sharded over (1 = single device)")
 
 # POST init streaming pipeline (post/initializer.py). Stage seconds carry a
 # stage label (dispatch/fetch/write/stall) so an operator can see where a

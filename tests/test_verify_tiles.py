@@ -226,6 +226,60 @@ def test_on_the_virtual_mesh_the_ceiling_is_per_chip(b, low_ceiling,
     assert len(_named(evs, "device.flight")) == 1
 
 
+@pytest.mark.parametrize("ceiling, b, real, sent", [
+    # one tile in its whole-batch bucket: 64 lanes, 16 a chip
+    (1 << 20, 37, [16, 16, 5, 0], [16, 16, 16, 16]),
+    # 32-lane mesh tiles, the rest (11 lanes) in a 16-lane one
+    (CEILING, 2 * 4 * CEILING + 11, [20, 20, 19, 16], [20, 20, 20, 20]),
+], ids=["untiled", "tiled"])
+def test_on_the_virtual_mesh_each_chips_edges_fail_their_owner(
+        ceiling, b, real, sent, monkeypatch):
+    """Four virtual devices: a bad index at the first and the last real
+    lane of each chip's slice of each tile fails exactly its proof,
+    as the plain reference has it; the span counts each chip's real and
+    dispatched lanes and the gauge says four chips."""
+    monkeypatch.setattr(scrypt, "lane_ceiling",
+                        lambda n, devices=None: ceiling)
+    monkeypatch.setenv("SPACEMESH_MESH", "4")
+    tiles = verifier._lane_tiles(b, 4)
+    bad = set()
+    for at, width in tiles:
+        per = width // 4
+        for c in range(4):
+            lo, hi = at + c * per, min(at + (c + 1) * per, b)
+            if lo < hi:
+                bad |= {lo, hi - 1}
+    items = [_with_index(i, 4, i not in bad) for i in range(b)]
+    want = [i not in bad for i in range(b)]
+    assert [_want(it) for it in items] == want
+    got, evs = _traced(items)
+    assert got == want
+    (call,) = _named(evs, "post.verify")
+    a = call["args"]
+    assert a["chips"] == 4
+    assert a["chip_lanes_valid"] == real and a["chip_lanes"] == sent
+    assert sum(a["chip_lanes_valid"]) == a["lanes_valid"] == b
+    assert sum(a["chip_lanes"]) == a["lanes"]
+    (flight,) = _named(evs, "device.flight")
+    assert flight["args"]["chips"] == 4
+    assert metrics.post_verify_mesh_devices._values.get(()) == 4
+
+
+def test_off_the_mesh_one_chip_holds_every_lane(low_ceiling, monkeypatch):
+    monkeypatch.delenv("SPACEMESH_MESH", raising=False)
+    items = [_item(i, 4) for i in range(2 * CEILING + 3)]
+    metrics.post_verify_mesh_devices.set(4)
+    got, evs = _traced(items)
+    assert got == [_want(it) for it in items]
+    (call,) = _named(evs, "post.verify")
+    a = call["args"]
+    assert a["chips"] == 1
+    assert a["chip_lanes_valid"] == [2 * CEILING + 3]
+    assert a["chip_lanes"] == [a["lanes"]] == [2 * CEILING + 4]
+    assert _named(evs, "device.flight")[0]["args"]["chips"] == 1
+    assert metrics.post_verify_mesh_devices._values.get(()) == 1
+
+
 def test_the_ceiling_comes_from_the_devices_memory():
     """128 * N bytes of V a lane, in three quarters of what the device
     reports: the largest power of two of lanes. A 16 GB v5e chip
