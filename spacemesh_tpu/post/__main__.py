@@ -9,6 +9,9 @@ exposes Providers()/Benchmark()).
 Usage:
   python -m spacemesh_tpu.post init --data-dir D --node-id-hex .. \
       --commitment-hex .. --num-units 1 --labels-per-unit 1024 [--scrypt-n N]
+  python -m spacemesh_tpu.post init --identities IDS.json --num-units 4 \
+      --labels-per-unit 4294967296   # IDS.json: [{data_dir, node_id,
+                                     # commitment}, ...], hex ids
   python -m spacemesh_tpu.post prove --data-dir D --challenge-hex ..
   python -m spacemesh_tpu.post verify --data-dir D --proof-file P.json \
       --challenge-hex ..
@@ -31,8 +34,56 @@ def _hex32(s: str) -> bytes:
     return b
 
 
+def _identities(path: str) -> list:
+    """``--identities``: a JSON list of ``{data_dir, node_id,
+    commitment}`` (the two ids as 32 bytes of hex)."""
+    out = []
+    for entry in json.loads(Path(path).read_text()):
+        try:
+            out.append((entry["data_dir"], _hex32(entry["node_id"]),
+                        _hex32(entry["commitment"])))
+        except (KeyError, TypeError, ValueError,
+                argparse.ArgumentTypeError) as e:
+            raise SystemExit(f"{path}: bad identity {entry!r}: {e}")
+    return out
+
+
+def cmd_init_many(a) -> int:
+    """Every identity of ``--identities`` through ONE tenant scheduler:
+    a progress line per identity as its segments land, then one JSON
+    line per identity."""
+    from . import initializer
+
+    ids = _identities(a.identities)
+
+    def progress(i, done, total):
+        print(f"{ids[i][0]}: {done}/{total} labels "
+              f"({100 * done / total:.1f}%)", file=sys.stderr, flush=True)
+
+    res = initializer.initialize_many(
+        ids, num_units=a.num_units, labels_per_unit=a.labels_per_unit,
+        scrypt_n=a.scrypt_n, max_file_size=a.max_file_size,
+        progress=progress)
+    for (d, _, _), meta in zip(ids, res.metas):
+        print(json.dumps({
+            "data_dir": str(d),
+            "labels_written": meta.labels_written,
+            "vrf_nonce": -1 if meta.vrf_nonce is None else meta.vrf_nonce,
+            "elapsed_s": round(res.elapsed_s, 2)}))
+    return 0
+
+
 def cmd_init(a) -> int:
     from . import initializer
+
+    if a.identities is not None:
+        if a.data_dir or a.node_id_hex or a.commitment_hex:
+            raise SystemExit("init: --identities replaces --data-dir, "
+                             "--node-id-hex and --commitment-hex")
+        return cmd_init_many(a)
+    if not (a.data_dir and a.node_id_hex and a.commitment_hex):
+        raise SystemExit("init: --data-dir, --node-id-hex and "
+                         "--commitment-hex are required (or --identities)")
 
     def progress(done, total):
         print(f"\r{done}/{total} labels ({100 * done / total:.1f}%)",
@@ -179,9 +230,14 @@ def main(argv=None) -> int:
     sub = p.add_subparsers(dest="cmd", required=True)
 
     pi = sub.add_parser("init", help="fill a POST data directory with labels")
-    pi.add_argument("--data-dir", required=True)
-    pi.add_argument("--node-id-hex", type=_hex32, required=True)
-    pi.add_argument("--commitment-hex", type=_hex32, required=True)
+    pi.add_argument("--data-dir")
+    pi.add_argument("--node-id-hex", type=_hex32)
+    pi.add_argument("--commitment-hex", type=_hex32)
+    pi.add_argument("--identities", default=None, metavar="FILE",
+                    help="initialize many identities through one tenant "
+                    "scheduler: a JSON list of {data_dir, node_id, "
+                    "commitment} (hex); the geometry and --scrypt-n are "
+                    "shared")
     pi.add_argument("--num-units", type=int, required=True)
     pi.add_argument("--labels-per-unit", type=int, required=True)
     pi.add_argument("--scrypt-n", type=int, default=8192)

@@ -39,6 +39,7 @@ Progress/status mirrors the reference's state machine
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import enum
 import errno
@@ -444,48 +445,22 @@ class Initializer:
         self._save_meta(writer, stats)
 
     def _save_meta(self, writer: LabelWriter, stats: PipelineStats) -> None:
-        """Persist resume metadata. Ordering rule: the cursor is the
-        writer's durable (contiguous-FSYNCED) label count — never the
-        dispatch or fetch frontier, and never bytes merely handed to
-        the OS. ``checkpoint()`` fsyncs the dirty label files first and
-        hands back the interval CRC the recovery path verifies on
-        reopen (docs/CRASH_SAFETY.md)."""
-        meta = self.meta
+        """Persist resume metadata under the one ordering rule
+        (:func:`save_checkpoint`): the cursor is the writer's durable
+        (contiguous-FSYNCED) label count — never the dispatch or fetch
+        frontier, and never bytes merely handed to the OS."""
         t0 = time.perf_counter()
         if self.save_barrier:
             writer.drain()
-        # ENOSPC on the checkpoint fsync or the metadata save degrades
-        # exactly like an ENOSPC label write: the save path parks (the
-        # post.store probe flips, /readyz shows degraded), retries on
-        # the writer's interval/kick, and the session survives
-        while True:
-            try:
-                durable, crc = writer.checkpoint()
-                break
-            except OSError as e:
-                if e.errno != errno.ENOSPC or not writer.enospc_wait:
-                    raise
-                writer.wait_for_space("label-file fsync")
+        nonce = value = None
         decoded = scrypt.vrf_carry_decode(self._snapshot)
-        meta.labels_written = durable
-        prev_end = meta.intervals[-1][0] if meta.intervals else 0
-        if durable > prev_end:
-            meta.intervals.append([durable, crc])
         if decoded is not None:
-            idx, (hi, lo) = decoded
-            meta.vrf_nonce = idx
-            meta.vrf_nonce_value = (
-                lo.to_bytes(8, "little") + hi.to_bytes(8, "little")).hex()
-        with tracing.span("init.save_meta", {"durable": durable}
-                          if tracing.is_enabled() else None):
-            while True:
-                try:
-                    meta.save(self.store.dir, fs=self.store.fs)
-                    break
-                except OSError as e:
-                    if e.errno != errno.ENOSPC or not writer.enospc_wait:
-                        raise
-                    writer.wait_for_space("metadata save")
+            nonce, (hi, lo) = decoded
+            value = (lo.to_bytes(8, "little")
+                     + hi.to_bytes(8, "little")).hex()
+        save_checkpoint(self.meta, self.store, writer.checkpoint,
+                        vrf_nonce=nonce, vrf_value=value, writer=writer,
+                        span="init.save_meta")
         writer.clear_degraded()
         stats.meta_saves += 1
         stats.save_s += time.perf_counter() - t0
@@ -496,6 +471,54 @@ class Initializer:
         # would re-trip the label trigger on every retire (a checkpoint
         # storm — fsync + durable metadata write per batch)
         self._last_save_labels = self._resume_at + writer.labels_submitted
+
+
+def save_checkpoint(meta: PostMetadata, store: LabelStore,
+                    cut: Callable[[], tuple[int, int]], *,
+                    vrf_nonce: int | None = None,
+                    vrf_value: str | None = None,
+                    writer: LabelWriter | None = None,
+                    span: str | None = None) -> int:
+    """Persist one identity's resume metadata under the ordering rule
+    every init path keeps (the single-identity :class:`Initializer` and
+    the scheduler's packed jobs, runtime/scheduler.py;
+    docs/CRASH_SAFETY.md).
+
+    ``cut()`` makes the written prefix durable FIRST: it fsyncs the
+    dirty label files and returns ``(durable, crc)``, the contiguous
+    fsynced label count and the CRC32 of [previous checkpoint, durable)
+    (``LabelWriter.checkpoint``). Then the cursor moves to ``durable``,
+    never past it; the interval joins the checkpoint ledger that
+    ``recover_store`` verifies on reopen; the VRF carry
+    (``vrf_nonce``, ``vrf_value``: the index and the LE-u128 label hex)
+    is recorded; and the metadata is written durably, under a ``span``
+    of that name if one is given. ENOSPC on the fsync or the save parks
+    on ``writer``'s space wait where it has one (the ``post.store``
+    probe reads degraded meanwhile). -> the durable cursor."""
+
+    def retry(fn, what: str):
+        while True:
+            try:
+                return fn()
+            except OSError as e:
+                if (e.errno != errno.ENOSPC or writer is None
+                        or not writer.enospc_wait):
+                    raise
+                writer.wait_for_space(what)
+
+    durable, crc = retry(cut, "label-file fsync")
+    meta.labels_written = durable
+    prev_end = meta.intervals[-1][0] if meta.intervals else 0
+    if durable > prev_end:
+        meta.intervals.append([durable, crc])
+    if vrf_nonce is not None:
+        meta.vrf_nonce = vrf_nonce
+        meta.vrf_nonce_value = vrf_value
+    with (tracing.span(span, {"durable": durable}
+                       if tracing.is_enabled() else None)
+          if span else contextlib.nullcontext()):
+        retry(lambda: meta.save(store.dir, fs=store.fs), "metadata save")
+    return durable
 
 
 def open_or_create_meta(data_dir: Path, *, node_id: bytes,
@@ -563,3 +586,80 @@ def initialize(data_dir: str | Path, *, node_id: bytes, commitment: bytes,
                        fs=fs, **pipeline_opts)
     res = init.run()
     return meta, res
+
+
+@dataclasses.dataclass
+class ManyResult:
+    """What :func:`initialize_many` leaves: each identity's metadata as
+    saved (in the order given), and what the scheduler ran."""
+
+    metas: list
+    elapsed_s: float
+    tenants: int       # tenants the scheduler had registered
+    pack_lanes: int    # lanes it composed a pack to at this scrypt N
+    widest_pack: int   # the widest packed program it dispatched (lanes)
+
+
+def initialize_many(identities, *, num_units: int, labels_per_unit: int,
+                    scrypt_n: int = 8192,
+                    max_file_size: int = 64 * 1024 * 1024,
+                    progress: Callable[[int, int, int], None] | None = None,
+                    stop=None, fs=None, **scheduler_opts) -> ManyResult:
+    """Create-or-resume many identities' inits through ONE
+    :class:`~spacemesh_tpu.runtime.scheduler.TenantScheduler`: one
+    tenant and one packed ``submit_init`` per identity, all sharing the
+    device (the multi-identity form of ``python -m spacemesh_tpu.post
+    init``). ``identities`` is a list of ``(data_dir, node_id,
+    commitment)``; the geometry and N are shared. Every identity's
+    metadata is checkpointed during the run under :func:`save_checkpoint`'s
+    rule, so a crash loses at most one interval of each.
+
+    ``progress(i, done, total)`` reports identity ``i``'s fetch frontier
+    after each of its retired segments (the packer's thread). Setting the
+    ``stop`` event (a ``threading.Event``) cancels every job: each
+    checkpoints what is durable and this returns. ``scheduler_opts`` pass
+    through to the scheduler (tests pin ``pack_lanes``; the defaults are
+    the deployment's).
+    Raises the first identity's error once every job has resolved."""
+    from ..runtime.scheduler import TenantScheduler
+
+    t0 = time.monotonic()
+    dirs = [Path(d) for d, _, _ in identities]
+    with TenantScheduler(**scheduler_opts) as sched:
+        tids: list[str] = []
+        handles: list = []
+        try:
+            for i, (d, node_id, commitment) in enumerate(identities):
+                tid = f"id{i}-{node_id.hex()[:8]}"
+                sched.register_tenant(tid)
+                tids.append(tid)
+                handles.append(sched.submit_init(
+                    tid, d, node_id=node_id, commitment=commitment,
+                    num_units=num_units, labels_per_unit=labels_per_unit,
+                    scrypt_n=scrypt_n, max_file_size=max_file_size,
+                    progress=(None if progress is None else
+                              lambda done, total, i=i:
+                              progress(i, done, total)),
+                    fs=fs))
+            tenants = len(sched.tenants())
+            cancelled = False
+            pending = list(handles)
+            while pending:
+                if stop is not None and stop.is_set() and not cancelled:
+                    cancelled = True
+                    for h in pending:
+                        h.cancel()
+                pending[0].wait(None if stop is None or cancelled
+                                else 0.05)
+                pending = [h for h in pending if not h.done()]
+            for h in handles:
+                if not h.future.cancelled():
+                    h.result()
+        finally:
+            for tid in tids:
+                sched.unregister_tenant(tid)
+        pack_lanes = sched.pack_width(scrypt_n)
+        widest = sched.widest_pack
+    return ManyResult(metas=[PostMetadata.load(d, fs=fs) for d in dirs],
+                      elapsed_s=time.monotonic() - t0, tenants=tenants,
+                      pack_lanes=pack_lanes, widest_pack=widest)

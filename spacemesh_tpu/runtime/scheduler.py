@@ -29,6 +29,16 @@ submit -> batch -> dispatch -> retire engine (runtime/engine.py):
   each tenant's store and folds each tenant's VRF minimum on host
   (runtime/workloads.py fold_min_host — bit-identical to the device
   scan).  16 tiny sessions become a handful of full-bucket programs.
+  A pack is as wide as the chips hold (:func:`default_pack_lanes`: the
+  verifier's lane ceiling rule), so at mainnet N=8192 one tenant's
+  lanes fill a pack and packing reduces to fair-share rotation.
+* **Per-tenant durable checkpoints.**  Each packed job saves its resume
+  metadata during the run under the single-identity rule (the same
+  interval constants and the one helper,
+  ``post/initializer.save_checkpoint``): the cursor is the tenant's
+  fsynced contiguous count, the CRC interval joins the ledger and the
+  VRF carry is recorded, so a crash loses at most one interval of
+  every identity.
 * **Gang-scheduled prove windows.**  One prove window (a whole disk
   pass: every nonce-group step chain of the window) runs as ONE
   quantum on one worker, gated by a ``gang_windows`` semaphore — its
@@ -57,8 +67,25 @@ from pathlib import Path
 from ..utils import metrics, sanitize, tracing
 from . import engine, workloads
 
-_DEFAULT_PACK_LANES = 4096
 _DEADLINE_SLACK_S = 0.05   # jobs due within this window jump fair share
+
+
+def default_pack_lanes(n: int) -> int:
+    """Lanes per packed init program at scrypt ``n`` when the caller
+    names none: ``ops/scrypt.lane_ceiling(n)`` on each device
+    ``parallel/mesh.auto_mesh`` would shard a pack over, times those
+    devices (the rule the verifier's lane tiles follow). 8,192 on one
+    v5e chip (or a platform that reports no memory) at N=8192."""
+    import jax
+
+    from ..ops import scrypt
+    from ..parallel import mesh as pmesh
+
+    per_chip = scrypt.lane_ceiling(n)
+    mesh = pmesh.auto_mesh(per_chip * jax.device_count())
+    if mesh is None:
+        return per_chip
+    return mesh.size * scrypt.lane_ceiling(n, mesh.devices.flat)
 
 
 class SchedulerClosed(RuntimeError):
@@ -160,7 +187,7 @@ class _InitJob:
     __slots__ = ("handle", "tenant", "store", "meta", "writer", "cw",
                  "total", "next_index", "outstanding", "written",
                  "min_carry", "cancelled", "error", "progress",
-                 "finalized", "crc")
+                 "finalized", "crc", "saved_at", "saved_labels")
 
     def __init__(self, handle: JobHandle, tenant: _Tenant, store, meta,
                  writer, cw, progress=None):
@@ -174,12 +201,17 @@ class _InitJob:
         self.next_index = meta.labels_written   # next lane to pack
         self.outstanding = 0               # lanes dispatched, not retired
         self.written = meta.labels_written
-        # running CRC32 of the inline-written bytes (segments retire in
-        # ascending-start order per job: next_index is monotone and the
-        # engine retires packs FIFO) — finalize appends it to the
-        # metadata's checkpoint ledger so the next reopen's recovery
-        # does not roll a verified cursor back to a stale interval
+        # running CRC32 of the inline-written bytes of the open
+        # checkpoint interval (segments retire in ascending-start order
+        # per job: next_index is monotone and the engine retires packs
+        # FIFO) — each checkpoint appends it to the metadata's ledger
+        # and cuts it, so the next reopen's recovery does not roll a
+        # verified cursor back to a stale interval
         self.crc = 0
+        # the last checkpoint: monotonic time and cursor (the interval
+        # triggers of post/initializer.py)
+        self.saved_at = time.monotonic()
+        self.saved_labels = self.written
         self.min_carry = None              # (u128 value, index) | None
         if meta.vrf_nonce is not None and meta.vrf_nonce_value is not None:
             v = bytes.fromhex(meta.vrf_nonce_value)
@@ -199,7 +231,8 @@ class TenantScheduler:
     """Many identities, one device runtime (module docstring).
 
     ``workers``       worker threads for prove/verify/pow/call quanta.
-    ``pack_lanes``    target lanes per packed init dispatch (bucketed).
+    ``pack_lanes``    target lanes per packed init dispatch (bucketed);
+                      None = :func:`default_pack_lanes` of each pack's N.
     ``inflight``      packed init dispatches in flight (engine window).
     ``gang_windows``  prove windows allowed on device concurrently.
     ``writer_threads`` background writer threads per init job (0 =
@@ -211,7 +244,7 @@ class TenantScheduler:
     """
 
     def __init__(self, *, workers: int = 2,
-                 pack_lanes: int = _DEFAULT_PACK_LANES,
+                 pack_lanes: int | None = None,
                  inflight: int = 3, gang_windows: int = 1,
                  writer_threads: int = 0,
                  pack_linger_s: float = 0.002,
@@ -221,15 +254,24 @@ class TenantScheduler:
                  autostart: bool = True,
                  time_source=time.monotonic):
         from ..ops import scrypt
+        from ..post import initializer
         from ..utils import accel
 
         # compiled pack shapes persist across processes like every other
         # entry point's (utils/accel.py) — a cold 16-tenant start must
         # not pay one serialized compile per pack bucket
         accel.enable_persistent_cache()
-        self.pack_lanes = max(scrypt.shape_bucket(int(pack_lanes)), 1)
+        self.pack_lanes = (None if pack_lanes is None
+                           else max(scrypt.shape_bucket(int(pack_lanes)), 1))
         self.inflight = max(int(inflight), 1)
         self.writer_threads = int(writer_threads)
+        # a packed job's checkpoint triggers: the single-identity
+        # Initializer's own
+        self.meta_interval_s = initializer.DEFAULT_META_INTERVAL_S
+        self.meta_interval_labels = initializer.DEFAULT_META_INTERVAL_LABELS
+        self._pack_widths: dict[int, int] = {}  # scrypt n -> lanes
+        # the widest packed program dispatched, in lanes (packer thread)
+        self.widest_pack = 0
         self.pack_linger_s = max(float(pack_linger_s), 0.0)
         self._defaults = (default_weight, default_max_inflight,
                           default_max_queued)
@@ -386,6 +428,7 @@ class TenantScheduler:
         # without bound; the queued-gauge removal alone left these)
         for inst in (metrics.runtime_tenant_jobs,
                      metrics.runtime_tenant_labels,
+                     metrics.runtime_tenant_checkpoints,
                      metrics.runtime_quantum_seconds):
             inst.remove_matching(tenant=tid)
         for job in failed:
@@ -516,10 +559,11 @@ class TenantScheduler:
                     commitment: bytes, num_units: int, labels_per_unit: int,
                     scrypt_n: int = 8192,
                     max_file_size: int = 64 * 1024 * 1024,
-                    progress=None) -> JobHandle:
+                    progress=None, fs=None) -> JobHandle:
         """Create-or-resume one identity's POST init as a PACKED job:
         its lanes dispatch interleaved with every other tenant's through
-        the shared engine.  Resolves to the final PostMetadata."""
+        the shared engine.  Resolves to the final PostMetadata.  ``fs``
+        is the injectable I/O layer (post/faultfs.py fault plans)."""
         from ..ops import scrypt
         from ..post.data import LabelStore
         from ..post.initializer import open_or_create_meta
@@ -527,8 +571,8 @@ class TenantScheduler:
         meta = open_or_create_meta(
             Path(data_dir), node_id=node_id, commitment=commitment,
             num_units=num_units, labels_per_unit=labels_per_unit,
-            scrypt_n=scrypt_n, max_file_size=max_file_size)
-        store = LabelStore(data_dir, meta)
+            scrypt_n=scrypt_n, max_file_size=max_file_size, fs=fs)
+        store = LabelStore(data_dir, meta, fs=fs)
         cw = scrypt.commitment_to_words(commitment)
         try:
             with self._lock:
@@ -608,6 +652,9 @@ class TenantScheduler:
                 pass
         if cancelled:
             handle.future.cancel()
+            # wakes JobHandle.wait() too (concurrent.futures.wait
+            # sees a cancel only once it is notified)
+            handle.future.set_running_or_notify_cancel()
         elif error is not None:
             handle.future.set_exception(error)
         else:
@@ -715,6 +762,33 @@ class TenantScheduler:
 
     # -- the init packer ------------------------------------------------
 
+    def pack_width(self, n: int) -> int:
+        """Lanes the packer composes a pack of scrypt ``n`` to."""
+        with self._lock:
+            self._shared.touch(write=False)
+            return self._width_locked(n)
+
+    # guarded by: self._lock — pack_width and _compose_pack hold it
+    def _width_locked(self, n: int) -> int:
+        if self.pack_lanes is not None:
+            return self.pack_lanes
+        width = self._pack_widths.get(n)
+        if width is None:
+            width = self._pack_widths[n] = default_pack_lanes(n)
+        return width
+
+    # guarded by: self._lock — _compose_pack calls it with the lock held
+    def _packable_locked(self):
+        """-> (tenants with init work, the N of the next pack, its width,
+        packable lanes of that N)."""
+        ready = [t for t in self._tenants.values() if t.init_jobs]
+        if not ready:
+            return ready, None, 0, 0
+        n = min(ready, key=lambda t: t.vtime).init_jobs[0].meta.scrypt_n
+        avail = sum(j.packable for t in ready for j in t.init_jobs
+                    if j.meta.scrypt_n == n)
+        return ready, n, self._width_locked(n), avail
+
     def _compose_pack(self, block: bool):
         """Cut one pack of init lanes in fair-share order.
 
@@ -723,6 +797,9 @@ class TenantScheduler:
         packer then yields IDLE so in-flight packs keep retiring).
         Returns (segments, scrypt_n), or None on close/no-work.
 
+        One static N per fused program: the least-vtime tenant's head
+        job sets it, and with it the pack's width (:meth:`pack_width`).
+
         Pack-fill policy: a burst of submits races the packer, and a
         half-empty first pack both wastes lanes and mints a smaller
         shape bucket.  So a partial pack LINGERS up to
@@ -730,32 +807,27 @@ class TenantScheduler:
         already in flight (``block`` False) a pack under half full is
         deferred outright — the engine retires results meanwhile and
         the lanes coalesce into the next full pack."""
+        work = self._packable_locked
         with self._lock:
             self._shared.touch()
             while True:
                 if self._closed:
                     return None
-                ready = [t for t in self._tenants.values() if t.init_jobs]
+                ready, n, width, avail = work()
                 if ready:
-                    avail = sum(j.packable for t in ready
-                                for j in t.init_jobs)
-                    if avail >= self.pack_lanes:
+                    if avail >= width:
                         break
                     if not block:
-                        if avail >= self.pack_lanes // 2:
+                        if avail >= width // 2:
                             break
                         return None
                     deadline = time.monotonic() + self.pack_linger_s
-                    while avail < self.pack_lanes and not self._closed:
+                    while avail < width and not self._closed:
                         left = deadline - time.monotonic()
                         if left <= 0 or not self._pack_work.wait(left):
                             break
-                        ready = [t for t in self._tenants.values()
-                                 if t.init_jobs]
-                        avail = sum(j.packable for t in ready
-                                    for j in t.init_jobs)
-                    ready = [t for t in self._tenants.values()
-                             if t.init_jobs]
+                        ready, n, width, avail = work()
+                    ready, n, width, avail = work()
                     if ready:
                         break
                     continue
@@ -764,11 +836,10 @@ class TenantScheduler:
                 self._pack_work.wait()
             segments: list[workloads.PackSegment] = []
             lanes = 0
-            n = None
             for t in sorted(ready, key=lambda t: t.vtime):
-                while t.init_jobs and lanes < self.pack_lanes:
+                while t.init_jobs and lanes < width:
                     job = t.init_jobs[0]
-                    take = min(job.packable, self.pack_lanes - lanes)
+                    take = min(job.packable, width - lanes)
                     if take == 0:
                         # cancelled/errored (packable 0) or the pack is
                         # full for this tenant's head job: never emit a
@@ -777,9 +848,7 @@ class TenantScheduler:
                             t.init_jobs.popleft()
                             continue
                         break
-                    if n is None:
-                        n = job.meta.scrypt_n
-                    elif job.meta.scrypt_n != n:
+                    if job.meta.scrypt_n != n:
                         break  # one static N per fused program
                     segments.append(workloads.PackSegment(
                         job, job.next_index, take, lanes))
@@ -791,7 +860,7 @@ class TenantScheduler:
                     t.charge(take * self._lane_cost_ema)
                     if job.packable == 0:
                         t.init_jobs.popleft()
-                if lanes >= self.pack_lanes:
+                if lanes >= width:
                     break
             return segments, n
 
@@ -818,6 +887,7 @@ class TenantScheduler:
         # one executable per (n, bucket) — so the bucket is what the
         # mesh must divide.
         bucket = scrypt.shape_bucket(lanes)
+        self.widest_pack = max(self.widest_pack, bucket)
         mesh = pmesh.auto_mesh(bucket)
         if mesh is not None:
             # mesh callers pre-bucket on host (ops/scrypt.py skips its
@@ -863,7 +933,6 @@ class TenantScheduler:
         # 16 tiny per-tenant byteswaps would hand back the per-call
         # overhead the pack just amortized
         pack_bytes = scrypt.labels_to_bytes(arr)
-        finalize: list[_InitJob] = []
         for s in segments:
             job: _InitJob = s.job
             with tracing.span("runtime.segment",
@@ -889,6 +958,25 @@ class TenantScheduler:
                             job.progress(job.written, job.total)
                 except Exception as exc:  # noqa: BLE001 — fail THIS job, not the pack
                     job.error = exc
+        # interval checkpoints, while the segments' lanes still count as
+        # outstanding: a cancel or unregister on another thread then
+        # leaves the job's finalize to this thread instead of racing
+        # the save (a job's last lanes checkpoint in finalize)
+        for s in segments:
+            job = s.job
+            if (job.error is None and not job.cancelled
+                    and job.written < job.total
+                    and (time.monotonic() - job.saved_at
+                         >= self.meta_interval_s
+                         or job.written - job.saved_labels
+                         >= self.meta_interval_labels)):
+                try:
+                    self._checkpoint(job)
+                except Exception as exc:  # noqa: BLE001 — fail THIS job, not the pack
+                    job.error = exc
+        finalize: list[_InitJob] = []
+        for s in segments:
+            job = s.job
             with self._lock:
                 self._shared.touch()
                 job.outstanding -= s.count
@@ -908,6 +996,33 @@ class TenantScheduler:
         for job in finalize:
             self._finalize_init(job)
 
+    def _checkpoint(self, job: _InitJob) -> None:
+        """Persist one packed job's resume metadata: the single-identity
+        ordering rule through its one helper
+        (``post/initializer.save_checkpoint``)."""
+        from ..post.initializer import save_checkpoint
+
+        if job.writer is not None:
+            cut = job.writer.checkpoint
+        else:
+            def cut():
+                # same contract as the writer pool's checkpoint(): fsync
+                # first, then hand back the cursor and cut the interval
+                job.store.sync()
+                crc, job.crc = job.crc, 0
+                return job.written, crc
+        nonce, value = workloads.min_carry_to_meta(job.min_carry)
+        with tracing.span("runtime.checkpoint",
+                          {"tenant": job.tenant.id}
+                          if tracing.is_enabled() else None) as sp:
+            durable = save_checkpoint(job.meta, job.store, cut,
+                                      vrf_nonce=nonce, vrf_value=value,
+                                      writer=job.writer)
+            sp.set(durable=durable)
+        job.saved_at = time.monotonic()
+        job.saved_labels = job.written
+        metrics.runtime_tenant_checkpoints.inc(tenant=job.tenant.id)
+
     def _finalize_init(self, job: _InitJob) -> None:
         # idempotent: unregister/close/retire can race to finalize the
         # same job; only the first pass drains/closes and resolves
@@ -918,36 +1033,18 @@ class TenantScheduler:
             job.finalized = True
         error = job.error
         try:
-            if job.writer is not None:
-                # drain + checkpoint fsync the dirty label files before
-                # advancing the durable cursor (post/data.py fsync
-                # discipline) — the cursor persisted below means
-                # FSYNCED, not "handed to the page cache" — and hand
-                # back the interval CRC for the ledger
-                job.writer.drain()
-                durable, crc = job.writer.checkpoint()
-                job.writer.close(drain=False)
-            else:
-                job.store.sync()  # same contract on the inline path
-                durable, crc = job.written, job.crc
-            if error is None and not job.cancelled:
-                meta = job.meta
-                meta.labels_written = durable
-                # the checkpoint ledger must cover the cursor it backs:
-                # a cursor ahead of a stale ledger would be rolled BACK
-                # (and its durable labels truncated) by the next
-                # reopen's recovery (post/data.py recover_store)
-                prev_end = meta.intervals[-1][0] if meta.intervals else 0
-                if durable > prev_end:
-                    meta.intervals.append([durable, crc])
-                nonce, value = workloads.min_carry_to_meta(job.min_carry)
-                if nonce is not None:
-                    meta.vrf_nonce = nonce
-                    meta.vrf_nonce_value = value
-                meta.save(job.store.dir)
-        except Exception as exc:  # noqa: BLE001 — surface via the handle
-            error = error or exc
+            # a job that completed or was cancelled (a stop) checkpoints
+            # what is durable; a failed one keeps its last checkpoint
+            # (what a crash at that instant would have left)
+            if error is None:
+                if job.writer is not None:
+                    job.writer.drain()
+                self._checkpoint(job)
+        except BaseException as exc:  # noqa: BLE001 — surface via the handle
+            error = exc
         finally:
+            if job.writer is not None:
+                job.writer.close(drain=False)
             job.store.close()
         if job.cancelled and error is None:
             self._resolve(job, cancelled=True)
@@ -955,6 +1052,9 @@ class TenantScheduler:
             self._resolve(job, error=error)
         else:
             self._resolve(job, result=job.meta)
+        if error is not None and not isinstance(error, Exception) \
+                and error is not job.error:
+            raise error  # a power cut (post/faultfs) ends its thread too
 
     def _packer_loop(self) -> None:
         """The shared-device init stream: one engine pipeline whose
@@ -963,10 +1063,7 @@ class TenantScheduler:
         device the way per-job ownership does."""
         pipe = engine.Pipeline(kind="init_pack", tenant="*",
                                inflight=self.inflight, span="runtime.pack",
-                               attrs=lambda p: {
-                                   "lanes": sum(s.count for s in p[0]),
-                                   "tenants": len({s.job.tenant.id
-                                                   for s in p[0]})},
+                               attrs=self._pack_attrs,
                                stop=lambda: self._closed)  # spacecheck: ok=SC007 monotonic close flag; a stale read only delays stop by one batch
 
         def packs():
@@ -988,15 +1085,30 @@ class TenantScheduler:
 
         try:
             pipe.run(packs(), self._dispatch_pack, self._retire_pack)
-        except Exception as exc:  # noqa: BLE001 — fail in-flight init jobs, not the thread
+        except BaseException as exc:  # noqa: BLE001 — the power cut of post/faultfs too
+            # the pipeline is gone and nothing in flight will retire:
+            # fail every init job with what stopped it, keeping each
+            # one's last checkpoint (as a crash here would)
             with self._lock:
                 self._shared.touch(write=False)
                 jobs = [j for j in self._jobs.values()
                         if isinstance(j, _InitJob)]
             for j in jobs:
                 j.error = j.error or exc
-                if j.outstanding == 0:
-                    self._finalize_init(j)
+                self._finalize_init(j)
+            if not isinstance(exc, Exception):
+                raise
+
+    @staticmethod
+    def _pack_attrs(pack) -> dict:
+        """``runtime.pack.dispatch`` attributes: real ``lanes``, distinct
+        ``tenants`` and the ``bucket`` the program runs at."""
+        from ..ops import scrypt
+
+        lanes = sum(s.count for s in pack[0])
+        return {"lanes": lanes,
+                "tenants": len({s.job.tenant.id for s in pack[0]}),
+                "bucket": scrypt.shape_bucket(lanes)}
 
 
 class ShardScheduler:

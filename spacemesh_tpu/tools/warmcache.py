@@ -107,19 +107,23 @@ def _warm_prove(batch: int) -> dict:
     return doc
 
 
-def _warm_runtime_kinds(n: int, batch: int, pack_lanes: int) -> dict:
+def _warm_runtime_kinds(n: int, batch: int,
+                        pack_lanes: int | None) -> dict:
     """Warm every registered runtime workload kind's executables.
 
     The packed init / verify kinds warm at the PACK bucket (the shape
-    the multi-tenant scheduler composes), the rest at the session
+    the multi-tenant scheduler composes: ``pack_lanes``, or where that
+    is None the scheduler's own default at ``n``,
+    ``runtime/scheduler.default_pack_lanes``), the rest at the session
     ``batch``; each kind's recipe lives beside the kind itself
     (runtime/workloads.py), so a new workload registered there is
     automatically covered here and by the CI warm-cache job.
     """
     from ..ops import scrypt
-    from ..runtime import workloads
+    from ..runtime import scheduler, workloads
 
-    pack = scrypt.shape_bucket(pack_lanes)
+    pack = scrypt.shape_bucket(pack_lanes if pack_lanes is not None
+                               else scheduler.default_pack_lanes(n))
     out: dict = {}
     for kind in workloads.registered():
         b = pack if kind.name in ("init_pack", "verify") else batch
@@ -134,7 +138,7 @@ def _warm_runtime_kinds(n: int, batch: int, pack_lanes: int) -> dict:
 
 def warm(n: int = 8192, batches: list[int] | None = None, *,
          mesh: bool = True, prove: bool = False,
-         runtime_kinds: bool = True, pack_lanes: int = 4096) -> dict:
+         runtime_kinds: bool = True, pack_lanes: int | None = None) -> dict:
     """Warm the persistent caches; returns a JSON-able report."""
     import os
 
@@ -197,9 +201,10 @@ def main(argv=None) -> int:
     ap.add_argument("--no-runtime", action="store_true",
                     help="skip the registered runtime workload kinds "
                     "(fused/packed init, prove scan, verify, k2pow)")
-    ap.add_argument("--pack-lanes", type=int, default=4096,
+    ap.add_argument("--pack-lanes", type=int, default=None,
                     help="pack bucket for the multi-tenant init/verify "
-                    "kind warms (runtime/scheduler.py pack_lanes)")
+                    "kind warms (default: the scheduler's own, "
+                    "runtime/scheduler.default_pack_lanes)")
     a = ap.parse_args(argv)
     doc = warm(a.n, [int(b) for b in a.batches.split(",") if b],
                mesh=not a.no_mesh, prove=a.prove,

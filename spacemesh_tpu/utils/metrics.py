@@ -442,6 +442,10 @@ runtime_tenant_queued = REGISTRY.gauge(
 runtime_tenant_labels = REGISTRY.counter(
     "runtime_tenant_labels_total",
     "init labels computed+written through the scheduler (label: tenant)")
+runtime_tenant_checkpoints = REGISTRY.counter(
+    "runtime_tenant_checkpoints_total",
+    "packed-init resume-metadata checkpoints saved through the scheduler "
+    "(label: tenant)")
 runtime_pack_occupancy = REGISTRY.histogram(
     "runtime_pack_occupancy_lanes",
     "lanes per packed multi-tenant init dispatch",

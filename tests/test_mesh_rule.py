@@ -234,7 +234,8 @@ def _run_warmcache(cache_dir):
                JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
     r = subprocess.run(
         [sys.executable, "-m", "spacemesh_tpu.tools.warmcache",
-         "--n", "32", "--batches", "64", "--no-mesh"],
+         "--n", "32", "--batches", "64", "--no-mesh",
+         "--pack-lanes", "4096"],
         env=env, capture_output=True, text=True, timeout=570)
     assert r.returncode == 0, r.stderr[-2000:]
     return json.loads(r.stdout)

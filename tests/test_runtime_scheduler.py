@@ -48,7 +48,9 @@ def _host_vrf_nonce(label_bytes):
 def test_packed_init_matches_solo_initializer(tmp_path):
     """4 tenants at ragged totals (1, 7, 300, 1000): the scheduled
     packed path must write byte-identical labels and the identical VRF
-    nonce the solo device-scan Initializer persists."""
+    nonce the solo device-scan Initializer persists, and both must equal
+    hashlib.scrypt under each tenant's own commitment."""
+    from spacemesh_tpu.post import initializer
     from spacemesh_tpu.post.data import LabelStore
 
     totals = [1, 7, 300, 1000]
@@ -70,6 +72,19 @@ def test_packed_init_matches_solo_initializer(tmp_path):
             assert got == ref, f"{tid}: packed labels diverge"
             assert meta.vrf_nonce == _host_vrf_nonce(ref)
             assert meta.labels_written == total
+            assert got == b"".join(
+                hashlib.scrypt(commit, salt=i.to_bytes(8, "little"), n=N,
+                               r=1, p=1, dklen=16) for i in range(total))
+            node = next(n for t, n, _ in ids if t == tid)
+            solo, _ = initializer.initialize(
+                tmp_path / "solo" / tid, node_id=node, commitment=commit,
+                num_units=1, labels_per_unit=total, scrypt_n=N,
+                max_file_size=1 << 20, batch_size=PACK, mesh=None)
+            store = LabelStore(tmp_path / "solo" / tid, solo)
+            assert store.read_labels(0, total) == got
+            store.close()
+            assert (solo.vrf_nonce, solo.vrf_nonce_value) == \
+                (meta.vrf_nonce, meta.vrf_nonce_value)
 
 
 def test_packed_init_resume(tmp_path):
